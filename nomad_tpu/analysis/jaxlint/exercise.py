@@ -134,9 +134,11 @@ def run_placement_paths(
     return sum(1 for r in results if r is not None)
 
 
-def run_score_matrix() -> None:
+def run_score_matrix():
     """score_matrix_kernel in both configs: class-less (throughputs
-    None — the Python gate) and with the throughput axis."""
+    None — the Python gate) and with the throughput axis. Like every
+    ``run_*`` below, returns the kernels' (possibly still in-flight)
+    outputs so the caller can wait for the device to finish them."""
     import numpy as np
 
     from ...device.score import score_matrix_kernel
@@ -153,18 +155,18 @@ def run_score_matrix() -> None:
     has_aff = np.zeros(g, dtype=bool)
     distinct = np.zeros(g, dtype=bool)
     spread = np.asarray(False)
-    score_matrix_kernel(
+    plain = score_matrix_kernel(
         capacity, used, asks, eligible, job_counts, desired_totals,
         penalty, affinity, has_aff, distinct, spread,
     )
     tp = np.ones((g, n), dtype=np.float32)
-    score_matrix_kernel(
+    return plain, score_matrix_kernel(
         capacity, used, asks, eligible, job_counts, desired_totals,
         penalty, affinity, has_aff, distinct, spread, tp,
     )
 
 
-def run_preemption() -> None:
+def run_preemption():
     import numpy as np
 
     from ...device.preempt import (
@@ -183,17 +185,17 @@ def run_preemption() -> None:
     ).astype(np.float32)
     victim_prio = np.full((n, v), 20, dtype=np.int32)
     victim_mask = np.ones((n, v), dtype=bool)
-    find_preemption_kernel(
+    found = find_preemption_kernel(
         capacity, used, ask, eligible, victim_res, victim_prio,
         victim_mask,
     )
-    choose_preemption_node_kernel(
+    return found, choose_preemption_node_kernel(
         capacity, used, ask, eligible, victim_res, victim_prio,
         victim_mask,
     )
 
 
-def run_hetero(policy: int = 0) -> None:
+def run_hetero(policy: int = 0):
     import numpy as np
 
     from ...scheduler.hetero import hetero_place_kernel
@@ -207,13 +209,13 @@ def run_hetero(policy: int = 0) -> None:
     tp = np.ones((g, n), dtype=np.float32)
     tpmax = np.ones(g, dtype=np.float32)
     cost = np.ones(n, dtype=np.float32)
-    hetero_place_kernel(
+    return hetero_place_kernel(
         capacity, used0, asks, counts, eligible, tp, tpmax, cost,
         policy=policy, steps=8, max_c=4,
     )
 
 
-def run_cp() -> None:
+def run_cp():
     import numpy as np
 
     from ...device.cp import cp_place_kernel
@@ -232,13 +234,13 @@ def run_cp() -> None:
     distinct = np.zeros(g, dtype=bool)
     jobgrp = np.arange(g, dtype=np.int32)
     lam0 = np.zeros(n, dtype=np.float32)
-    cp_place_kernel(
+    return cp_place_kernel(
         capacity, used0, asks, counts, eligible, scores, prio,
         job_counts, distinct, jobgrp, lam0, steps=8, max_c=4,
     )
 
 
-def run_cp_gang() -> None:
+def run_cp_gang():
     import numpy as np
 
     from ...device.cp import cp_gang_place_kernel
@@ -267,14 +269,14 @@ def run_cp_gang() -> None:
     ici_oh = np.zeros((n, levels * 2), dtype=np.int32)
     ici_oh[np.arange(n), 1 + np.arange(n) % (levels * 2 - 1)] = 1
     lam0 = np.zeros(n, dtype=np.float32)
-    cp_gang_place_kernel(
+    return cp_gang_place_kernel(
         capacity, used0, asks, counts, eligible, scores, prio,
         job_counts, distinct, jobgrp, gang, w_rack, w_pod, w_ici,
         rack_oh, pod_oh, ici_oh, lam0, steps=8, max_c=4,
     )
 
 
-def run_migrate() -> None:
+def run_migrate():
     """migrate_plan_kernel: the defrag plane's bounded-budget move
     selection over a small fragmented fleet."""
     import numpy as np
@@ -293,24 +295,29 @@ def run_migrate() -> None:
     cur_scores = scores[np.arange(a), cur]
     move_cost = np.full(a, 0.05, dtype=np.float32)
     lam0 = np.zeros(n, dtype=np.float32)
-    migrate_plan_kernel(
+    return migrate_plan_kernel(
         capacity, used0, sizes, cur, eligible, scores, cur_scores,
         move_cost, np.int32(2), lam0, steps=8,
     )
 
 
 def exercise_fleet(explain: bool = False) -> dict:
-    """Run the whole fleet exercise; returns the kernel registry
-    afterwards (every production kernel now has a recorded spec)."""
+    """Run the whole fleet exercise to completion on the device;
+    returns the kernel registry afterwards (every production kernel now
+    has a recorded spec and has executed once)."""
+    import jax
+
     from ...utils import backend
     from .retracer import import_fleet
 
     import_fleet()
     run_placement_paths(explain=explain)
-    run_score_matrix()
-    run_preemption()
-    run_hetero()
-    run_cp()
-    run_cp_gang()
-    run_migrate()
+    jax.block_until_ready([
+        run_score_matrix(),
+        run_preemption(),
+        run_hetero(),
+        run_cp(),
+        run_cp_gang(),
+        run_migrate(),
+    ])
     return backend.kernel_registry()

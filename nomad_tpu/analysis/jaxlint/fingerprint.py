@@ -64,9 +64,9 @@ def _var_namer():
     names: dict = {}
 
     def name_of(v):
-        import jax
+        import jax.extend
 
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jax.extend.core.Literal):
             return f"lit({_norm_param(v.val)}:{_aval_str(v.aval)})"
         if v not in names:
             names[v] = f"v{len(names)}"
@@ -167,11 +167,9 @@ def fingerprint_table(registry=None, production_only: bool = True) -> dict:
     for name, entry in sorted(reg.items()):
         configs = {}
         for sig in entry.specs:
-            label = retracer.spec_label(entry, sig)
-            try:
-                configs[label] = fingerprint_for(entry, sig)
-            except Exception as e:  # noqa: BLE001 — surfaced, not hidden
-                configs[label] = f"error:{type(e).__name__}"
+            configs[retracer.spec_label(entry, sig)] = fingerprint_for(
+                entry, sig
+            )
         if configs:
             out[entry.short] = configs
     return out

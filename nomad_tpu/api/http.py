@@ -1712,12 +1712,16 @@ class HTTPAgent:
         # compiled programs from their trace surfaces alone. Re-tracing
         # is abstract (no compile) and cached per (kernel, spec); the
         # flight-recorder surface must never 500 because a kernel spec
-        # went unretraceable, hence best-effort.
+        # went unretraceable: the failure lands in the ``errors`` ring
+        # this same response carries.
         try:
             from ..analysis.jaxlint import fingerprint_table
 
             fingerprints = fingerprint_table()
-        except Exception:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001
+            from ..utils.metrics import count_swallowed
+
+            count_swallowed("http", e)
             fingerprints = {}
         return {
             "traces": flight_recorder.list(int(query.get("n", 50))),

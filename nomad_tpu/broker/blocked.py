@@ -33,8 +33,7 @@ class BlockedEvals:
 
     def captured(self) -> list:
         """Snapshot of currently-parked blocked evals (bench/ops
-        accounting: every unplaced alloc must be attributable —
-        VERDICT r3 weak #4)."""
+        accounting: every unplaced alloc must be attributable)."""
         with self._lock:
             return list(self._captured.values())
 

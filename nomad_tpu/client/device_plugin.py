@@ -64,14 +64,14 @@ class JaxDevicePlugin(DevicePlugin):
     name = "jax"
 
     def fingerprint(self) -> list[dict]:
-        try:
-            import jax
+        # This runs in the plugin's OWN process. A chip belongs to one
+        # process at a time, so under an agent whose server already
+        # holds the chip ``jax.devices()`` fails here (or hangs): the
+        # failure travels back as the call's error, never as "no
+        # devices".
+        import jax
 
-            accel = [
-                d for d in jax.devices() if d.platform not in ("cpu",)
-            ]
-        except Exception:  # noqa: BLE001 — no backend = no devices
-            return []
+        accel = [d for d in jax.devices() if d.platform not in ("cpu",)]
         if not accel:
             return []
         platform = accel[0].platform

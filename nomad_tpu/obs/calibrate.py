@@ -99,6 +99,16 @@ class CalibrationConstant:
 # server/admission.py's _DEFAULTS (PR 11) and resilience/breaker.py's
 # deadline defaults. This tuple is the ONE place bare threshold numbers
 # are allowed to live (NTA018 exempts this module).
+#
+# The two resilience deadlines were checked against a TPU v5e by
+# chip_smoke.py (PR 21, 10k-node fleet, cold compile cache): the longest
+# cold compile of a place_closed_form_kernel variant was 10.1 s on one
+# chip and 19.6 s under the four-chip (2,2) mesh against the 60 s
+# compile deadline, and the longest warm dispatch 125 ms against the 5 s
+# execute deadline — both stand. (The same compile inside a pass is
+# enough to push eval-latency p99 past admission.shed_p99_ms, and with
+# eight workers a thread waiting on a neighbour's compile is timed by
+# the execute deadline; see PERF.md.)
 DEFAULT_CONSTANTS: tuple[tuple[str, float], ...] = (
     ("admission.brownout_backlog", 512.0),
     ("admission.shed_backlog", 2048.0),

@@ -1,4 +1,4 @@
-"""Auxiliary surface from VERDICT r3 'what's missing': fingerprint
+"""Auxiliary surface: fingerprint
 detector breadth (client/fingerprint/), the pprof + operator-debug
 profiling surface (command/agent/http.go:331, command/operator_debug.go),
 and the HCL agent config file (command/agent/config.go)."""

@@ -1,7 +1,7 @@
 """Chunked spread placement (place_spread_chunked_kernel): large
 spread-coupled groups place CHUNK instances per step with the per-value
 boost tables frozen within a chunk. Exactness is deliberately traded for
-~CHUNK× less sequential depth (VERDICT r3 #2); these tests bound the
+~CHUNK× less sequential depth; these tests bound the
 trade against the stepwise NumPy oracle from test_value_scan:
 
 - every placement is feasible (capacity, eligibility, caps);
@@ -228,7 +228,7 @@ def test_batch_decorrelation_and_repair_large_lanes():
 def test_repair_rescore_places_conflicts_without_abort():
     """Two identical lanes, no decorrelation, tiny overflow: the second
     lane's conflicts must be re-placed by the exact host re-score instead
-    of aborting the lane (VERDICT r3 #1b)."""
+    of aborting the lane."""
     ct = make_cluster(64, seed=26, load_max=0.0)
     ct.capacity[:64, 0] = 1000.0
     ct.capacity[:64, 1] = 1024.0

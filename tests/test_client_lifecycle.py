@@ -1,6 +1,6 @@
 """Client lifecycle: heartbeatstop (stop_after_client_disconnect) and
-terminal-alloc GC — the two accepted-but-ignored knobs VERDICT r3 #7
-carried. Reference: client/heartbeatstop.go:11-40, client/gc.go."""
+terminal-alloc GC — two knobs that were once accepted but
+ignored. Reference: client/heartbeatstop.go:11-40, client/gc.go."""
 
 import os
 import time

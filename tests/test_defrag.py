@@ -440,7 +440,10 @@ class TestOperatorSurfaces:
         from nomad_tpu.api.client import NomadClient
 
         global_metrics.incr("nomad.migrate.planned", 0)
-        c = NomadClient(http.address)
+        # the trace surface fingerprints every kernel spec the process
+        # has recorded; late in a full tier-1 session that alone runs
+        # close to the client's default 10 s
+        c = NomadClient(http.address, timeout=60.0)
         idx = c._request("GET", "/v1/agent/trace")
         assert "migrate" in idx
         assert all(

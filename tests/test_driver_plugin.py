@@ -64,7 +64,7 @@ class TestPluginProtocol:
             d.close()
 
     def test_reattach_through_plugin_restart(self, tmp_path):
-        """The VERDICT #9 done-criterion: raw_exec out-of-process with
+        """The done-criterion: raw_exec out-of-process with
         restart re-attach through the protocol. The task (own session)
         survives the plugin dying; a fresh plugin recovers the persisted
         handle and can still stop the task."""

@@ -266,7 +266,7 @@ class TestJXL003DtypeDiscipline:
         e = fixture_kernel(
             widened, "test_jaxlint.jxl003.wide", retrace_budget=1
         )
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(widened)(
                 jax.ShapeDtypeStruct((4,), np.float32)
             )

@@ -150,13 +150,24 @@ def test_mesh_shapes_1x8_and_4x2():
         np.testing.assert_array_equal(c, ref_c)
 
 
-def test_dryrun_multichip_in_process(monkeypatch):
+def test_dryrun_multichip_in_process():
     """With 8 virtual devices provisioned (conftest), the driver's dryrun
-    entry must run fully in-process and pass. NOMAD_TPU_DRYRUN_CHILD
-    forbids delegation, so a regression that breaks the in-process path
-    cannot hide behind a successful CPU child subprocess."""
-    monkeypatch.setenv("NOMAD_TPU_DRYRUN_CHILD", "1")
+    entry runs in this process and passes."""
     graft.dryrun_multichip(8)
+
+
+def test_dryrun_multichip_raises_without_enough_devices(monkeypatch):
+    """Asked for more devices than jax shows, the dryrun raises — it
+    never starts another process to find them."""
+    import subprocess
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("dryrun_multichip must not spawn a process")
+
+    monkeypatch.setattr(subprocess, "run", no_spawn)
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    with pytest.raises(RuntimeError, match="only 8 cpu device"):
+        graft.dryrun_multichip(16)
 
 
 # -- mesh seam (utils/backend.py) -------------------------------------------
@@ -381,6 +392,20 @@ def _mesh_cfg(dp, mp):
 
 def _degenerate_cfg():
     return backend.MeshConfig(None, 1, 1, "test")
+
+
+def test_shard_put_counts_the_axes_it_replicates(mesh_env):
+    """An axis the mesh does not divide is replicated — and counted, so
+    'everything on every device' is never a silent layout."""
+    cfg = mesh_env("2,4")
+    assert backend.shard_drops() == {}
+    x = backend.shard_put(
+        np.zeros((1, 16), np.float32), ("groups", "nodes"), cfg
+    )
+    assert x.sharding.spec == jax.sharding.PartitionSpec(None, "nodes")
+    backend.shard_put(np.zeros((2, 1), np.float32), ("groups", "nodes"), cfg)
+    backend.shard_put(np.zeros((2, 16), np.float32), ("groups", "nodes"), cfg)
+    assert backend.shard_drops() == {"groups:1%2": 1, "nodes:1%4": 1}
 
 
 class TestProductionPathSharded:

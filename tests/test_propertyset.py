@@ -293,8 +293,8 @@ class TestSchedulerSpread:
         assert by_dc == {"dc1": 5, "dc2": 5}
 
     def test_two_block_spread_parity(self):
-        """Two spread blocks score together (VERDICT r2 #3: two-block
-        parity; spread_test.go:176 TestSpreadIterator_MultipleAttributes):
+        """Two spread blocks score together (two-block parity;
+        spread_test.go:176 TestSpreadIterator_MultipleAttributes):
         rack spread (weight 70) + dc spread (weight 30)."""
         h = Harness()
         info = {}

@@ -330,6 +330,52 @@ class TestKernelFallback:
         assert _counter("nomad.resilience.fallback_passes") == before + 1
 
 
+class TestNestedKernelGuard:
+    def test_inner_kernel_inlines_under_outer_trace(self, monkeypatch):
+        """A traced_jit kernel called while another one is being traced
+        gets tracer arguments bound to that trace's thread: it must
+        inline — no breaker of its own, no second hop through the
+        watchdog executor — and still trace exactly once."""
+        import jax.numpy as jnp
+
+        from nomad_tpu.resilience import watchdog
+        from nomad_tpu.utils.backend import trace_counts, traced_jit
+
+        inner_name = "tests.test_resilience.nested_inner"
+        outer_name = "tests.test_resilience.nested_outer"
+        seen = []
+
+        @traced_jit(trace_name=inner_name)
+        def inner(x):
+            seen.append(type(x).__name__)
+            return x * 2.0
+
+        @traced_jit(trace_name=outer_name)
+        def outer(x):
+            return inner(x) + 1.0
+
+        runs = []
+        real_run = watchdog.global_executor.run
+
+        def counting_run(thunk, **kw):
+            runs.append(kw["name"])
+            return real_run(thunk, **kw)
+
+        monkeypatch.setattr(watchdog.global_executor, "run", counting_run)
+        out = outer(jnp.arange(4, dtype=jnp.float32))
+        np.testing.assert_array_equal(np.asarray(out), [1.0, 3.0, 5.0, 7.0])
+        assert seen and "Tracer" in seen[0]
+        assert runs == [outer_name]
+        assert inner_name not in rbr.all_breakers()
+        assert outer_name in rbr.all_breakers()
+        assert trace_counts()[inner_name] == 1
+        # called directly with concrete arrays, the inner kernel is
+        # guarded like any other
+        inner(jnp.ones(4, dtype=jnp.float32))
+        assert runs == [outer_name, inner_name]
+        assert inner_name in rbr.all_breakers()
+
+
 # -- RPC retry / idempotency -------------------------------------------------
 
 

@@ -255,8 +255,8 @@ def test_target_spread_untargeted_value_penalty():
 
 
 def test_multi_block_spread_matches_oracle():
-    """Two spread blocks with relative weights (VERDICT r2 #4: multi-block
-    was scored against the first block only)."""
+    """Two spread blocks with relative weights (multi-block was once
+    scored against the first block only)."""
     ct = make_cluster(24, seed=5)
     vids_rack = (np.arange(ct.padded_n) % 4).astype(np.int32)
     vids_dc = (np.arange(ct.padded_n) % 2).astype(np.int32)
@@ -354,7 +354,7 @@ def test_fuzz_value_scan_vs_oracle():
 
 
 def test_even_spread_zero_count_boundary():
-    """VERDICT r3 weak #7: pin the deliberate deviation at the exact
+    """Pin the deliberate deviation at the exact
     boundary where this build and the reference can diverge — a value
     whose combined count is (or has been cleared to) ZERO while others
     are positive. The reference's evenSpreadScoreBoost iterates a Go map
