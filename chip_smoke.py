@@ -17,9 +17,12 @@ pass → plan queue → applier → allocs in the store.
   compiled and executed (those the phases did not reach run once on
   ``analysis/jaxlint/exercise.py``'s fleet: "toy shape only").
 
-Any failed check exits non-zero with the reason. It prints no rate and no
-latency: the deadline block only sets the longest compile / pass beside
-the deadline it must stay under. ``__main__`` always demands a TPU; the
+Any failed check exits non-zero with the reason. Standard output is two
+lines: ``{"report": {...}}`` (what was established: kernels, compiles per
+phase, memory, cache, parity), then last the result line
+``{"ok": true, "device": {"platform", "kind", "count"}}`` with exactly
+those keys. It prints no rate and no latency: the deadline block only sets
+the longest compile / pass beside the deadline it must stay under. ``__main__`` always demands a TPU; the
 functions take sizes so tier-1 can drive them at toy size on the CPU.
 """
 
@@ -613,6 +616,15 @@ def run_smoke(
     return report
 
 
+def result_lines(ok: bool, device: dict, report: dict, failed=None) -> list:
+    """What ``main`` writes to stdout: the report on one line, then the
+    result line, whose keys are exactly ``ok`` and ``device``."""
+    detail = {"report": report} if failed is None else {
+        "failed": failed, "report": report,
+    }
+    return [json.dumps(detail), json.dumps({"ok": ok, "device": device})]
+
+
 def main() -> int:
     import jax
 
@@ -630,16 +642,14 @@ def main() -> int:
         "count": len(dev),
     }
     report: dict = {}
+    failed = None
     try:
         run_smoke(report=report)
     except SmokeFailure as e:
-        print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
-        print(json.dumps(
-            {"ok": False, "failed": str(e), "device": device, **report}
-        ))
-        return 1
-    print(json.dumps({"ok": True, "device": device, **report}))
-    return 0
+        failed = str(e)
+        print(f"chip_smoke: FAILED: {failed}", file=sys.stderr)
+    print("\n".join(result_lines(failed is None, device, report, failed)))
+    return 0 if failed is None else 1
 
 
 if __name__ == "__main__":

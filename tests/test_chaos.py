@@ -361,6 +361,14 @@ class TestChaosRunner:
 class TestMigrationFaults:
     """Defrag two-phase moves under the fault plane (law 16)."""
 
+    @pytest.fixture(scope="class", autouse=True)
+    def _warm_migrate_kernel(self):
+        """A run whose defrag thread spends its whole length in the
+        first trace+compile of ``migrate_plan_kernel`` plans no move and
+        reaches no ``migrate.*`` site; compile it before the clock of
+        the runs below starts."""
+        _small_run(11, steps=60)
+
     def test_move_drop_commits_nothing(self):
         run = _small_run(
             7, steps=60,

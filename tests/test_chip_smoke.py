@@ -69,6 +69,23 @@ def test_main_without_tpu_exits_nonzero_and_prints_no_result():
     assert time.monotonic() - t0 < 30
 
 
+@pytest.mark.parametrize("failed", [None, "phase b: 1 worker nacks"])
+def test_last_stdout_line_has_exactly_ok_and_device(failed):
+    """The driver parses the last line: keys exactly ``ok`` and
+    ``device``; the report (and the reason) ride on the line before."""
+    import json
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    lines = chip_smoke.result_lines(
+        failed is None, device, {"parity": {}}, failed
+    )
+    assert all("\n" not in line for line in lines)
+    assert json.loads(lines[-1]) == {"ok": failed is None, "device": device}
+    detail = json.loads(lines[-2])
+    assert detail["report"] == {"parity": {}}
+    assert detail.get("failed") == failed
+
+
 class TestFailureIsLoud:
     @pytest.fixture
     def server(self):
