@@ -463,7 +463,7 @@ def kernel_report() -> tuple:
             ),
             "compile_deadline_s": breakers[name]["compile_deadline_s"],
             "longest_warm_dispatch_s": round(
-                samples.get(f"nomad.kernel.{short}.execute", {}).get(
+                samples.get(f"nomad.kernel.{short}.dispatch", {}).get(
                     "max_ms", 0.0
                 ) / 1000.0, 4,
             ),

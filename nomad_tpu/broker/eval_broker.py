@@ -54,6 +54,45 @@ class _PQ:
         return len(self._h)
 
 
+class BrokerStay:
+    """One eval's stay in the broker, for the trace layer: when it first
+    became ready and when a worker took it (``perf_counter`` stamps, the
+    tracer's clock), the parts of that interval it spent parked behind
+    its job's gate (another eval of the job in flight) and on the delayed
+    heap by admission deferral, and the ``register`` interval the server
+    entry point handed in with it."""
+
+    __slots__ = (
+        "ready_at", "dequeued_at", "gate_s", "deferred_s", "register",
+        "_parked_at", "_parked_as",
+    )
+
+    def __init__(self, ready_at: float, register=None):
+        self.ready_at = ready_at
+        self.dequeued_at = ready_at
+        self.gate_s = 0.0
+        self.deferred_s = 0.0
+        self.register = register  # (entry, enqueue) stamps, or None
+        self._parked_at = 0.0
+        self._parked_as = ""
+
+    def park(self, now: float, kind: str) -> None:
+        self._parked_at, self._parked_as = now, kind
+
+    def unpark(self, now: float) -> None:
+        if self._parked_as == "gate":
+            self.gate_s += now - self._parked_at
+        elif self._parked_as == "deferred":
+            self.deferred_s += now - self._parked_at
+        self._parked_as = ""
+
+    @property
+    def wait_s(self) -> float:
+        """The whole stay; what the two parked parts leave of it was spent
+        in a ready queue."""
+        return self.dequeued_at - self.ready_at
+
+
 class EvalBroker:
     def __init__(
         self,
@@ -103,11 +142,11 @@ class EvalBroker:
         self._delayed: list[tuple] = []
         self._seq = itertools.count()
         self._delivery_count: dict[str, int] = {}
-        # queue-wait attribution for the trace layer: eval id → wall clock
-        # of first readiness, converted at dequeue into a wait the worker
-        # collects via take_queue_wait() for the dequeue span's tags
-        self._enqueued_at: dict[str, float] = {}
-        self._queue_waits: dict[str, float] = {}
+        # queue-wait attribution for the trace layer: eval id → its
+        # BrokerStay from first readiness; closed at dequeue, when the
+        # worker collects it via take_stay() for the dequeue span
+        self._enqueued_at: dict[str, BrokerStay] = {}
+        self._queue_waits: dict[str, BrokerStay] = {}
         self.stats = {
             "total_ready": 0,
             "total_unacked": 0,
@@ -144,9 +183,14 @@ class EvalBroker:
             self._lock.notify_all()
 
     # -- enqueue -----------------------------------------------------------
-    def enqueue(self, ev: Evaluation) -> None:
+    def enqueue(
+        self, ev: Evaluation, entered_at: Optional[float] = None
+    ) -> None:
+        """``entered_at``: the ``perf_counter`` stamp at which the server
+        entry point that made ``ev`` was entered; the broker keeps the
+        interval up to now beside the eval's stay, for its trace."""
         with self._lock:
-            self._enqueue_locked(ev)
+            self._enqueue_locked(ev, entered_at=entered_at)
             self._lock.notify_all()
 
     def enqueue_all(self, evals: list[Evaluation]) -> None:
@@ -155,11 +199,17 @@ class EvalBroker:
                 self._enqueue_locked(ev)
             self._lock.notify_all()
 
-    def _enqueue_locked(self, ev: Evaluation, ignore_job_gate: bool = False) -> None:
+    def _enqueue_locked(
+        self,
+        ev: Evaluation,
+        ignore_job_gate: bool = False,
+        entered_at: Optional[float] = None,
+    ) -> None:
         if not self.enabled:
             return
         self.counters["enqueues"] += 1
         now = self._clock()
+        now_mono = time.perf_counter()
         if ev.wait_until_unix and ev.wait_until_unix > now:
             heapq.heappush(
                 self._delayed, (ev.wait_until_unix, next(self._seq), ev)
@@ -167,7 +217,14 @@ class EvalBroker:
             return
         # stamp first readiness (delayed evals stamp when they fire; the
         # job-gate defer still counts — that IS queue wait for the job)
-        self._enqueued_at.setdefault(ev.id, now)
+        stay = self._enqueued_at.get(ev.id)
+        if stay is None:
+            stay = self._enqueued_at[ev.id] = BrokerStay(
+                now_mono,
+                None if entered_at is None else (entered_at, now_mono),
+            )
+        else:
+            stay.unpark(now_mono)
         # per-priority admission watermarks: past the brownout point,
         # externally-submitted evals whose tier watermark is below the
         # active backlog park on the delayed heap and re-decide when
@@ -182,10 +239,12 @@ class EvalBroker:
             delay = adm.gate_enqueue(ev, backlog)
             if delay is not None:
                 self.counters["admission_deferred"] += 1
+                stay.park(now_mono, "deferred")
                 heapq.heappush(self._delayed, (now + delay, next(self._seq), ev))
                 return
         job_key = (ev.namespace, ev.job_id)
         if not ignore_job_gate and job_key in self._in_flight_jobs:
+            stay.park(now_mono, "gate")
             self._pending_by_job.setdefault(job_key, _PQ()).push(ev)
             return
         self._ready.setdefault(self._queue_key(ev), _PQ()).push(ev)
@@ -297,6 +356,9 @@ class EvalBroker:
                         job_key = (cand.namespace, cand.job_id)
                         if job_key in self._in_flight_jobs:
                             q.pop()
+                            stay = self._enqueued_at.get(cand.id)
+                            if stay is not None:
+                                stay.park(time.perf_counter(), "gate")
                             self._pending_by_job.setdefault(job_key, _PQ()).push(
                                 cand
                             )
@@ -320,9 +382,10 @@ class EvalBroker:
                         self._delivery_count.get(ev.id, 0) + 1
                     )
                     self.counters["dequeues"] += 1
-                    t_ready = self._enqueued_at.pop(ev.id, None)
-                    if t_ready is not None:
-                        self._queue_waits[ev.id] = self._clock() - t_ready
+                    stay = self._enqueued_at.pop(ev.id, None)
+                    if stay is not None:
+                        stay.dequeued_at = time.perf_counter()
+                        self._queue_waits[ev.id] = stay
                     if chaos_site("broker.dequeue") == "drop":
                         # delivered-but-lost: the eval is charged as a
                         # dequeue and sits unacked, so the redelivery
@@ -382,12 +445,12 @@ class EvalBroker:
                 del self._pending_by_job[job_key]
             self._enqueue_locked(nxt)
 
-    def take_queue_wait(self, eval_id: str) -> float:
-        """Pop the ready→dequeue wait recorded for an eval (seconds);
-        0.0 when unknown. The dequeuing worker calls this exactly once to
-        tag the trace's dequeue span, so the table never accumulates."""
+    def take_stay(self, eval_id: str) -> Optional[BrokerStay]:
+        """Pop the ready→dequeue stay recorded for an eval; None when
+        unknown. The dequeuing worker calls this exactly once for the
+        trace's dequeue span, so the table never accumulates."""
         with self._lock:
-            return self._queue_waits.pop(eval_id, 0.0)
+            return self._queue_waits.pop(eval_id, None)
 
     def ack(self, eval_id: str, token: str) -> None:
         # consulted outside the lock: a "delay" here models a *late*

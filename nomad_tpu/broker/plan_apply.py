@@ -425,10 +425,8 @@ class PlanApplier:
         token = getattr(plan, "eval_token", "")
         if not token or self.token_check is None:
             return False
-        if self.token_check(plan.eval_id, token):
-            return False
-        metrics.incr("nomad.plan.stale_token_rejects")
-        return True
+        # the worker counts the drop (nomad.worker.stale_token_drops)
+        return not self.token_check(plan.eval_id, token)
 
     def _check_lane_ownership(self, mplan: MergedPlan) -> None:
         """The structural assertion lane mode buys us: every node a
@@ -543,14 +541,14 @@ class PlanApplier:
         """Verify + commit one merged batch under the serialized applier
         lock: one union verify pass, one FSM/Raft entry, one store index
         bump — per-member attribution preserved in the returned results.
-        Returns (results, phase timings in seconds); the apply loop
-        records the timings as shared spans into every member's trace."""
+        Returns (results, phase timings: seconds and ``perf_counter``
+        start stamps); the apply loop records them as spans of the pass."""
         t_apply = time.perf_counter()
         with self._lock:
             lane_mode = self.lanes is not None and mplan.owner_worker >= 0
             if lane_mode:
                 self._check_lane_ownership(mplan)
-            t0 = time.perf_counter()
+            t_evaluate = time.perf_counter()
             chaos_site("plan_apply.verify")
             # stale-token members are excluded BEFORE the union verify:
             # a redelivered eval's duplicate placements must neither
@@ -572,7 +570,7 @@ class PlanApplier:
                 results = evaluate_merged_plan(self.store, mplan.plans)
             if lane_mode:
                 self._check_lane_rejections(mplan, results)
-            evaluate_s = time.perf_counter() - t0
+            evaluate_s = time.perf_counter() - t_evaluate
             metrics.measure("nomad.plan.evaluate", evaluate_s)
             # merged-only sample so the bench can report the batched
             # verify latency separately from single-plan evaluates
@@ -586,7 +584,7 @@ class PlanApplier:
             for _eid, res in commit_members:
                 if res.node_preemptions:
                     evals.extend(preemption_evals(self.store, res))
-            t0 = time.perf_counter()
+            t_commit = time.perf_counter()
             if commit_members:
                 fresh = (
                     [
@@ -634,7 +632,7 @@ class PlanApplier:
                     self.on_evals_created([
                         self.store.eval_by_id(e.id) or e for e in evals
                     ])
-            commit_s = time.perf_counter() - t0
+            commit_s = time.perf_counter() - t_commit
             for res in results:
                 if res.rejected_nodes:
                     res.refresh_index = self.store.latest_index
@@ -642,6 +640,9 @@ class PlanApplier:
             metrics.measure("nomad.plan.apply", apply_s)
             return results, {
                 "apply_s": apply_s,
+                "apply_start": t_apply,
                 "evaluate_s": evaluate_s,
+                "evaluate_start": t_evaluate,
                 "commit_s": commit_s,
+                "commit_start": t_commit,
             }

@@ -48,14 +48,15 @@ class PendingMergedPlan:
     """One queue entry for a whole batched pass: B member plans, B result
     futures — the coalesced commit unit the merged-apply path consumes."""
 
-    __slots__ = ("mplan", "futures", "trace_ctxs", "enqueued_at")
+    __slots__ = ("mplan", "futures", "trace_ctx", "enqueued_at")
 
-    def __init__(self, mplan: MergedPlan, trace_ctxs=None):
+    def __init__(self, mplan: MergedPlan, trace_ctx=None):
         self.mplan = mplan
         self.futures: list[Future] = [Future() for _ in mplan.plans]
-        # one span context per member, so the applier thread records the
-        # shared merged-apply phases into every member's trace
-        self.trace_ctxs = list(trace_ctxs or [None] * len(mplan.plans))
+        # the pass's submit_plan span context (in the trace of the pass's
+        # leader): the applier thread records the queue wait and the
+        # merged apply under it, once per pass
+        self.trace_ctx = trace_ctx
         self.enqueued_at = time.perf_counter()
 
     def cancel(self) -> None:
@@ -95,7 +96,7 @@ class PlanQueue:
             return pending.future
 
     def enqueue_merged(
-        self, mplan: MergedPlan, trace_ctxs=None
+        self, mplan: MergedPlan, trace_ctx=None
     ) -> list[Future]:
         """Submit a whole batched pass as ONE pending entry; returns one
         result future per member plan, resolved together when the merged
@@ -112,7 +113,7 @@ class PlanQueue:
                     f.set_exception(RuntimeError("plan queue is disabled"))
                     futures.append(f)
                 return futures
-            pending = PendingMergedPlan(mplan, trace_ctxs=trace_ctxs)
+            pending = PendingMergedPlan(mplan, trace_ctx=trace_ctx)
             heapq.heappush(
                 self._heap, (-mplan.priority, next(self._c), pending)
             )
@@ -174,6 +175,7 @@ class PlanApplyLoop:
                     ctx.trace_id,
                     "plan_queue.wait",
                     time.perf_counter() - pending.enqueued_at,
+                    start=pending.enqueued_at,
                     parent=ctx,
                 )
             try:
@@ -187,8 +189,8 @@ class PlanApplyLoop:
 
     def _apply_merged(self, pending: PendingMergedPlan) -> None:
         """Apply one merged batch and resolve every member future; the
-        shared queue-wait and apply phases are recorded into each
-        member's trace (the batch-wide ``shared`` span convention)."""
+        queue wait and the apply with its two stages are recorded once,
+        where they happened, under the pass's submit_plan span."""
         wait_s = time.perf_counter() - pending.enqueued_at
         mplan = pending.mplan
         try:
@@ -200,31 +202,28 @@ class PlanApplyLoop:
                 if not f.done():
                     f.set_exception(e)
             return
-        n = len(mplan.plans)
-        for mp, res, fut, ctx in zip(
-            mplan.plans, results, pending.futures, pending.trace_ctxs
-        ):
-            if ctx is not None:
-                eid = mp.eval_id
-                tracer.add_span(
-                    eid, "plan_queue.wait", wait_s,
-                    parent=ctx, tags={"shared": True},
-                )
-                sp = tracer.add_span(
-                    eid, "plan_apply", timings["apply_s"], parent=ctx,
-                    tags={
-                        "shared": True,
-                        "members": n,
-                        "rejected_nodes": len(res.rejected_nodes),
-                    },
-                )
-                if sp is not None:
+        ctx = pending.trace_ctx
+        if ctx is not None:
+            eid = ctx.trace_id
+            tracer.add_span(
+                eid, "plan_queue.wait", wait_s,
+                start=pending.enqueued_at, parent=ctx,
+            )
+            sp = tracer.add_span(
+                eid, "plan_apply", timings["apply_s"],
+                start=timings["apply_start"], parent=ctx,
+                tags={
+                    "members": len(mplan.plans),
+                    "rejected_nodes": sum(
+                        len(res.rejected_nodes) for res in results
+                    ),
+                },
+            )
+            if sp is not None:
+                for stage in ("evaluate", "commit"):
                     tracer.add_span(
-                        eid, "plan_apply.evaluate", timings["evaluate_s"],
-                        parent=sp, tags={"shared": True},
+                        eid, f"plan_apply.{stage}", timings[f"{stage}_s"],
+                        start=timings[f"{stage}_start"], parent=sp,
                     )
-                    tracer.add_span(
-                        eid, "plan_apply.commit", timings["commit_s"],
-                        parent=sp, tags={"shared": True},
-                    )
+        for res, fut in zip(results, pending.futures):
             fut.set_result(res)

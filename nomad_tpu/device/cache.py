@@ -23,7 +23,9 @@ from dataclasses import replace
 
 import numpy as np
 
+from ..obs.trace import global_tracer
 from ..structs.resources import node_comparable_capacity
+from ..utils.metrics import global_metrics
 from .flatten import ClusterTensors, flatten_cluster
 
 
@@ -105,8 +107,11 @@ class DeviceStateCache:
     def tensors(self, snap) -> ClusterTensors:
         from ..utils.backend import get_mesh, incremental_enabled
 
-        with self._lock:
+        with global_tracer.span("flatten") as sp, self._lock:
+            flattens = self.full_flattens
             ct = self._refresh_locked(snap)
+            if sp is not None:
+                sp.tags["full"] = self.full_flattens > flattens
             out = replace(ct, used=ct.used.copy())
             cfg = get_mesh()
             if cfg.active:
@@ -428,6 +433,7 @@ class DeviceStateCache:
     # -- refresh machinery -------------------------------------------------
     def _rebuild_locked(self, snap) -> ClusterTensors:
         self.full_flattens += 1
+        global_metrics.incr("nomad.device_cache.full_flattens")
         self._ct = replace(
             flatten_cluster(snap), layout_gen=self.full_flattens
         )

@@ -345,34 +345,44 @@ def place_closed_form_kernel(
         capacity, eligible, job_counts, penalty_nodes
     )
 
+    # the named scopes change no equation: they put a stage's name into
+    # the op metadata, so a profile says which stage ``fusion.130`` is
     def one_group(ask, elig, jc0, dt, pen, aff, has_aff, dh, caps, count):
-        num, den, fits = _score_planes(
-            capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
-            caps, algorithm_spread, max_j, jitter=jitter,
-        )
-        s_raw = jnp.where(fits, num / den, -jnp.inf)
-        # Selection runs on the running-min clamp: it restores the prefix
-        # rule "(n,j) requires (n,j-1)" that plain top-k needs.
-        s_sel = jax.lax.associative_scan(jnp.minimum, s_raw, axis=1)
-
-        flat_sel = s_sel.reshape(-1)  # [N*J]
-        flat_raw = s_raw.reshape(-1)
-        k_eff = min(k, flat_sel.shape[0])  # tiny clusters: < k slots total
-        # node-major flattening keeps each shard's rows contiguous in
-        # flat index space, so the hierarchical reduction applies as-is
-        top_sel, top_idx = _topk_nodes(flat_sel, k_eff, n_shards)
-        if k_eff < k:
-            pad = k - k_eff
-            top_sel = jnp.concatenate(
-                [top_sel, jnp.full(pad, -jnp.inf, top_sel.dtype)]
+        with jax.named_scope("fit_score"):
+            num, den, fits = _score_planes(
+                capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
+                caps, algorithm_spread, max_j, jitter=jitter,
             )
-            top_idx = jnp.concatenate([top_idx, jnp.zeros(pad, top_idx.dtype)])
-        # report the TRUE (unclamped) score of each chosen (n, j) — the
-        # AllocMetric the oracle would have recorded for that placement
-        top_raw = flat_raw[top_idx]
-        node_rows = (top_idx // max_j).astype(jnp.int32)
-        ok = top_sel > -jnp.inf  # caller slices [:count] vs overflow
-        return jnp.where(ok, node_rows, -1), jnp.where(ok, top_raw, -jnp.inf)
+            s_raw = jnp.where(fits, num / den, -jnp.inf)
+            # Selection runs on the running-min clamp: it restores the
+            # prefix rule "(n,j) requires (n,j-1)" that plain top-k needs.
+            s_sel = jax.lax.associative_scan(jnp.minimum, s_raw, axis=1)
+
+        with jax.named_scope("top_k"):
+            flat_sel = s_sel.reshape(-1)  # [N*J]
+            flat_raw = s_raw.reshape(-1)
+            k_eff = min(k, flat_sel.shape[0])  # tiny clusters: < k slots
+            # node-major flattening keeps each shard's rows contiguous in
+            # flat index space, so the hierarchical reduction applies as-is
+            top_sel, top_idx = _topk_nodes(flat_sel, k_eff, n_shards)
+            if k_eff < k:
+                pad = k - k_eff
+                top_sel = jnp.concatenate(
+                    [top_sel, jnp.full(pad, -jnp.inf, top_sel.dtype)]
+                )
+                top_idx = jnp.concatenate(
+                    [top_idx, jnp.zeros(pad, top_idx.dtype)]
+                )
+        with jax.named_scope("pack_rows_scores"):
+            # report the TRUE (unclamped) score of each chosen (n, j) — the
+            # AllocMetric the oracle would have recorded for that placement
+            top_raw = flat_raw[top_idx]
+            node_rows = (top_idx // max_j).astype(jnp.int32)
+            ok = top_sel > -jnp.inf  # caller slices [:count] vs overflow
+            return (
+                jnp.where(ok, node_rows, -1),
+                jnp.where(ok, top_raw, -jnp.inf),
+            )
 
     choices, scores = jax.vmap(one_group)(
         asks, eligible, job_counts, desired_totals, penalty_nodes,
@@ -380,9 +390,11 @@ def place_closed_form_kernel(
     )
     # one fused [G, 2k] i32 result: one device→host fetch per pass
     # instead of two, scores riding bitcast alongside rows
-    return jnp.concatenate(
-        [choices, jax.lax.bitcast_convert_type(scores, jnp.int32)], axis=1
-    )
+    with jax.named_scope("pack_rows_scores"):
+        return jnp.concatenate(
+            [choices, jax.lax.bitcast_convert_type(scores, jnp.int32)],
+            axis=1,
+        )
 
 
 # -- gather-scan (spread / distinct_property groups) -------------------------
@@ -477,10 +489,11 @@ def place_value_scan_kernel(
         ask, elig, jc0, dt, pen, aff, has_aff, dh, caps,
         vids, c0, desired, vcaps, weights, kinds, count,
     ):
-        num, den, fits = _score_planes(
-            capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
-            caps, algorithm_spread, max_j, jitter=jitter,
-        )
+        with jax.named_scope("fit_score"):
+            num, den, fits = _score_planes(
+                capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
+                caps, algorithm_spread, max_j, jitter=jitter,
+            )
         n = num.shape[0]
         is_spread = (kinds == BLOCK_TARGET_SPREAD) | (kinds == BLOCK_EVEN_SPREAD)
         has_spread_any = jnp.any(is_spread)
@@ -535,9 +548,10 @@ def place_value_scan_kernel(
             )
 
         state0 = (jnp.zeros(n, dtype=jnp.int32), c0)
-        _, (choices, scores) = jax.lax.scan(
-            step, state0, jnp.arange(max_steps)
-        )
+        with jax.named_scope("spread_loop"):
+            _, (choices, scores) = jax.lax.scan(
+                step, state0, jnp.arange(max_steps)
+            )
         return choices, scores
 
     return jax.vmap(one_group)(
@@ -605,10 +619,11 @@ def place_spread_chunked_kernel(
         ask, elig, jc0, dt, pen, aff, has_aff, dh, caps,
         vids, c0, desired, vcaps, weights, kinds, count,
     ):
-        num, den, fits = _score_planes(
-            capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
-            caps, algorithm_spread, max_j, jitter=jitter,
-        )
+        with jax.named_scope("fit_score"):
+            num, den, fits = _score_planes(
+                capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
+                caps, algorithm_spread, max_j, jitter=jitter,
+            )
         n = num.shape[0]
         nb = vids.shape[0]
         is_spread = (kinds == BLOCK_TARGET_SPREAD) | (kinds == BLOCK_EVEN_SPREAD)
@@ -680,10 +695,12 @@ def place_spread_chunked_kernel(
             c0,
             jnp.zeros((), dtype=jnp.int32),
         )
-        _, (choices, scores) = jax.lax.scan(
-            step, state0, None, length=n_chunks
-        )
-        return choices.reshape(-1), scores.reshape(-1)
+        with jax.named_scope("spread_loop"):
+            _, (choices, scores) = jax.lax.scan(
+                step, state0, None, length=n_chunks
+            )
+        with jax.named_scope("pack_rows_scores"):
+            return choices.reshape(-1), scores.reshape(-1)
 
     return jax.vmap(one_group)(
         asks, eligible, job_counts, desired_totals, penalty_nodes,
@@ -745,10 +762,11 @@ def place_spread_opv_kernel(
         ask, elig, jc0, dt, pen, aff, has_aff, dh, caps,
         vids, c0, desired, vcaps, weights, kinds, eidx, count,
     ):
-        num, den, fits = _score_planes(
-            capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
-            caps, algorithm_spread, max_j, jitter=jitter,
-        )
+        with jax.named_scope("fit_score"):
+            num, den, fits = _score_planes(
+                capacity, used0, ask, elig, jc0, dt, pen, aff, has_aff, dh,
+                caps, algorithm_spread, max_j, jitter=jitter,
+            )
         n = num.shape[0]
         nb = vids.shape[0]
         nv = c0.shape[1]
@@ -863,7 +881,8 @@ def place_spread_opv_kernel(
                 jnp.where(seg_plane, score1[None, :], -jnp.inf), axis=1
             )
             seg_max = jnp.where(seg_allowed, seg_max, -jnp.inf)
-            vals, vsel = jax.lax.top_k(seg_max, k_seg - 1)
+            with jax.named_scope("top_k"):
+                vals, vsel = jax.lax.top_k(seg_max, k_seg - 1)
             take_r = (
                 jnp.arange(k_seg - 1) + n_placed + ok0.astype(jnp.int32)
                 < count
@@ -905,10 +924,12 @@ def place_spread_opv_kernel(
             c0,
             jnp.zeros((), dtype=jnp.int32),
         )
-        _, (choices, scores) = jax.lax.scan(
-            step, state0, None, length=n_chunks
-        )
-        return choices.reshape(-1), scores.reshape(-1)
+        with jax.named_scope("spread_loop"):
+            _, (choices, scores) = jax.lax.scan(
+                step, state0, None, length=n_chunks
+            )
+        with jax.named_scope("pack_rows_scores"):
+            return choices.reshape(-1), scores.reshape(-1)
 
     return jax.vmap(one_group)(
         asks, eligible, job_counts, desired_totals, penalty_nodes,
@@ -1181,6 +1202,21 @@ class PlacementKernel:
         mp = cfg.n_node_shards
         return mp if mp > 1 and pn % mp == 0 else 1
 
+    def _upload(self, cluster, used0, batch, jitter, cfg):
+        """One kernel call's host→device transfers, as the stage
+        ``place.upload``: capacity, usage, the batch, the tie-break
+        jitter and the algorithm flag."""
+        with _tracer.span("place.upload"):
+            return (
+                self._capacity_dev(cluster, cfg),
+                used_device(cluster, used0, cfg),
+                _device_batch(batch, cfg),
+                None
+                if jitter is None
+                else shard_put(jitter, ("nodes",), cfg),
+                jnp.asarray(self.algorithm_spread),
+            )
+
     @staticmethod
     def _capacity_dev(cluster, cfg):
         """The DeviceStateCache's per-shard-refreshed capacity buffer
@@ -1253,8 +1289,12 @@ class PlacementKernel:
                 fast.append(i)
         out: list[Optional[PlacementResult]] = [None] * len(asks)
         # the span carries the routing split so a trace shows WHICH
-        # kernel family scored each pass (jit-level detail — compile
-        # events, shapes — attaches underneath via traced_jit's hooks)
+        # kernel family scored each pass. Its children are the stages of
+        # each family's call: place.assemble (host batch), place.upload,
+        # kernel:<name> (the dispatch, from traced_jit's hook),
+        # place.pull (the blocking fetch: device wait + transfer).
+        # ``narrowed``: lanes that decorrelation confined to a stripe or
+        # a worker's slice of the nodes; the rest score the full set
         with _tracer.span(
             "kernel.place",
             tags={
@@ -1263,6 +1303,7 @@ class PlacementKernel:
                 "chunked": len(chunked),
                 "opv": len(opv),
                 "scan": len(scan),
+                "narrowed": sum(w is not a for w, a in zip(work, asks)),
             },
         ):
             for idxs, fn in (
@@ -1290,28 +1331,29 @@ class PlacementKernel:
             # not part of the score semantics being explained.
             from ..obs.explain import explain_group
 
-            sharded = self.mesh_cfg().n_node_shards > 1
-            for a, res in zip(asks, out):
-                if res is not None:
-                    cand = None
-                    if sharded:
-                        # node axis sharded: rank only the candidate
-                        # columns the kernel actually surfaced (primary +
-                        # overflow) instead of gathering full score rows
-                        # back to host — the per-shard top-k union
-                        # provably contains every global winner
-                        cand = np.unique(
-                            np.concatenate(
-                                [res.node_rows, res.overflow_rows]
+            with _tracer.span("explain", tags={"step": "groups"}):
+                sharded = self.mesh_cfg().n_node_shards > 1
+                for a, res in zip(asks, out):
+                    if res is not None:
+                        cand = None
+                        if sharded:
+                            # node axis sharded: rank only the candidate
+                            # columns the kernel actually surfaced (primary +
+                            # overflow) instead of gathering full score rows
+                            # back to host — the per-shard top-k union
+                            # provably contains every global winner
+                            cand = np.unique(
+                                np.concatenate(
+                                    [res.node_rows, res.overflow_rows]
+                                )
                             )
+                            cand = cand[cand >= 0]
+                        res.explanation = explain_group(
+                            cluster, a, used0,
+                            algorithm=self.algorithm,
+                            algorithm_spread=self.algorithm_spread,
+                            candidate_rows=cand,
                         )
-                        cand = cand[cand >= 0]
-                    res.explanation = explain_group(
-                        cluster, a, used0,
-                        algorithm=self.algorithm,
-                        algorithm_spread=self.algorithm_spread,
-                        candidate_rows=cand,
-                    )
         return out
 
     @staticmethod
@@ -1380,24 +1422,26 @@ class PlacementKernel:
                 )
             return out
 
-        real_n = len(asks)
-        asks = _pad_group_axis(asks, pn)
-        batch = _shared_batch(asks, pn)
+        with _tracer.span("place.assemble"):
+            real_n = len(asks)
+            asks = _pad_group_axis(asks, pn)
+            batch = _shared_batch(asks, pn)
         cfg = self.mesh_cfg()
-        fused = np.array(
-            place_closed_form_kernel(
-                self._capacity_dev(cluster, cfg),
-                used_device(cluster, used0, cfg),
-                **_device_batch(batch, cfg),
-                algorithm_spread=jnp.asarray(self.algorithm_spread),
-                max_j=max_j,
-                k=k,
-                jitter=None
-                if jitter is None
-                else shard_put(jitter, ("nodes",), cfg),
-                n_shards=self._n_shards(pn),
-            )
+        capacity, used, dev_batch, jitter_dev, spread = self._upload(
+            cluster, used0, batch, jitter, cfg
         )
+        packed = place_closed_form_kernel(
+            capacity,
+            used,
+            **dev_batch,
+            algorithm_spread=spread,
+            max_j=max_j,
+            k=k,
+            jitter=jitter_dev,
+            n_shards=self._n_shards(pn),
+        )
+        with _tracer.span("place.pull"):
+            fused = np.array(packed)
         choices = fused[:, :k]  # writable copies: repair mutates rows
         scores = fused[:, k:].view(np.float32)
         return [
@@ -1419,33 +1463,35 @@ class PlacementKernel:
         from .flatten import pad_value_blocks
 
         pn = cluster.padded_n
-        real_n = len(asks)
-        asks = _pad_group_axis(asks, pn)
-        max_count = max(a.count for a in asks)
-        max_steps = _steps_bucket(max(max_count + overflow, 1))
-        max_j = self._max_j(cluster, asks)
+        with _tracer.span("place.assemble"):
+            real_n = len(asks)
+            asks = _pad_group_axis(asks, pn)
+            max_count = max(a.count for a in asks)
+            max_steps = _steps_bucket(max(max_count + overflow, 1))
+            max_j = self._max_j(cluster, asks)
 
-        batch = _shared_batch(asks, pn)
-        # emit overflow candidates past each lane's primary count
-        batch["counts"] = np.minimum(
-            batch["counts"] + overflow, max_steps
-        ).astype(np.int32)
-        # zero-count padding lanes stay inert (eligible nowhere)
-        batch["counts"] = np.where(
-            np.array([a.count for a in asks]) > 0, batch["counts"], 0
-        ).astype(np.int32)
-        batch.update(pad_value_blocks([a.blocks for a in asks], pn))
+            batch = _shared_batch(asks, pn)
+            # emit overflow candidates past each lane's primary count
+            batch["counts"] = np.minimum(
+                batch["counts"] + overflow, max_steps
+            ).astype(np.int32)
+            # zero-count padding lanes stay inert (eligible nowhere)
+            batch["counts"] = np.where(
+                np.array([a.count for a in asks]) > 0, batch["counts"], 0
+            ).astype(np.int32)
+            batch.update(pad_value_blocks([a.blocks for a in asks], pn))
         cfg = self.mesh_cfg()
+        capacity, used, dev_batch, jitter_dev, spread = self._upload(
+            cluster, used0, batch, jitter, cfg
+        )
         choices, scores = place_value_scan_kernel(
-            self._capacity_dev(cluster, cfg),
-            used_device(cluster, used0, cfg),
-            **_device_batch(batch, cfg),
-            algorithm_spread=jnp.asarray(self.algorithm_spread),
+            capacity,
+            used,
+            **dev_batch,
+            algorithm_spread=spread,
             max_j=max_j,
             max_steps=max_steps,
-            jitter=None
-            if jitter is None
-            else shard_put(jitter, ("nodes",), cfg),
+            jitter=jitter_dev,
         )
         return self._unpack_coupled(choices, scores, asks[:real_n], overflow)
 
@@ -1458,35 +1504,40 @@ class PlacementKernel:
         from .flatten import pad_value_blocks
 
         pn = cluster.padded_n
-        real_n = len(asks)
-        asks = _pad_group_axis(asks, pn)
-        max_count = max(a.count for a in asks)
-        max_j = self._max_j(cluster, asks)
-        # round chunk count to a multiple of 4, not a power of two — the
-        # sequential depth is the dominant cost and 2× overshoot is real
-        # wall-clock; a handful of extra compile variants is not
-        n_chunks = max(4, -(-max(-(-(max_count + overflow) // CHUNK), 1) // 4) * 4)
+        with _tracer.span("place.assemble"):
+            real_n = len(asks)
+            asks = _pad_group_axis(asks, pn)
+            max_count = max(a.count for a in asks)
+            max_j = self._max_j(cluster, asks)
+            # round chunk count to a multiple of 4, not a power of two —
+            # the sequential depth is the dominant cost and 2× overshoot
+            # is real wall-clock; a handful of extra compile variants is
+            # not
+            n_chunks = max(
+                4, -(-max(-(-(max_count + overflow) // CHUNK), 1) // 4) * 4
+            )
 
-        batch = _shared_batch(asks, pn)
-        batch["counts"] = np.minimum(
-            batch["counts"] + overflow, n_chunks * CHUNK
-        ).astype(np.int32)
-        batch["counts"] = np.where(
-            np.array([a.count for a in asks]) > 0, batch["counts"], 0
-        ).astype(np.int32)
-        batch.update(pad_value_blocks([a.blocks for a in asks], pn))
+            batch = _shared_batch(asks, pn)
+            batch["counts"] = np.minimum(
+                batch["counts"] + overflow, n_chunks * CHUNK
+            ).astype(np.int32)
+            batch["counts"] = np.where(
+                np.array([a.count for a in asks]) > 0, batch["counts"], 0
+            ).astype(np.int32)
+            batch.update(pad_value_blocks([a.blocks for a in asks], pn))
         cfg = self.mesh_cfg()
+        capacity, used, dev_batch, jitter_dev, spread = self._upload(
+            cluster, used0, batch, jitter, cfg
+        )
         choices, scores = place_spread_chunked_kernel(
-            self._capacity_dev(cluster, cfg),
-            used_device(cluster, used0, cfg),
-            **_device_batch(batch, cfg),
-            algorithm_spread=jnp.asarray(self.algorithm_spread),
+            capacity,
+            used,
+            **dev_batch,
+            algorithm_spread=spread,
             max_j=max_j,
             chunk=CHUNK,
             n_chunks=n_chunks,
-            jitter=None
-            if jitter is None
-            else shard_put(jitter, ("nodes",), cfg),
+            jitter=jitter_dev,
             n_shards=self._n_shards(pn),
         )
         return self._unpack_coupled(choices, scores, asks[:real_n], overflow)
@@ -1500,75 +1551,77 @@ class PlacementKernel:
         from .flatten import pad_value_blocks
 
         pn = cluster.padded_n
-        real_n = len(asks)
-        asks = _pad_group_axis(asks, pn)
-        max_j = self._max_j(cluster, asks)
+        with _tracer.span("place.assemble"):
+            real_n = len(asks)
+            asks = _pad_group_axis(asks, pn)
+            max_j = self._max_j(cluster, asks)
 
-        batch = _shared_batch(asks, pn)
-        blocks_list = [a.blocks for a in asks]
-        batch.update(pad_value_blocks(blocks_list, pn))
-        nv = batch["block_counts0"].shape[2]
-        k_seg = min(CHUNK, nv + 1)
+            batch = _shared_batch(asks, pn)
+            blocks_list = [a.blocks for a in asks]
+            batch.update(pad_value_blocks(blocks_list, pn))
+            nv = batch["block_counts0"].shape[2]
+            k_seg = min(CHUNK, nv + 1)
 
-        # per-lane: dominant even block + how many picks one chunk can
-        # actually yield (active values of that block, +1 for value-less
-        # nodes) — lanes with few values need more sequential chunks
-        enforce_idx = np.zeros(len(asks), dtype=np.int32)
-        lane_steps = 1
-        for gi, a in enumerate(asks):
-            b = a.blocks
-            if b is None or a.count <= 0:
-                continue
-            even = np.flatnonzero(b.kinds == BLOCK_EVEN_SPREAD)
-            if even.size:
-                enforce_idx[gi] = even[np.argmax(b.weights[even])]
-            ev = b.value_ids[enforce_idx[gi]]
-            # a step can only yield picks from segments that hold at
-            # least one ELIGIBLE node (pad rows and unreachable values
-            # yield nothing — counting them under-provisions n_chunks
-            # and truncates the lane's placements)
-            elig = a.eligible
-            v_act = len(np.unique(ev[(ev >= 0) & elig])) + int(
-                ((ev < 0) & elig).any()
-            )
-            per_chunk = max(1, min(k_seg, v_act))
-            lane_steps = max(
-                lane_steps, -(-(a.count + overflow) // per_chunk)
-            )
-        # multiple-of-4 rounding, not power-of-two (sequential depth is
-        # the dominant cost; see _place_spread_chunked). +2 slack chunks:
-        # the rotation guard makes a chunk starting from uneven counts
-        # yield fewer than v_act picks; the host repair re-score rescues
-        # any residue, but slack keeps that path cold.
-        n_chunks = max(4, -(-(lane_steps + 2) // 4) * 4)
-        # J bound tightened by the kernel's own structure: each chunk
-        # step picks DISTINCT nodes (the first pick and the one-per-value
-        # segment picks are disjoint), so one node gains at most one
-        # instance per step — head_j never exceeds n_chunks. At the
-        # config-3 shape this cuts the [N, J] planes ~3× (J 80 → 24):
-        # plane construction dominates the pass, so it's ~linear
-        # wall-clock.
-        max_j = min(max_j, self._j_bucket(n_chunks + 1))
+            # per-lane: dominant even block + how many picks one chunk can
+            # actually yield (active values of that block, +1 for value-less
+            # nodes) — lanes with few values need more sequential chunks
+            enforce_idx = np.zeros(len(asks), dtype=np.int32)
+            lane_steps = 1
+            for gi, a in enumerate(asks):
+                b = a.blocks
+                if b is None or a.count <= 0:
+                    continue
+                even = np.flatnonzero(b.kinds == BLOCK_EVEN_SPREAD)
+                if even.size:
+                    enforce_idx[gi] = even[np.argmax(b.weights[even])]
+                ev = b.value_ids[enforce_idx[gi]]
+                # a step can only yield picks from segments that hold at
+                # least one ELIGIBLE node (pad rows and unreachable values
+                # yield nothing — counting them under-provisions n_chunks
+                # and truncates the lane's placements)
+                elig = a.eligible
+                v_act = len(np.unique(ev[(ev >= 0) & elig])) + int(
+                    ((ev < 0) & elig).any()
+                )
+                per_chunk = max(1, min(k_seg, v_act))
+                lane_steps = max(
+                    lane_steps, -(-(a.count + overflow) // per_chunk)
+                )
+            # multiple-of-4 rounding, not power-of-two (sequential depth is
+            # the dominant cost; see _place_spread_chunked). +2 slack chunks:
+            # the rotation guard makes a chunk starting from uneven counts
+            # yield fewer than v_act picks; the host repair re-score rescues
+            # any residue, but slack keeps that path cold.
+            n_chunks = max(4, -(-(lane_steps + 2) // 4) * 4)
+            # J bound tightened by the kernel's own structure: each chunk
+            # step picks DISTINCT nodes (the first pick and the one-per-value
+            # segment picks are disjoint), so one node gains at most one
+            # instance per step — head_j never exceeds n_chunks. At the
+            # config-3 shape this cuts the [N, J] planes ~3× (J 80 → 24):
+            # plane construction dominates the pass, so it's ~linear
+            # wall-clock.
+            max_j = min(max_j, self._j_bucket(n_chunks + 1))
 
-        batch["counts"] = np.minimum(
-            batch["counts"] + overflow, n_chunks * k_seg
-        ).astype(np.int32)
-        batch["counts"] = np.where(
-            np.array([a.count for a in asks]) > 0, batch["counts"], 0
-        ).astype(np.int32)
+            batch["counts"] = np.minimum(
+                batch["counts"] + overflow, n_chunks * k_seg
+            ).astype(np.int32)
+            batch["counts"] = np.where(
+                np.array([a.count for a in asks]) > 0, batch["counts"], 0
+            ).astype(np.int32)
         cfg = self.mesh_cfg()
+        capacity, used, dev_batch, jitter_dev, spread = self._upload(
+            cluster, used0, batch, jitter, cfg
+        )
         choices, scores = place_spread_opv_kernel(
-            self._capacity_dev(cluster, cfg),
-            used_device(cluster, used0, cfg),
-            **_device_batch(batch, cfg),
+            capacity,
+            used,
+            **dev_batch,
             enforce_idx=jnp.asarray(enforce_idx),
-            algorithm_spread=jnp.asarray(self.algorithm_spread),
+            algorithm_spread=spread,
             max_j=max_j,
             k_seg=k_seg,
             n_chunks=n_chunks,
-            jitter=None
-            if jitter is None
-            else shard_put(jitter, ("nodes",), cfg),
+            jitter=jitter_dev,
         )
         return self._unpack_coupled(choices, scores, asks[:real_n], overflow)
 
@@ -1579,8 +1632,9 @@ class PlacementKernel:
         intersperse empty slots between chunks (a chunk is capped at one
         pick per value, not by feasibility), so valid picks are compacted
         rather than sliced positionally."""
-        choices = np.array(choices)
-        scores = np.array(scores)
+        with _tracer.span("place.pull"):
+            choices = np.array(choices)
+            scores = np.array(scores)
         out = []
         for gi, a in enumerate(asks):
             row = choices[gi]
@@ -1720,8 +1774,6 @@ def _decorrelate_lanes(
         # this worker's node slice first (cross-worker disjointness),
         # provided it still holds the lane's ask comfortably — else fall
         # back to the full set and let repair/applier arbitrate
-        from ..utils.metrics import global_metrics as _metrics
-
         base_elig = a.eligible
         if worker_universe is not None:
             wu_elig = a.eligible & worker_universe
@@ -1731,9 +1783,6 @@ def _decorrelate_lanes(
                 and values_reachable(wu_elig)
             ):
                 base_elig = wu_elig
-                _metrics.incr("nomad.kernel.lane_universe_applied")
-            else:
-                _metrics.incr("nomad.kernel.lane_universe_skipped")
         jn_w = np.where(base_elig, jn, 0.0)
         total_elig = int(base_elig.sum())
         slots = float(jn_w.sum())
@@ -1762,15 +1811,12 @@ def _decorrelate_lanes(
         if ok:
             ok = values_reachable(elig)
         if ok:
-            _metrics.incr("nomad.kernel.lane_striped")
             out.append(replace(a, eligible=elig))
         elif base_elig is not a.eligible:
             # stripe rejected but the worker slice is viable: keep
             # cross-worker disjointness at least
-            _metrics.incr("nomad.kernel.lane_universe_only")
             out.append(replace(a, eligible=base_elig))
         else:
-            _metrics.incr("nomad.kernel.lane_full_set")
             out.append(a)
     return out
 

@@ -17,9 +17,20 @@ cannot carry a trace end to end. The model here:
   stamps its submit-plan span's context onto the pending plan, the
   applier thread attaches it, and the plan-apply spans parent correctly.
 - ``add_span`` records an interval *retroactively* — for phases measured
-  before the trace existed (broker dequeue) or shared by a whole batch
-  (one device pass scoring 16 evals is recorded into each member's
-  trace, tagged ``shared``).
+  before the trace existed (register, broker dequeue), on a thread with
+  no current span (the applier's merged apply) or shared by a whole
+  batch (a pass's top-level phases are copied into each member's trace,
+  tagged ``shared``). The caller hands over where the interval started;
+  nothing is counted back from the moment of the call.
+- ``phase(name)`` opens a top-level phase of a scheduling pass: a span
+  that carries the pass tags (``pass_id``, ``path``, ``evals``) the
+  worker put on the trace's root, so every phase of every member of one
+  pass is found by the same id.
+
+One clock: every start is taken on ``perf_counter``, the clock the
+durations use, and exported as ``start_unix`` = one wall anchor read when
+the tracer is built + the monotonic offset. A step of the host's wall
+clock moves no span against another.
 
 Disabled mode (``set_enabled(False)``) keeps every call a cheap no-op
 but ``span(timer=...)`` still feeds the metrics sample — turning tracing
@@ -68,33 +79,31 @@ class Span:
         "start_unix",
         "duration_ms",
         "status",
-        "_t0",
+        "t0",
     )
 
     def __init__(
         self,
         trace_id: str,
         name: str,
-        parent_id: Optional[int] = None,
-        tags: Optional[dict] = None,
-        clock=None,
+        parent_id: Optional[int],
+        tags: Optional[dict],
+        t0: float,
+        start_unix: float,
     ):
         self.trace_id = trace_id
         self.span_id = next(_ids)
         self.parent_id = parent_id
         self.name = name
         self.tags = dict(tags) if tags else {}
-        # injectable wall clock (NTA008): the tracer threads its own so
-        # estimator/SLO windows over span streams replay under FakeClock
-        wall = clock if clock is not None else time.time
-        self.start_unix = wall()
+        self.start_unix = start_unix
         self.duration_ms: Optional[float] = None
         self.status = "ok"
-        self._t0 = time.perf_counter()
+        self.t0 = t0  # perf_counter stamp of the start
 
     def finish(self, status: Optional[str] = None) -> None:
         if self.duration_ms is None:
-            self.duration_ms = (time.perf_counter() - self._t0) * 1000.0
+            self.duration_ms = (time.perf_counter() - self.t0) * 1000.0
         if status is not None:
             self.status = status
 
@@ -122,6 +131,11 @@ class _Trace:
         self.spans: list[Span] = [root]
 
 
+# root tags that name the pass an eval was scheduled in; ``phase`` copies
+# them onto every top-level phase span
+PASS_TAGS = ("pass_id", "path", "evals")
+
+
 class Tracer:
     def __init__(self, recorder=None, clock=None):
         self._lock = threading.Lock()
@@ -130,8 +144,21 @@ class Tracer:
         self._enabled = True
         self._dropped = 0
         self.recorder = recorder
-        # wall clock for span start stamps (injectable for FakeClock tests)
-        self._clock = clock if clock is not None else time.time
+        # injectable wall clock (NTA008): estimator/SLO windows over span
+        # streams replay under FakeClock
+        self._clock = clock
+        # the one wall anchor: start_unix = anchor + perf_counter stamp
+        wall = clock if clock is not None else time.time
+        self._anchor = wall() - time.perf_counter()
+
+    def unix_at(self, t: float) -> float:
+        """The exported wall time of the ``perf_counter`` stamp ``t``."""
+        if self._clock is not None:
+            return self._clock() - (time.perf_counter() - t)
+        return self._anchor + t
+
+    def _span(self, trace_id, name, parent_id, tags, t0) -> Span:
+        return Span(trace_id, name, parent_id, tags, t0, self.unix_at(t0))
 
     # -- enable switch -----------------------------------------------------
     @property
@@ -167,7 +194,9 @@ class Tracer:
             if tr is None:
                 tr = _Trace(
                     trace_id,
-                    Span(trace_id, name, tags=tags, clock=self._clock),
+                    self._span(
+                        trace_id, name, None, tags, time.perf_counter()
+                    ),
                 )
                 self._active[trace_id] = tr
             elif tags:
@@ -283,7 +312,7 @@ class Tracer:
         active — callers never branch on tracing state. ``timer`` names a
         legacy metrics sample fed unconditionally, tracing on or off."""
         t0 = time.perf_counter()
-        sp = self._open(name, parent, tags)
+        sp = self._open(name, parent, tags, t0)
         try:
             yield sp
         except BaseException:
@@ -298,7 +327,26 @@ class Tracer:
                 sp.duration_ms = dt * 1000.0
                 self._pop(sp)
 
-    def _open(self, name, parent, tags) -> Optional[Span]:
+    def phase(
+        self,
+        name: str,
+        *,
+        tags: Optional[dict] = None,
+        timer: Optional[str] = None,
+    ):
+        """``span`` for a top-level phase of a scheduling pass: the span
+        carries the pass tags of its trace's root."""
+        cur = self.current()
+        tr = self._active.get(cur.trace_id) if cur is not None else None
+        if tr is not None:
+            root = tr.root.tags
+            tags = {
+                **{k: root[k] for k in PASS_TAGS if k in root},
+                **(tags or {}),
+            }
+        return self.span(name, tags=tags, timer=timer)
+
+    def _open(self, name, parent, tags, t0) -> Optional[Span]:
         if not self._enabled:
             return None
         if parent is None:
@@ -311,10 +359,7 @@ class Tracer:
             with self._lock:
                 self._dropped += 1
             return None
-        sp = Span(
-            tr.trace_id, name, parent_id=parent.span_id, tags=tags,
-            clock=self._clock,
-        )
+        sp = self._span(tr.trace_id, name, parent.span_id, tags, t0)
         tr.spans.append(sp)
         self._stack().append(sp)
         return sp
@@ -325,12 +370,15 @@ class Tracer:
         name: str,
         duration_s: float,
         *,
+        start: Optional[float] = None,
         parent=None,
         tags: Optional[dict] = None,
     ) -> Optional[Span]:
         """Record an already-measured interval into a trace: the broker
         dequeue (measured before any eval id existed) and batch-shared
-        phases (one device pass recorded into each member's tree)."""
+        phases (a pass's phases copied into each member's tree).
+        ``start`` is the ``perf_counter`` stamp at which the interval
+        began; without it the interval ended now."""
         if not self._enabled:
             return None
         tr = self._active.get(trace_id)
@@ -339,8 +387,9 @@ class Tracer:
                 self._dropped += 1
             return None
         pid = parent.span_id if parent is not None else tr.root.span_id
-        sp = Span(trace_id, name, parent_id=pid, tags=tags, clock=self._clock)
-        sp.start_unix -= duration_s
+        if start is None:
+            start = time.perf_counter() - duration_s
+        sp = self._span(trace_id, name, pid, tags, start)
         sp.duration_ms = duration_s * 1000.0
         tr.spans.append(sp)
         return sp
@@ -350,6 +399,7 @@ class Tracer:
         name: str,
         seconds: float,
         *,
+        start: Optional[float] = None,
         traced: bool = False,
         shape: Optional[str] = None,
     ) -> Optional[Span]:
@@ -365,6 +415,7 @@ class Tracer:
             cur.trace_id,
             f"kernel:{name}",
             seconds,
+            start=start,
             parent=cur,
             tags=tags,
         )

@@ -178,34 +178,41 @@ class GenericScheduler:
 
     # -- one attempt ------------------------------------------------------
     def _process_once(self) -> tuple[bool, bool]:
-        """Returns (done, should_retry)."""
-        placements = self._start_attempt()
-        if placements and self.job is not None:
-            ct, tg_order = self._build_group_asks(placements)
-            asks = [t[3] for t in tg_order]
-            if self.node_filter is not None and asks:
-                mask = self.node_filter(ct)
-                for a in asks:
-                    a.eligible &= mask
-            used_override = None
-            if self.overlay is not None:
-                used_override = self.overlay.begin_pass(ct)
-            try:
-                with tracer.span(
-                    "kernel_score",
-                    tags={"lanes": len(asks), "explain": self._explain},
-                ):
-                    results = self.kernel.place(
-                        ct, asks, used_override=used_override,
-                        explain=self._explain,
-                    )
-                    # the repair walk is also the single-eval safety net:
-                    # it resolves cross-TG conflicts within this plan and
-                    # re-places kernel shortfalls (e.g. chunked-path
-                    # truncation) by exact host re-score before they read
-                    # as placement failures
-                    from ..device.score import repair_batch_conflicts
+        """Returns (done, should_retry). Writes the phases of a solo
+        pass, the same ones the worker writes around a batched pass."""
+        asks: list = []
+        with tracer.phase("prepare"):
+            placements = self._start_attempt()
+            if placements and self.job is not None:
+                ct, tg_order = self._build_group_asks(placements)
+                asks = [t[3] for t in tg_order]
+                if self.node_filter is not None and asks:
+                    mask = self.node_filter(ct)
+                    for a in asks:
+                        a.eligible &= mask
+        if not asks:
+            return self._submit_attempt()
+        used_override = None
+        if self.overlay is not None:
+            used_override = self.overlay.begin_pass(ct)
+        try:
+            with tracer.phase(
+                "invoke_scheduler",
+                timer="nomad.worker.invoke_scheduler",
+                tags={"lanes": len(asks), "explain": self._explain},
+            ):
+                results = self.kernel.place(
+                    ct, asks, used_override=used_override,
+                    explain=self._explain,
+                )
+                # the repair walk is also the single-eval safety net:
+                # it resolves cross-TG conflicts within this plan and
+                # re-places kernel shortfalls (e.g. chunked-path
+                # truncation) by exact host re-score before they read
+                # as placement failures
+                from ..device.score import repair_batch_conflicts
 
+                with tracer.span("repair"):
                     repair_batch_conflicts(
                         ct, asks, results,
                         algorithm_spread=self.kernel.algorithm_spread,
@@ -216,30 +223,31 @@ class GenericScheduler:
                         fail_on_contention=True,
                         used_override=used_override,
                     )
-                    if self._explain:
-                        # repair moves rows in place, so provenance is
-                        # stamped from the POST-repair (= committed) rows
-                        from ..obs.explain import finalize_explanations
+                if self._explain:
+                    # repair moves rows in place, so provenance is
+                    # stamped from the POST-repair (= committed) rows
+                    from ..obs.explain import finalize_explanations
 
+                    with tracer.span("explain", tags={"step": "final"}):
                         finalize_explanations(
                             ct, asks, results, used_override=used_override
                         )
-                if self.overlay is not None:
-                    for a, res in zip(asks, results):
-                        rows = res.node_rows[res.node_rows >= 0]
-                        if rows.size:
-                            self.overlay.add_delta(ct, rows, a.ask)
+            if self.overlay is not None:
+                for a, res in zip(asks, results):
+                    rows = res.node_rows[res.node_rows >= 0]
+                    if rows.size:
+                        self.overlay.add_delta(ct, rows, a.ask)
+            with tracer.phase("build_plan"):
                 self._finish_placements(ct, tg_order, results)
                 self._adjust_queued()
-                # the pass marker is held through plan SUBMISSION: once
-                # released with the commit not yet applied, a concurrent
-                # worker's maybe_reset() could drop the overlay while
-                # these placements are still only predictions
-                return self._submit_attempt()
-            finally:
-                if self.overlay is not None:
-                    self.overlay.pass_finished()
-        return self._submit_attempt()
+            # the pass marker is held through plan SUBMISSION: once
+            # released with the commit not yet applied, a concurrent
+            # worker's maybe_reset() could drop the overlay while
+            # these placements are still only predictions
+            return self._submit_attempt()
+        finally:
+            if self.overlay is not None:
+                self.overlay.pass_finished()
 
     # -- batched multi-eval pass (SURVEY.md §7 step 5) --------------------
     def prepare_batch_attempt(self, evaluation: Evaluation, ct=None):

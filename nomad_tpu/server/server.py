@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Iterable, Optional
 
 from ..broker.blocked import BlockedEvals
@@ -473,6 +474,7 @@ class Server:
     def register_job(self, job: Job) -> Evaluation:
         """Job.Register (nomad/job_endpoint.go): upsert job + create eval
         in one commit, then enqueue."""
+        t_entry = time.perf_counter()  # the eval's ``register`` span
         validate_job(job)
         # overload gate BEFORE any state commit: a shed register raises
         # AdmissionRejected (HTTP: 429 + Retry-After) with nothing
@@ -507,7 +509,7 @@ class Server:
             self.periodic.add(job)
         if needs_eval:
             (ev,) = self._fresh_evals([ev])
-            self.eval_broker.enqueue(ev)
+            self.eval_broker.enqueue(ev, entered_at=t_entry)
         return ev
 
     def dispatch_job(
@@ -548,6 +550,7 @@ class Server:
         return child, ev
 
     def deregister_job(self, namespace: str, job_id: str) -> Optional[Evaluation]:
+        t_entry = time.perf_counter()  # the eval's ``register`` span
         job = self.store.job_by_id(namespace, job_id)
         if job is None:
             return None
@@ -571,7 +574,7 @@ class Server:
             "Job", "JobDeregistered", job_id, namespace, {"job_id": job_id}
         )
         (ev,) = self._fresh_evals([ev])
-        self.eval_broker.enqueue(ev)
+        self.eval_broker.enqueue(ev, entered_at=t_entry)
         return ev
 
     # -- API: nodes --------------------------------------------------------

@@ -24,9 +24,11 @@ mis-guess surfaces as a partial commit and an individual retry.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import threading
 import time
+from contextlib import contextmanager
 from typing import Optional
 
 import numpy as np
@@ -76,6 +78,57 @@ SCHEDULER_TYPES = ["service", "batch", "system", "sysbatch", "_core"]
 # extra pass dispatches while smaller passes commit sooner and cap the
 # p99 at one-quarter the device time.
 EVAL_BATCH_SIZE = 16
+
+
+class _PassTrace:
+    """The trace record of one scheduling pass, solo or batched. Every
+    member's root carries ``pass_id`` (``<worker>-<sequence>``), ``path``
+    and ``evals``; the first member is the pass's leader (``leader`` on
+    the roots, ``leader_eval`` naming it). The thread running the pass
+    activates the leader's trace, so what happens below a top-level
+    phase (flatten, the placement stages, the merged apply) is written
+    once, there; ``phase`` then copies the phase itself, same start and
+    duration, into the other members' trees — an operator reads one
+    eval's tree. A phase several members share is tagged ``shared``, and
+    a copy also ``leader_eval``."""
+
+    def __init__(self, worker_id: int, seq: int, path: str, evals: list):
+        self.leader = evals[0].id
+        self.members = [ev.id for ev in evals]
+        self.tags = {
+            "pass_id": f"{worker_id}-{seq}",
+            "path": path,
+            "evals": len(evals),
+        }
+        for eid in self.members:
+            tracer.begin(eid, tags={
+                **self.tags,
+                "leader": eid == self.leader,
+                "leader_eval": self.leader,
+            })
+
+    @contextmanager
+    def phase(self, name: str, *, tags=None, timer=None):
+        """A phase the whole pass shares: a real span in the leader's
+        trace, copied on exit into every other member's (each waited for
+        it, whatever became of its own eval in it)."""
+        if len(self.members) > 1:
+            tags = {**(tags or {}), "shared": True}
+        t0 = time.perf_counter()
+        sp = None
+        try:
+            with tracer.phase(name, tags=tags, timer=timer) as sp:
+                yield sp
+        finally:
+            if sp is not None:
+                copy, t0, dt = sp.tags, sp.t0, sp.duration_ms / 1000.0
+            else:  # the leader's trace is gone, or tracing is off
+                copy = {**self.tags, **(tags or {})}
+                dt = time.perf_counter() - t0
+            copy = {**copy, "leader_eval": self.leader}
+            for eid in self.members:
+                if eid != self.leader:
+                    tracer.add_span(eid, name, dt, start=t0, tags=copy)
 
 
 class _EvalBuffer:
@@ -131,7 +184,7 @@ class _TokenPlanner:
         server = self._worker.server
         # the enqueue captures this span's context onto the pending plan,
         # so the applier thread's plan_apply spans parent under it
-        with tracer.span(
+        with tracer.phase(
             "submit_plan", timer="nomad.worker.submit_plan"
         ) as sp:
             future = server.plan_queue.enqueue(plan)
@@ -140,7 +193,7 @@ class _TokenPlanner:
                 sp.tags["rejected_nodes"] = len(result.rejected_nodes)
         new_snapshot = None
         if result.refresh_index:
-            with tracer.span(
+            with tracer.phase(
                 "refresh_snapshot",
                 tags={"refresh_index": result.refresh_index},
             ):
@@ -170,6 +223,8 @@ class Worker:
     _eval_deadline: Optional[float] = None
     _eval_attempt_limit: int = 3
     _clock = staticmethod(time.time)
+    # pass sequence behind ``pass_id``; per worker once constructed
+    _pass_seq = itertools.count(1)
 
     def __init__(self, server, worker_id: int = 0, schedulers=None):
         self.server = server
@@ -201,6 +256,7 @@ class Worker:
             deadline if deadline > 0 else None
         )
         self._eval_attempt_limit: int = getattr(cfg, "eval_attempt_limit", 3)
+        self._pass_seq = itertools.count(1)
 
     def _planner(self, token: str) -> _TokenPlanner:
         p = _TokenPlanner(self, token)
@@ -306,9 +362,10 @@ class Worker:
                     t for t in self.schedulers
                     if t not in ("service", "batch")
                 ]
-            # pre-trace interval: no eval (hence no trace) exists until the
-            # dequeue returns — the sample feeds /v1/metrics directly and
-            # the span is attached retroactively per dequeued eval below
+            # the worker's blocked wait for work is idleness, not a cost of
+            # any eval: a sample for /v1/metrics and no span. Each eval's own
+            # stay in the broker comes back with it (take_stay) and becomes
+            # its ``dequeue`` span below
             # brownout lever: past the brownout point the batch worker
             # widens its dequeue window (bigger batch, longer wait) so
             # each device pass amortizes more evals instead of
@@ -338,12 +395,11 @@ class Worker:
                     # unblock any handoff-settled nodes (the next
                     # snapshot includes those committed placements)
                     ov = self._my_overlay()
-                    if ov.maybe_reset():
-                        metrics.incr("nomad.worker.pipeline_epoch_resets")
+                    ov.maybe_reset()
                     self._rebase_lanes(ov)
                 continue
             for ev, _token in batch:
-                queue_wait = self.server.eval_broker.take_queue_wait(ev.id)
+                stay = self.server.eval_broker.take_stay(ev.id)
                 root = tracer.begin(
                     ev.id,
                     tags={
@@ -356,16 +412,8 @@ class Worker:
                         "batch_size": len(batch),
                     },
                 )
-                if root is not None:
-                    tracer.add_span(
-                        ev.id,
-                        "dequeue",
-                        dequeue_s,
-                        tags={
-                            "queue_wait_ms": round(queue_wait * 1000.0, 3),
-                            "shared": len(batch) > 1,
-                        },
-                    )
+                if root is not None and stay is not None:
+                    self._trace_stay(ev.id, stay, len(batch) > 1)
             try:
                 if len(batch) == 1 and not lane_mode:
                     # batch accounting reconciliation: evals dequeued solo
@@ -395,12 +443,44 @@ class Worker:
                     tracer.finish(ev.id, status="nacked", error=repr(e))
         self._join_commit()
 
+    @staticmethod
+    def _trace_stay(eval_id: str, stay, shared: bool) -> None:
+        """The eval's time before the worker had it, where it was spent:
+        ``register`` (server entry point → enqueue) and ``dequeue`` (first
+        ready → handed to a worker; ``queue_wait_ms`` is its duration and
+        the three parts sum to it). Both precede the root, which opens at
+        the dequeue."""
+        if stay.register is not None:
+            t_entry, t_enqueue = stay.register
+            tracer.add_span(
+                eval_id, "register", t_enqueue - t_entry, start=t_entry
+            )
+        wait_ms = round(stay.wait_s * 1000.0, 3)
+        gate_ms = round(stay.gate_s * 1000.0, 3)
+        deferred_ms = round(stay.deferred_s * 1000.0, 3)
+        tracer.add_span(
+            eval_id,
+            "dequeue",
+            stay.wait_s,
+            start=stay.ready_at,
+            tags={
+                "queue_wait_ms": wait_ms,
+                "ready_wait_ms": round(wait_ms - gate_ms - deferred_ms, 3),
+                "gate_wait_ms": gate_ms,
+                "deferred_ms": deferred_ms,
+                "shared": shared,
+            },
+        )
+
     def _run_one(self, ev: Evaluation, token: str) -> None:
         planner = self._planner(token)
         # idempotent: run() already opened the trace for dequeued evals;
         # this covers direct callers (tests, batch single-path fallbacks
-        # keep appending to the tree they started in)
+        # keep appending to the tree they started in, as a pass of their
+        # own)
         tracer.begin(ev.id, tags={"job_id": ev.job_id, "type": ev.type})
+        _PassTrace(self.id, next(self._pass_seq), "solo", [ev])
+        metrics.incr("nomad.worker.passes_solo")
         try:
             with tracer.activate(ev.id):
                 self.process_eval(ev, planner)
@@ -437,6 +517,16 @@ class Worker:
         """Run a batch of evals through one combined device pass, then
         hand the commit to the pipeline thread and return — the NEXT
         pass's prepare + device time overlaps this pass's commit."""
+        ps = _PassTrace(
+            self.id, next(self._pass_seq), "batched", [ev for ev, _ in batch]
+        )
+        metrics.incr("nomad.worker.passes_batched")
+        with tracer.activate(ps.leader):
+            self._run_batch_pass(batch, ps)
+
+    def _run_batch_pass(
+        self, batch: list[tuple[Evaluation, str]], ps: _PassTrace
+    ) -> None:
         # Reap a finished commit thread and (only when NOTHING is in
         # flight anywhere) reset the shared overlay epoch — strictly
         # BEFORE the snapshot, so the snapshot taken next is guaranteed
@@ -453,34 +543,27 @@ class Worker:
         ):
             self._join_commit()
         overlay = self._my_overlay()
-        if overlay.maybe_reset():
-            metrics.incr("nomad.worker.pipeline_epoch_resets")
+        overlay.maybe_reset()
         lane_mode = self._lane_mode()
         if lane_mode:
             # a fresh epoch rebases this worker onto the committed
             # store — any nodes settled by peers' handoffs unblock now
             self._rebase_lanes(overlay)
-        t0 = time.perf_counter()
-        self.server.store.wait_for_index(
-            max(ev.modify_index for ev, _ in batch), timeout=5.0
-        )
-        wfi_s = time.perf_counter() - t0
-        metrics.measure("nomad.worker.wait_for_index", wfi_s)
-        t0 = time.perf_counter()
-        snapshot = self.server.store.snapshot()
-        # One ClusterTensors for the WHOLE batch: if each scheduler fetched
-        # its own, a concurrent worker advancing the cache generation
-        # mid-batch would hand later schedulers a transient build whose row
-        # order differs (sorted-by-id vs incremental append) — their masks
-        # would silently misalign with the capacity/used arrays in the
-        # combined kernel call.
-        ct = self.server.device_cache.tensors(snapshot)
-        snap_s = time.perf_counter() - t0
-        # shared phases happen once for the whole batch; record the same
-        # interval into every member's trace, tagged shared
-        for ev, _tok in batch:
-            tracer.add_span(ev.id, "wait_for_index", wfi_s, tags={"shared": True})
-            tracer.add_span(ev.id, "snapshot", snap_s, tags={"shared": True})
+        # shared phases happen once for the whole batch: ps.phase records
+        # them in the leader's trace and copies them to the other members
+        with ps.phase("wait_for_index", timer="nomad.worker.wait_for_index"):
+            self.server.store.wait_for_index(
+                max(ev.modify_index for ev, _ in batch), timeout=5.0
+            )
+        with ps.phase("snapshot"):
+            snapshot = self.server.store.snapshot()
+            # One ClusterTensors for the WHOLE batch: if each scheduler
+            # fetched its own, a concurrent worker advancing the cache
+            # generation mid-batch would hand later schedulers a transient
+            # build whose row order differs (sorted-by-id vs incremental
+            # append) — their masks would silently misalign with the
+            # capacity/used arrays in the combined kernel call.
+            ct = self.server.device_cache.tensors(snapshot)
 
         prepared = []  # (ev, token, sched, n_asks)
         all_asks: list = []
@@ -497,16 +580,16 @@ class Worker:
                 cache=self.server.device_cache,
                 overlay=overlay,
             )
-            t0 = time.perf_counter()
             try:
-                asks = sched.prepare_batch_attempt(ev, ct=ct)
+                # each member's own prepare, in its own trace
+                with tracer.activate(ev.id), tracer.phase("prepare"):
+                    asks = sched.prepare_batch_attempt(ev, ct=ct)
             except Exception as e:
                 log.exception("worker %d: batch prepare %s", self.id, ev.id)
                 count_swallowed("worker", e)
                 asks = None
                 singles.append((ev, token))
                 continue
-            tracer.add_span(ev.id, "prepare", time.perf_counter() - t0)
             if asks is None:
                 singles.append((ev, token))
             else:
@@ -541,14 +624,11 @@ class Worker:
             # this overlay is the worker's own; peers' in-flight state
             # is irrelevant by construction (disjoint lanes + claims).
             used_override = overlay.begin_pass(ct)
-            if used_override is not None:
-                metrics.incr("nomad.worker.pipeline_override_passes")
             try:
                 kernel = prepared[0][2].kernel
                 # all scheds in a batch share one scheduler config, so
                 # the first lane's explain gate speaks for the pass
                 explain = bool(getattr(prepared[0][2], "_explain", False))
-                t0 = time.perf_counter()
                 # decorrelate: each lane scores a disjoint node stripe
                 # (the vector analog of per-worker shuffle sampling,
                 # stack.go:74-90) so concurrent lanes stop argmaxing
@@ -559,63 +639,59 @@ class Worker:
                 # reference byte for byte, and the legacy cross-worker
                 # node-universe carving (decorrelate_workers) is retired
                 # — structural claims replace it.
-                results = kernel.place(
-                    ct,
-                    all_asks,
-                    decorrelate=True,
-                    decorrelate_salt=(
-                        self.server.lanes.lane_of_job(
-                            prepared[0][0].namespace, prepared[0][0].job_id
-                        )
-                        if lane_mode
-                        else self.id
-                    ),
-                    decorrelate_workers=(
-                        1
-                        if lane_mode
-                        else getattr(
-                            self.server.config, "num_batch_workers", 1
-                        )
-                    ),
-                    overflow=32,
-                    used_override=used_override,
-                    explain=explain,
-                )
-                from ..device.score import repair_batch_conflicts
-
-                lane_ok = repair_batch_conflicts(
-                    ct,
-                    all_asks,
-                    results,
-                    algorithm_spread=kernel.algorithm_spread,
-                    # multi-TG evals span lanes; a failed lane
-                    # discards the WHOLE eval, so repair must release
-                    # (and stop reserving for) every sibling lane too
-                    lane_groups=lane_groups,
-                    used_override=used_override,
-                )
-                if explain:
-                    # post-repair: stamp the committed rows into each
-                    # lane's explanation (obs/explain.py)
-                    from ..obs.explain import finalize_explanations
-
-                    finalize_explanations(
-                        ct, all_asks, results, used_override=used_override
+                with ps.phase(
+                    "invoke_scheduler",
+                    timer="nomad.worker.invoke_scheduler",
+                    tags={"lanes": len(all_asks), "explain": explain},
+                ):
+                    results = kernel.place(
+                        ct,
+                        all_asks,
+                        decorrelate=True,
+                        decorrelate_salt=(
+                            self.server.lanes.lane_of_job(
+                                prepared[0][0].namespace,
+                                prepared[0][0].job_id,
+                            )
+                            if lane_mode
+                            else self.id
+                        ),
+                        decorrelate_workers=(
+                            1
+                            if lane_mode
+                            else getattr(
+                                self.server.config, "num_batch_workers", 1
+                            )
+                        ),
+                        overflow=32,
+                        used_override=used_override,
+                        explain=explain,
                     )
-                invoke_s = time.perf_counter() - t0
-                metrics.measure("nomad.worker.invoke_scheduler", invoke_s)
-                for ev, _tok, _sched, _n in prepared:
-                    tracer.add_span(
-                        ev.id,
-                        "invoke_scheduler",
-                        invoke_s,
-                        tags={
-                            "shared": True,
-                            "evals": len(prepared),
-                            "lanes": len(all_asks),
-                            "explain": explain,
-                        },
-                    )
+                    from ..device.score import repair_batch_conflicts
+
+                    with tracer.span("repair"):
+                        lane_ok = repair_batch_conflicts(
+                            ct,
+                            all_asks,
+                            results,
+                            algorithm_spread=kernel.algorithm_spread,
+                            # multi-TG evals span lanes; a failed lane
+                            # discards the WHOLE eval, so repair must
+                            # release (and stop reserving for) every
+                            # sibling lane too
+                            lane_groups=lane_groups,
+                            used_override=used_override,
+                        )
+                    if explain:
+                        # post-repair: stamp the committed rows into each
+                        # lane's explanation (obs/explain.py)
+                        from ..obs.explain import finalize_explanations
+
+                        with tracer.span("explain", tags={"step": "final"}):
+                            finalize_explanations(
+                                ct, all_asks, results,
+                                used_override=used_override,
+                            )
             except Exception as e:
                 # shared pass failed — every prepared eval falls back to
                 # the individual path rather than dying unacked
@@ -656,8 +732,11 @@ class Worker:
 
         # pipeline: the previous commit must finish before this pass's
         # commit starts (plan order per job; one in-flight commit bounds
-        # memory), but the NEXT device pass overlaps THIS commit.
-        self._join_commit()
+        # memory), but the NEXT device pass overlaps THIS commit. The
+        # other join, at the top of the pass, only reaps a commit thread
+        # that has already ended and waits for nothing: it has no span.
+        with ps.phase("join_commit"):
+            self._join_commit()
         if commit_alive_at_start:
             # the previous commit ran concurrently with this pass's
             # prepare/flatten/device phases from t_pass0 until it
@@ -674,7 +753,7 @@ class Worker:
             # no kernel work (all singles) still needs it for the commit
             # thread's finally to balance
             overlay.commit_started()
-        args = (prepared, all_asks, results, lane_ok, singles)
+        args = (prepared, all_asks, results, lane_ok, singles, ps)
         self._commit_thread = threading.Thread(
             target=self._commit_batch, args=args,
             name=f"worker-{self.id}-commit", daemon=True,
@@ -682,15 +761,18 @@ class Worker:
         self._commit_thread.start()
 
     def _commit_batch(
-        self, prepared, all_asks, results, lane_ok, singles
+        self, prepared, all_asks, results, lane_ok, singles, ps
     ) -> None:
         """Commit one finished pass: per-eval plan submission + ack/nack.
         Runs on the commit thread while the worker's next device pass is
         in flight."""
         try:
-            self._commit_batch_inner(
-                prepared, all_asks, results, lane_ok, singles
-            )
+            # the pass's record continues on this thread, in the leader's
+            # trace
+            with tracer.activate(ps.leader):
+                self._commit_batch_inner(
+                    prepared, all_asks, results, lane_ok, singles, ps
+                )
         except ChaosThreadKill as e:
             # injected cooperative crash: die exactly like a killed
             # commit thread — whatever was not yet acked stays unacked
@@ -770,7 +852,7 @@ class Worker:
             tracer.finish(ev.id, status="nacked", error=repr(e))
 
     def _commit_batch_inner(
-        self, prepared, all_asks, results, lane_ok, singles
+        self, prepared, all_asks, results, lane_ok, singles, ps
     ) -> None:
         """Coalesced commit: build every member's plan from its result
         slice, then submit the WHOLE pass as one MergedPlan — one plan
@@ -809,7 +891,8 @@ class Worker:
                         # escalates (nack w/ delay, then failed) instead
                         # of committing stale work
                         sched.planner.check_deadline(ev.id)
-                        member = sched.build_batch_plan(span)
+                        with tracer.phase("build_plan"):
+                            member = sched.build_batch_plan(span)
                 except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
                     self._nack_member(ev, token, e, "batch build")
                     continue
@@ -862,48 +945,44 @@ class Worker:
             # 3. submit: ONE merged entry for the whole pass
             mresults: list = [None] * len(members)
             if members:
-                ctxs = []
-                for ev, token, _sched, member in members:
+                for _ev, token, _sched, member in members:
                     member.eval_token = token
                     member.normalize()
-                    with tracer.activate(ev.id):
-                        ctxs.append(tracer.current_ctx())
-                t0 = time.perf_counter()
                 # past this point the applier may land the claimed
                 # placements even if this thread dies — the finally
                 # below must settle (not just drop) the claimed nodes
                 for claim in claims:
                     claim.submitted = True
-                futures = server.plan_queue.enqueue_merged(
-                    MergedPlan(
-                        plans=[m[3] for m in members],
-                        owner_worker=self.id if self._lane_mode() else -1,
-                        claims=list(claims),
-                    ),
-                    trace_ctxs=ctxs,
-                )
-                # a kill here crashes the thread AFTER the merged plan
-                # is in flight: the applier still commits it, nobody
-                # acks, and redelivered members must converge to no-ops
-                # (never lose or double-commit a member)
-                chaos_site("worker.commit")
-                for i, (ev, token, _sched, _member) in enumerate(members):
-                    try:
-                        mresults[i] = futures[i].result(timeout=30)
-                    except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
-                        self._nack_member(ev, token, e, "merged submit")
-                submit_s = time.perf_counter() - t0
-                metrics.measure("nomad.worker.submit_plan", submit_s)
-                for i, (ev, _t, _s, _m) in enumerate(members):
-                    if mresults[i] is None:
-                        continue
-                    tracer.add_span(
-                        ev.id, "submit_plan", submit_s,
-                        tags={
-                            "shared": True,
-                            "rejected_nodes": len(mresults[i].rejected_nodes),
-                        },
+                with ps.phase(
+                    "submit_plan", timer="nomad.worker.submit_plan"
+                ) as sp:
+                    # the applier thread records the queue wait and the
+                    # merged apply under this span, once for the pass
+                    futures = server.plan_queue.enqueue_merged(
+                        MergedPlan(
+                            plans=[m[3] for m in members],
+                            owner_worker=(
+                                self.id if self._lane_mode() else -1
+                            ),
+                            claims=list(claims),
+                        ),
+                        trace_ctx=tracer.current_ctx(),
                     )
+                    # a kill here crashes the thread AFTER the merged plan
+                    # is in flight: the applier still commits it, nobody
+                    # acks, and redelivered members must converge to
+                    # no-ops (never lose or double-commit a member)
+                    chaos_site("worker.commit")
+                    for i, (ev, token, _sched, _member) in enumerate(members):
+                        try:
+                            mresults[i] = futures[i].result(timeout=30)
+                        except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
+                            self._nack_member(ev, token, e, "merged submit")
+                    if sp is not None:
+                        sp.tags["rejected_nodes"] = sum(
+                            len(r.rejected_nodes)
+                            for r in mresults if r is not None
+                        )
 
                 # 4. one shared refresh barrier for every partially
                 # committed member (each previously waited on its own)
@@ -912,15 +991,10 @@ class Worker:
                     default=0,
                 )
                 if refresh:
-                    t0 = time.perf_counter()
-                    server.store.wait_for_index(refresh, timeout=5.0)
-                    refresh_s = time.perf_counter() - t0
-                    for i, (ev, _t, _s, _m) in enumerate(members):
-                        if mresults[i] is not None and mresults[i].refresh_index:
-                            tracer.add_span(
-                                ev.id, "refresh_snapshot", refresh_s,
-                                tags={"shared": True, "refresh_index": refresh},
-                            )
+                    with ps.phase(
+                        "refresh_snapshot", tags={"refresh_index": refresh}
+                    ):
+                        server.store.wait_for_index(refresh, timeout=5.0)
 
                 # 5. complete: full commits finalize (status buffered);
                 # stale members retry individually on fresh state (the
@@ -1005,17 +1079,16 @@ class Worker:
         # Safe from the commit thread's singles fallback: the commit
         # marker is still held there, so maybe_reset() is a no-op.
         overlay = self._my_overlay()
-        if overlay.maybe_reset():
-            metrics.incr("nomad.worker.pipeline_epoch_resets")
+        overlay.maybe_reset()
         lane_mode = self._lane_mode()
         if lane_mode:
             self._rebase_lanes(overlay)
         # raft catch-up barrier (worker.go:536-549)
-        with tracer.span(
+        with tracer.phase(
             "wait_for_index", timer="nomad.worker.wait_for_index"
         ):
             self.server.store.wait_for_index(ev.modify_index, timeout=5.0)
-        with tracer.span("snapshot"):
+        with tracer.phase("snapshot"):
             snapshot = self.server.store.snapshot()
         # all workers share the server's resident device-state cache —
         # tensors refresh incrementally by state index, not per eval
@@ -1038,7 +1111,13 @@ class Worker:
             overlay=overlay,
             **kw,
         )
-        with tracer.span(
+        if ev.type in ("service", "batch"):
+            # the generic scheduler writes the pass's phases itself, the
+            # ones the batched pass writes (prepare, invoke_scheduler,
+            # build_plan, submit_plan)
+            sched.process(ev)
+            return
+        with tracer.phase(
             "invoke_scheduler", timer="nomad.worker.invoke_scheduler"
         ):
             sched.process(ev)

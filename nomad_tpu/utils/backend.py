@@ -195,7 +195,8 @@ def kernel_registry() -> dict[str, KernelEntry]:
 # -- kernel profiling (nomad_tpu.obs) ----------------------------------------
 #
 # Per-kernel call/compile accounting behind the same lock: every
-# traced_jit call records its dispatch wall time; calls that triggered an
+# traced_jit call records its dispatch wall time (the ``.dispatch`` sample
+# and the ``kernel:<name>`` span); calls that triggered an
 # XLA trace additionally record the abstract batch shape that caused it
 # and land in a bounded recent-events list. Caveat, stated honestly:
 # dispatch wall time UNDERESTIMATES device execute time under jax's
@@ -233,7 +234,7 @@ def _shape_sig(args, kwargs) -> str:
 
 
 def _record_kernel_call(
-    name: str, short: str, seconds: float, traced: bool
+    name: str, short: str, start: float, seconds: float, traced: bool
 ) -> None:
     with _trace_lock:
         st = _kernel_stats.setdefault(
@@ -251,7 +252,7 @@ def _record_kernel_call(
 
     global_metrics.measure(
         f"nomad.kernel.{short}.compile" if traced
-        else f"nomad.kernel.{short}.execute",
+        else f"nomad.kernel.{short}.dispatch",
         seconds,
     )
     global _obs_tracer
@@ -260,7 +261,8 @@ def _record_kernel_call(
 
         _obs_tracer = global_tracer
     _obs_tracer.record_kernel(
-        short, seconds, traced=traced, shape=shape if traced else None
+        short, seconds, start=start, traced=traced,
+        shape=shape if traced else None,
     )
 
 
@@ -446,7 +448,9 @@ def traced_jit(fn=None, *, trace_name=None, retrace_budget=None, **jit_kwargs):
             raise
         br.record_success()
         dt = time.perf_counter() - t0
-        _record_kernel_call(name, short, dt, _trace_counts.get(name, 0) > before)
+        _record_kernel_call(
+            name, short, t0, dt, _trace_counts.get(name, 0) > before
+        )
         return out
 
     _profiled.jitted = jitted  # escape hatch: the raw jax.jit object

@@ -1,0 +1,297 @@
+"""The benchmark's readers over recorded traces (``benchmark/readers/
+pass_wall.py``, ``latency_untraced.py``) and the checks of
+``benchmark/tests/check_traces.py``, on hand-made traces: CPU only, no
+server, so tier-1 counts them."""
+
+import os
+import sys
+import types
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (ROOT, os.path.join(ROOT, "benchmark", "tests")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import check_traces  # noqa: E402
+from benchmark.readers import (  # noqa: E402
+    latency_untraced,
+    pass_stage_sum,
+    pass_wall,
+)
+from nomad_tpu.obs.trace import global_tracer  # noqa: E402
+
+PHASES = ["wait_for_index", "snapshot", "prepare", "invoke_scheduler",
+          "join_commit"]
+T0 = 1_000.0  # span times below are seconds after T0
+
+
+def span(sid, parent, name, start, dur_ms, **tags):
+    return {"span_id": sid, "parent_id": parent, "name": name,
+            "start_unix": T0 + start, "duration_ms": dur_ms,
+            "status": "ok", "tags": tags}
+
+
+def trace(eval_id, spans, **tags):
+    return {"eval_id": eval_id, "status": "acked", "started_at": T0,
+            "duration_ms": 100.0, "tags": tags, "spans": spans}
+
+
+def solo_pass():
+    """Pass s-1: snapshot [0, 1) ms, prepare [1, 3) with flatten [1.5, 2.5),
+    invoke_scheduler [3, 13) with kernel.place [4, 12) holding a kernel
+    [5, 6) and a pull [6, 11); submit_plan later, outside the pass."""
+    p = {"pass_id": "s-1", "path": "solo", "evals": 1}
+    return trace("e-solo", [
+        span(1, None, "eval", 0.0, 100.0),
+        span(2, 1, "snapshot", 0.000, 1.0, **p),
+        span(4, 1, "prepare", 0.001, 2.0, **p),
+        span(3, 4, "flatten", 0.0015, 1.0, full=False),
+        span(5, 1, "invoke_scheduler", 0.003, 10.0, **p),
+        span(6, 5, "kernel.place", 0.004, 8.0),
+        span(7, 6, "kernel:place_closed_form_kernel", 0.005, 1.0),
+        span(8, 6, "place.pull", 0.006, 5.0),
+        span(9, 1, "submit_plan", 0.020, 5.0, **p),
+    ], **p)
+
+
+def batched_pass():
+    """Pass b-2, two members: the leader holds snapshot [0.100, 0.104) and
+    invoke_scheduler [0.106, 0.116) with kernel.place filling it; each
+    member its own prepare ([0.104, 0.105), [0.105, 0.106)); the second
+    member holds copies of the shared phases."""
+    p = {"pass_id": "b-2", "path": "batched", "evals": 2}
+    lead = trace("e-lead", [
+        span(10, None, "eval", 0.1, 50.0),
+        span(11, 10, "snapshot", 0.100, 4.0, shared=True, **p),
+        span(12, 10, "prepare", 0.104, 1.0, **p),
+        span(13, 10, "invoke_scheduler", 0.106, 10.0, shared=True, **p),
+        span(14, 13, "kernel.place", 0.106, 10.0),
+        span(15, 14, "kernel:place_spread_opv_kernel", 0.107, 1.0),
+    ], leader=True, **p)
+    member = trace("e-member", [
+        span(20, None, "eval", 0.1, 50.0),
+        span(21, 20, "snapshot", 0.100, 4.0, shared=True,
+             leader_eval="e-lead", **p),
+        span(22, 20, "prepare", 0.105, 1.0, **p),
+        span(23, 20, "invoke_scheduler", 0.106, 10.0, shared=True,
+             leader_eval="e-lead", **p),
+    ], leader_eval="e-lead", **p)
+    return [lead, member]
+
+
+class TestUnion:
+    @pytest.mark.parametrize("intervals, want", [
+        ([], 0.0),
+        ([(0.0, 1.0)], 1.0),
+        ([(0.0, 1.0), (2.0, 3.0)], 2.0),
+        ([(0.0, 2.0), (1.0, 3.0)], 3.0),
+        ([(1.0, 3.0), (0.0, 5.0), (2.0, 4.0)], 5.0),
+        ([(0.0, 1.0), (1.0, 2.0)], 2.0),
+    ])
+    def test_union_of_intervals(self, intervals, want):
+        assert pass_wall.union_s(intervals) == pytest.approx(want)
+
+
+class TestPassWall:
+    def test_solo_pass_runs_from_first_phase_start_to_last_phase_end(self):
+        ctx = {"traces": [solo_pass()]}
+        # snapshot starts at 0, invoke_scheduler ends at 13 ms; submit_plan
+        # is no phase of the pass proper
+        assert pass_wall.read(ctx, 0.5, PHASES) == pytest.approx(13.0)
+
+    def test_batched_pass_spans_every_members_phases_once(self):
+        ctx = {"traces": batched_pass()}
+        assert pass_wall.read(ctx, 0.5, PHASES) == pytest.approx(16.0)
+        found = pass_wall.passes(ctx["traces"], PHASES)
+        assert set(found) == {"b-2"}
+        # the member's own prepare counts, the copies it holds do not
+        assert sorted(s["span_id"] for s in found["b-2"]["phases"]) == [
+            11, 12, 13, 22]
+
+    def test_untraced_is_the_wall_less_the_union_of_the_leaves(self):
+        ctx = {"traces": [solo_pass()]}
+        # leaves: snapshot 1.0, flatten 1.0, kernel 1.0, pull 5.0 of 13 ms
+        assert pass_wall.read(
+            ctx, 0.5, PHASES, untraced=True) == pytest.approx(5.0)
+
+    def test_a_whole_stage_covers_its_own_time_beside_its_children(self):
+        ctx = {"traces": [solo_pass()]}
+        # prepare's 2.0 count, not only the flatten inside it
+        assert pass_wall.read(
+            ctx, 0.5, PHASES, untraced=True, whole=["prepare"]
+        ) == pytest.approx(4.0)
+
+    def test_copies_in_other_members_cover_nothing(self):
+        ctx = {"traces": batched_pass()}
+        # leaves: snapshot 4, the two prepares 1 + 1, the kernel 1, of 16
+        assert pass_wall.read(
+            ctx, 0.5, PHASES, untraced=True) == pytest.approx(9.0)
+
+    def test_holding_keeps_the_passes_that_hold_that_phase(self):
+        light = trace("e-dereg", [
+            span(1, None, "eval", 0.2, 5.0),
+            span(2, 1, "snapshot", 0.200, 2.0, pass_id="s-9", path="solo"),
+            span(3, 1, "prepare", 0.202, 1.0, pass_id="s-9", path="solo"),
+        ], pass_id="s-9", path="solo")
+        ctx = {"traces": [light, solo_pass()]}
+        assert pass_wall.read(ctx, 0.5, PHASES) == pytest.approx(3.0)
+        assert pass_wall.read(
+            ctx, 0.5, PHASES, holding="invoke_scheduler"
+        ) == pytest.approx(13.0)
+
+    def test_quantile_over_both_paths(self):
+        ctx = {"traces": [solo_pass()] + batched_pass()}
+        assert pass_wall.read(ctx, 0.5, PHASES) == pytest.approx(13.0)
+        assert pass_wall.read(ctx, 1.0, PHASES) == pytest.approx(16.0)
+
+    def test_stage_sum_adds_a_stages_spans_within_each_pass(self):
+        t = solo_pass()
+        p = {"step": "groups"}
+        t["spans"] += [
+            span(40, 5, "explain", 0.0120, 0.25, **p),
+            span(41, 5, "explain", 0.0125, 0.5, step="final"),
+        ]
+        ctx = {"traces": [t] + batched_pass()}
+        # one pass explains (0.25 + 0.5 ms); the batched one does not
+        assert pass_stage_sum.read(
+            ctx, "explain", 0.5, PHASES) == pytest.approx(0.75)
+        assert pass_stage_sum.read(ctx, "place.pull", 1.0, PHASES) == 5.0
+        assert pass_stage_sum.read(ctx, "repair", 0.5, PHASES) is None
+
+    def test_a_program_without_pass_ids_gives_nothing(self):
+        old = trace("e-old", [
+            span(1, None, "eval", 0.0, 10.0),
+            span(2, 1, "snapshot", 0.0, 2.0, shared=True),
+            span(3, 1, "invoke_scheduler", 0.002, 5.0),
+        ])
+        assert pass_wall.read({"traces": [old]}, 0.5, PHASES) is None
+        assert pass_wall.read({"traces": []}, 0.5, PHASES, True) is None
+
+
+def _request(eval_id, due, sent, done, ok=True):
+    return types.SimpleNamespace(
+        eval_id=eval_id, due=due, sent=sent, done=done, ok=ok)
+
+
+class TestLatencyUntraced:
+    def _trace(self, eval_id, t, with_register=True):
+        """Spans on the tracer's own clock, ``t`` a perf_counter stamp:
+        register [t, t+1ms), dequeue [t+1, t+3), invoke_scheduler
+        [t+4, t+8) and flush_status [t+8, t+9): 1 ms of 9 uncovered."""
+        u = global_tracer.unix_at(t) - T0
+        spans = [
+            span(1, None, "eval", u + 0.003, 7.0),
+            span(3, 1, "dequeue", u + 0.001, 2.0),
+            span(4, 1, "invoke_scheduler", u + 0.004, 4.0),
+            span(5, 1, "flush_status", u + 0.008, 1.0),
+        ]
+        if with_register:
+            spans.insert(1, span(2, 1, "register", u, 1.0))
+        return trace(eval_id, spans)
+
+    def test_latency_less_the_union_of_lateness_and_spans(self):
+        t = 5_000.0
+        ctx = {
+            "traces": [self._trace("e1", t)],
+            # due 2 ms before it was sent, done 1.5 ms after the flush
+            "registers": [_request("e1", t - 0.002, t, t + 0.0105)],
+        }
+        # 12.5 ms of latency; covered: 2 late + 1 + 2 + 4 + 1 = 10
+        assert latency_untraced.read(ctx, 0.5) == pytest.approx(2.5, abs=1e-3)
+
+    def test_spans_outside_due_to_done_are_clipped(self):
+        t = 6_000.0
+        ctx = {
+            "traces": [self._trace("e1", t)],
+            "registers": [_request("e1", t, t, t + 0.0085)],
+        }
+        # done falls inside flush_status: only the 1 ms gap is uncovered
+        assert latency_untraced.read(ctx, 0.5) == pytest.approx(1.0, abs=1e-3)
+
+    def test_failed_and_unknown_requests_are_left_out(self):
+        t = 7_000.0
+        ctx = {
+            "traces": [self._trace("e1", t)],
+            "registers": [
+                _request("e1", t, t, t + 0.009),
+                _request("e1", t, t, t + 0.5, ok=False),
+                _request("e-unknown", t, t, t + 0.5),
+            ],
+        }
+        assert latency_untraced.read(ctx, 1.0) == pytest.approx(1.0, abs=1e-3)
+
+    def test_a_program_without_register_spans_gives_nothing(self):
+        t = 8_000.0
+        ctx = {
+            "traces": [self._trace("e1", t, with_register=False)],
+            "registers": [_request("e1", t, t, t + 0.009)],
+        }
+        assert latency_untraced.read(ctx, 0.5) is None
+
+
+class TestCheckTraces:
+    def test_sound_traces_pass_every_check(self):
+        traces = [solo_pass()] + batched_pass()
+        result = {"metrics": {"passes_solo": {"value": 1.0},
+                              "passes_batched": {"value": 1.0}}}
+        report = check_traces.check(traces, result)
+        assert report["ok"] is True
+        assert report["distinct_pass_ids"] == 2
+        assert report["passes_counted_in_window"] == 2.0
+        assert report["nesting_faults"] == {}
+        assert check_traces.pass_ids(traces[0]) == {"s-1"}
+        whole = {"distinct_pass_ids": 3, "passes_counted": 2.0}
+        assert check_traces.check(traces, result, whole)["ok"] is False
+
+    def test_a_child_outside_its_parent_is_found(self):
+        t = solo_pass()
+        t["spans"].append(span(30, 5, "repair", 0.012, 3.0))  # ends at 15
+        assert check_traces.nesting_faults(t) == [
+            "repair ends after invoke_scheduler"]
+        t["spans"][-1] = span(30, 5, "repair", 0.001, 1.0)
+        assert check_traces.nesting_faults(t) == [
+            "repair starts before invoke_scheduler"]
+
+    def test_register_and_dequeue_may_precede_the_root(self):
+        t = solo_pass()
+        t["spans"].append(span(31, 1, "dequeue", -0.010, 9.0))
+        t["spans"].append(span(32, 1, "register", -0.012, 2.0))
+        assert check_traces.nesting_faults(t) == []
+
+    def test_overlapping_children_of_plan_apply_are_found(self):
+        t = trace("e", [
+            span(1, None, "eval", 0.0, 10.0),
+            span(2, 1, "plan_apply", 0.001, 5.0),
+            span(3, 2, "plan_apply.evaluate", 0.001, 3.0),
+            span(4, 2, "plan_apply.commit", 0.003, 2.0),
+        ])
+        assert check_traces.nesting_faults(t) == [
+            "plan_apply.evaluate overlaps plan_apply.commit"]
+        t["spans"][3] = span(4, 2, "plan_apply.commit", 0.004, 2.0)
+        assert check_traces.nesting_faults(t) == []
+
+    def test_a_pass_with_kernels_needs_exactly_one_kernel_place(self):
+        lead, member = batched_pass()
+        ids, bad = check_traces.pass_faults([lead, member])
+        assert ids == {"b-2"} and bad == []
+        lead["spans"] = [s for s in lead["spans"]
+                         if s["name"] != "kernel.place"]
+        lead["spans"][-1]["parent_id"] = 13  # the kernel, now under invoke
+        ids, bad = check_traces.pass_faults([lead, member])
+        assert bad == ["b-2"]
+
+    def test_gaps_are_summed_by_where_they_lie(self):
+        g = check_traces.gaps([solo_pass()])
+        assert g["prepare: (start) -> flatten"] == pytest.approx(0.0005)
+        assert g["kernel.place: place.pull -> (end)"] == pytest.approx(0.001)
+        assert g["eval: invoke_scheduler -> submit_plan"] == pytest.approx(
+            0.007)
+        assert "eval: snapshot -> prepare" not in g  # they touch
+
+    def test_stage_table_leaves_out_copies(self):
+        table = check_traces.stage_table(batched_pass())
+        assert table["snapshot"]["count"] == 1
+        assert table["prepare"]["count"] == 2
+        assert table["invoke_scheduler"]["p50_ms"] == pytest.approx(10.0)

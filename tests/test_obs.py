@@ -233,7 +233,7 @@ class TestFlightRecorder:
 
 
 class TestKernelProfile:
-    def test_traced_jit_records_compile_execute_and_shapes(self):
+    def test_traced_jit_records_compile_dispatch_and_shapes(self):
         import jax.numpy as jnp
 
         @backend.traced_jit
@@ -259,7 +259,7 @@ class TestKernelProfile:
 
         samples = global_metrics.snapshot()["samples"]
         assert samples["nomad.kernel._obs_toy_kernel.compile"]["count"] == 2
-        assert samples["nomad.kernel._obs_toy_kernel.execute"]["count"] == 1
+        assert samples["nomad.kernel._obs_toy_kernel.dispatch"]["count"] == 1
 
     def test_kernel_call_attaches_span_under_active_trace(self):
         import jax.numpy as jnp
@@ -369,6 +369,339 @@ class TestEndToEndTrace:
         finally:
             http.stop()
             server.shutdown()
+
+
+# -- one clock, true starts ---------------------------------------------------
+
+
+def _end(span):
+    return span["start_unix"] + span["duration_ms"] / 1000.0
+
+
+def _outside_parent(trace, slack_s=2e-6):
+    """Spans that start before or end after their parent (``register`` and
+    ``dequeue`` precede the root, which opens at the dequeue, by design)."""
+    by_id = {s["span_id"]: s for s in trace["spans"]}
+    out = []
+    for s in trace["spans"]:
+        parent = by_id.get(s["parent_id"])
+        if parent is None:
+            continue
+        if parent["parent_id"] is None and s["name"] in ("register", "dequeue"):
+            continue
+        if (
+            s["start_unix"] < parent["start_unix"] - slack_s
+            or _end(s) > _end(parent) + slack_s
+        ):
+            out.append((s["name"], parent["name"]))
+    return out
+
+
+class TestTrueStarts:
+    @pytest.mark.parametrize("ago", [2.0, 0.75])
+    def test_retroactive_span_starts_where_its_caller_says(self, ago):
+        t = Tracer()
+        t.begin("e1")
+        began = time.perf_counter() - ago  # long before the call
+        t.add_span("e1", "waited", 0.5, start=began)
+        sp = span_by_name(t.finish("e1"), "waited")
+        assert sp["start_unix"] == pytest.approx(t.unix_at(began), abs=1e-6)
+        assert sp["duration_ms"] == pytest.approx(500.0)
+
+    def test_starts_and_durations_share_one_clock(self):
+        """``start_unix`` is one wall anchor plus the monotonic offset: two
+        spans lie as far apart as ``perf_counter`` says."""
+        t = Tracer()
+        t.begin("e1")
+        with t.activate("e1"):
+            with t.span("a"):
+                pass
+            t1 = time.perf_counter()
+            with t.span("b"):
+                pass
+        tr = t.finish("e1")
+        gap = span_by_name(tr, "b")["start_unix"] - _end(span_by_name(tr, "a"))
+        assert 0.0 <= gap <= time.perf_counter() - t1 + 1e-4
+        assert t.unix_at(t1) == pytest.approx(time.time(), abs=5.0)
+
+    def test_kernel_span_starts_at_its_dispatch(self):
+        t = Tracer()
+        t.begin("e1")
+        t0 = time.perf_counter() - 1.0
+        with t.activate("e1"):
+            t.record_kernel("k", 0.25, start=t0)
+        sp = span_by_name(t.finish("e1"), "kernel:k")
+        assert sp["start_unix"] == pytest.approx(t.unix_at(t0), abs=1e-6)
+
+    def test_phase_carries_the_roots_pass_tags(self):
+        t = Tracer()
+        t.begin("e1", tags={"pass_id": "0-7", "path": "solo", "evals": 1,
+                            "job_id": "j"})
+        with t.activate("e1"):
+            with t.phase("prepare", tags={"x": 1}):
+                with t.span("below"):
+                    pass
+        tr = t.finish("e1")
+        assert span_by_name(tr, "prepare")["tags"] == {
+            "pass_id": "0-7", "path": "solo", "evals": 1, "x": 1,
+        }
+        assert span_by_name(tr, "below")["tags"] == {}
+
+
+# -- one pass record on both paths ------------------------------------------
+
+TRACE_KEYS = {"eval_id", "status", "started_at", "duration_ms", "tags", "spans"}
+SPAN_KEYS = {
+    "span_id", "parent_id", "name", "start_unix", "duration_ms", "status",
+    "tags",
+}
+# what only a batched pass does on the worker thread: it hands its commit
+# to the pipeline thread, after the last one has ended
+BATCHED_ONLY = {"join_commit"}
+
+
+def _top_level(trace):
+    root = trace["spans"][0]["span_id"]
+    return [s for s in trace["spans"] if s["parent_id"] == root]
+
+
+def _job(job_id, count=2):
+    job = mock.job()
+    job.id = job_id
+    job.task_groups[0].count = count
+    return job
+
+
+@pytest.fixture(scope="module")
+def pass_traces():
+    """One solo pass, one batched pass of two registrations (enqueued
+    before the worker is unpaused) and a deregistration enqueued while its
+    job's registration is still ahead of it: {name: trace}, the counters'
+    deltas under "counters"."""
+    global_tracer.set_enabled(True)
+    global_tracer.reset()
+    got = {}
+
+    def keep(trace):
+        got[trace["eval_id"]] = trace
+
+    flight_recorder.add_listener(keep)
+    before = dict(global_metrics.snapshot()["counters"])
+    server = Server(ServerConfig(num_workers=1))
+    server.establish_leadership()
+    try:
+        for _ in range(4):
+            server.register_node(mock.node())
+        solo = server.register_job(_job("pass-solo"))
+        assert server.wait_for_evals(timeout=30)
+        for w in server.workers:
+            w.pause()
+        time.sleep(0.05)  # the worker's dequeue poll may hold one more turn
+        lead = server.register_job(_job("pass-a"))
+        member = server.register_job(_job("pass-b"))
+        for w in server.workers:
+            w.resume()
+        assert server.wait_for_evals(timeout=30)
+        for w in server.workers:
+            w.pause()
+        time.sleep(0.05)
+        first = server.register_job(_job("pass-c"))
+        gated = server.deregister_job("default", "pass-c")
+        for w in server.workers:
+            w.resume()
+        assert server.wait_for_evals(timeout=30)
+        names = {"solo": solo.id, "lead": lead.id, "member": member.id,
+                 "first": first.id, "gated": gated.id}
+        deadline = time.time() + 5.0
+        while time.time() < deadline and not set(names.values()) <= set(got):
+            time.sleep(0.02)
+    finally:
+        server.shutdown()
+        flight_recorder.remove_listener(keep)
+    after = global_metrics.snapshot()["counters"]
+    out = {name: got[eid] for name, eid in names.items()}
+    out["counters"] = {
+        k: after.get(k, 0) - before.get(k, 0)
+        for k in ("nomad.worker.passes_solo", "nomad.worker.passes_batched")
+    }
+    return out
+
+
+class TestPassRecord:
+    def test_both_paths_write_the_same_top_level_phases(self, pass_traces):
+        solo = {s["name"] for s in _top_level(pass_traces["solo"])}
+        lead = {s["name"] for s in _top_level(pass_traces["lead"])}
+        member = {s["name"] for s in _top_level(pass_traces["member"])}
+        assert pass_traces["solo"]["tags"]["path"] == "solo"
+        assert pass_traces["lead"]["tags"]["path"] == "batched"
+        assert lead - BATCHED_ONLY == solo
+        assert member == lead
+        assert solo == {"register", "dequeue", "wait_for_index", "snapshot",
+                        "prepare", "invoke_scheduler", "build_plan",
+                        "submit_plan"}
+
+    def test_every_member_carries_the_pass_id(self, pass_traces):
+        lead, member = pass_traces["lead"], pass_traces["member"]
+        assert lead["tags"]["pass_id"] == member["tags"]["pass_id"]
+        assert lead["tags"]["evals"] == member["tags"]["evals"] == 2
+        assert lead["tags"]["leader"] is True
+        assert member["tags"]["leader"] is False
+        assert member["tags"]["leader_eval"] == lead["eval_id"]
+        assert pass_traces["solo"]["tags"]["pass_id"] != lead["tags"]["pass_id"]
+        for name in ("lead", "member", "solo"):
+            t = pass_traces[name]
+            for s in _top_level(t):
+                if s["name"] not in ("register", "dequeue"):
+                    assert s["tags"]["pass_id"] == t["tags"]["pass_id"], s
+                    assert s["tags"]["path"] == t["tags"]["path"]
+
+    @pytest.mark.parametrize("members", [("solo",), ("lead", "member")])
+    def test_kernel_place_exactly_once_per_pass(self, pass_traces, members):
+        spans = [s for m in members for s in pass_traces[m]["spans"]]
+        assert [s["name"] for s in spans].count("kernel.place") == 1
+        kernels = [s for s in spans if s["name"].startswith("kernel:")]
+        place = [s for s in spans if s["name"] == "kernel.place"][0]
+        assert kernels and all(
+            k["parent_id"] == place["span_id"] for k in kernels
+        )
+        stages = {s["name"] for s in spans
+                  if s["parent_id"] == place["span_id"]}
+        assert {"place.assemble", "place.upload", "place.pull"} <= stages
+
+    def test_stages_lie_below_the_leaders_phases_only(self, pass_traces):
+        """What happens below a phase is written once, in the leader's
+        trace; the other member holds the phases, tagged shared."""
+        member = pass_traces["member"]
+        root = member["spans"][0]["span_id"]
+        assert all(s["parent_id"] in (None, root) for s in member["spans"])
+        lead = pass_traces["lead"]
+        copies = {s["name"] for s in _top_level(member)
+                  if s["tags"].get("leader_eval") == lead["eval_id"]}
+        assert {"wait_for_index", "snapshot", "invoke_scheduler",
+                "join_commit", "submit_plan"} == copies
+        assert not any("leader_eval" in s["tags"] for s in lead["spans"][1:])
+        for name in ("snapshot", "invoke_scheduler", "submit_plan"):
+            theirs, ours = span_by_name(member, name), span_by_name(lead, name)
+            # a copy is the leader's interval, to the digit
+            assert theirs["start_unix"] == ours["start_unix"]
+            assert theirs["duration_ms"] == ours["duration_ms"]
+            assert theirs["tags"]["shared"] is ours["tags"]["shared"] is True
+        assert "shared" not in span_by_name(
+            pass_traces["solo"], "snapshot")["tags"]
+        assert span_by_name(lead, "flatten")["parent_id"] == span_by_name(
+            lead, "snapshot")["span_id"]
+        assert span_by_name(lead, "plan_apply")["parent_id"] == span_by_name(
+            lead, "submit_plan")["span_id"]
+
+    def test_a_solo_pass_flattens_only_when_it_places(self, pass_traces):
+        """The solo path refreshes the tensors where it builds its asks,
+        inside ``prepare``; a pass with nothing to place (a
+        deregistration) never touches the cache."""
+        solo, gated = pass_traces["solo"], pass_traces["gated"]
+        assert span_by_name(solo, "flatten")["parent_id"] == span_by_name(
+            solo, "prepare")["span_id"]
+        names = [s["name"] for s in gated["spans"]]
+        assert "prepare" in names
+        assert not {"flatten", "invoke_scheduler", "kernel.place"} & set(names)
+
+    @pytest.mark.parametrize("name", ["solo", "lead", "member", "first",
+                                      "gated"])
+    def test_dequeue_wait_tags_sum_to_the_queue_wait(self, pass_traces, name):
+        d = span_by_name(pass_traces[name], "dequeue")
+        tags = d["tags"]
+        assert tags["ready_wait_ms"] + tags["gate_wait_ms"] + tags[
+            "deferred_ms"] == pytest.approx(tags["queue_wait_ms"], abs=1e-6)
+        assert d["duration_ms"] == pytest.approx(tags["queue_wait_ms"],
+                                                 abs=1e-3)
+        # the eval's own stay, not the worker's blocked wait for work
+        assert _end(d) <= pass_traces[name]["spans"][0]["start_unix"] + 1e-3
+
+    def test_second_eval_of_a_job_in_flight_waits_at_the_gate(
+        self, pass_traces
+    ):
+        gated = span_by_name(pass_traces["gated"], "dequeue")["tags"]
+        first = span_by_name(pass_traces["first"], "dequeue")["tags"]
+        assert gated["gate_wait_ms"] > 0
+        assert first["gate_wait_ms"] == 0 and first["ready_wait_ms"] > 0
+
+    def test_register_span_precedes_the_queue_wait(self, pass_traces):
+        for name in ("solo", "lead", "gated"):
+            t = pass_traces[name]
+            reg, deq = span_by_name(t, "register"), span_by_name(t, "dequeue")
+            assert reg["duration_ms"] > 0
+            assert _end(reg) == pytest.approx(deq["start_unix"], abs=1e-4)
+
+    @pytest.mark.parametrize("name", ["solo", "lead", "member", "gated"])
+    def test_no_child_lies_outside_its_parent(self, pass_traces, name):
+        assert _outside_parent(pass_traces[name]) == []
+
+    @pytest.mark.parametrize("name", ["solo", "lead"])
+    def test_plan_apply_children_inside_it_and_disjoint(
+        self, pass_traces, name
+    ):
+        t = pass_traces[name]
+        apply_ = span_by_name(t, "plan_apply")
+        ev = span_by_name(t, "plan_apply.evaluate")
+        co = span_by_name(t, "plan_apply.commit")
+        assert ev["parent_id"] == co["parent_id"] == apply_["span_id"]
+        assert apply_["start_unix"] - 2e-6 <= ev["start_unix"]
+        assert _end(ev) <= co["start_unix"] + 2e-6
+        assert _end(co) <= _end(apply_) + 2e-6
+        wait = span_by_name(t, "plan_queue.wait")
+        assert _end(wait) <= apply_["start_unix"] + 2e-6
+
+    @pytest.mark.parametrize("name", ["solo", "lead", "member"])
+    def test_recorded_key_sets_are_unchanged(self, pass_traces, name):
+        t = pass_traces[name]
+        assert set(t) == TRACE_KEYS
+        assert all(set(s) == SPAN_KEYS for s in t["spans"])
+
+    def test_pass_counters_count_each_path(self, pass_traces):
+        c = pass_traces["counters"]
+        # solo, first and gated (each dequeued alone) against one batch
+        assert c["nomad.worker.passes_batched"] >= 1
+        assert c["nomad.worker.passes_solo"] >= 1
+        assert sum(c.values()) == len({
+            s["tags"]["pass_id"]
+            for name in ("solo", "lead", "member", "first", "gated")
+            for s in _top_level(pass_traces[name])
+            if "pass_id" in s["tags"]
+        })
+
+
+class TestDisabledTracer:
+    def test_no_stage_span_is_built_and_the_timers_still_sample(self):
+        from nomad_tpu.obs import trace as trace_mod
+
+        built = []
+        real = trace_mod.Span.__init__
+
+        def counting(self, *a, **kw):
+            built.append(a[1] if len(a) > 1 else kw.get("name"))
+            real(self, *a, **kw)
+
+        server = Server(ServerConfig(num_workers=1))
+        server.establish_leadership()
+        try:
+            for _ in range(3):
+                server.register_node(mock.node())
+            global_tracer.set_enabled(False)
+            global_metrics.reset()
+            trace_mod.Span.__init__ = counting
+            ev = server.register_job(_job("off-1"))
+            assert server.wait_for_evals(timeout=30)
+        finally:
+            trace_mod.Span.__init__ = real
+            global_tracer.set_enabled(True)
+            server.shutdown()
+        assert built == []
+        assert flight_recorder.get(ev.id) is None
+        samples = global_metrics.snapshot()["samples"]
+        for name in ("nomad.worker.wait_for_index",
+                     "nomad.worker.invoke_scheduler",
+                     "nomad.worker.submit_plan", "nomad.plan.apply",
+                     "nomad.plan.evaluate"):
+            assert samples[name]["count"] >= 1, name
 
 
 # -- overhead guard ---------------------------------------------------------
