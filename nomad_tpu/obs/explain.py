@@ -27,7 +27,13 @@ scheduler/server side). Three pieces:
 - ``finalize_explanations``: post-repair pass that stamps the
   *committed* rows (conflict repair may move placements after the
   kernel returns) and derives per-instance score breakdowns by
-  replaying the lane's placements against a usage overlay.
+  replaying the lane's placements against a lane-local overlay. The
+  overlay is computed, not stepped: once repair is done the placement
+  order is fixed, so the usage, collision count and per-value spread
+  counts instance i was scored against are the base snapshot plus
+  running sums over instances 0..i-1, formed for the whole lane in one
+  array pass. Addition commutes, so those sums are the state a
+  sequential walk would hold at i.
 
 Candidate ranking is computed against the same base usage snapshot the
 kernel pass scored against, so on an uncontended pass the top-1
@@ -502,128 +508,197 @@ def explain_cp_gang(
     return ex
 
 
+# elements of one [instances, values] count array in the finalize replay;
+# a longer lane is replayed in chunks that carry the counts over
+_REPLAY_CHUNK_ELEMS = 1 << 20
+
+
+def _instance_block_boost(blocks, rows):
+    """Summed spread boost each of one lane's committed instances was
+    scored with, f64[M] aligned with ``rows`` (placement order), and
+    whether the lane carries a spread block at all.
+
+    The counts instance i saw are ``counts0`` plus one per earlier
+    instance on a node of the same value (value id -1 adds nothing): the
+    exclusive running sum of the rows' one-hot values, which is the state
+    a sequential overlay holds after i increments. Boosts follow
+    ``device.score._host_block_tables`` rule for rule, read at the
+    instance's own value only. Blocks are independent, so the counts of a
+    BLOCK_DISTINCT_CAP block (no boost) are never formed."""
+    from ..device.score import (
+        BLOCK_DISTINCT_CAP,
+        BLOCK_EVEN_SPREAD,
+        BLOCK_TARGET_SPREAD,
+    )
+
+    m = len(rows)
+    boost = np.zeros(m, dtype=np.float64)
+    spread_any = False
+    values = np.arange(blocks.num_values)
+    step = max(1, _REPLAY_CHUNK_ELEMS // max(blocks.num_values, 1))
+    for b in range(blocks.num_blocks):
+        kind = blocks.kinds[b]
+        if kind == BLOCK_DISTINCT_CAP:
+            continue
+        spread_any = True
+        vids = blocks.value_ids[b][rows]
+        base = blocks.counts0[b]
+        for s in range(0, m, step):
+            v = vids[s:s + step]
+            at = (np.arange(len(v)), np.maximum(v, 0))
+            onehot = (v[:, None] == values[None, :]).astype(base.dtype)
+            after = base + np.cumsum(onehot, axis=0)
+            c = after - onehot  # [chunk, V]: the counts before each instance
+            base = after[-1]
+            c_at = c[at]
+            if kind == BLOCK_TARGET_SPREAD:
+                d = blocks.desired[b][at[1]]
+                val = np.where(
+                    d > 0,
+                    (d - (c_at + 1.0)) / np.maximum(d, 1e-9)
+                    * blocks.weights[b],
+                    -1.0,
+                )
+            elif kind == BLOCK_EVEN_SPREAD:
+                pos = c > 0
+                minc = np.where(pos, c, np.inf).min(axis=1)
+                maxc = np.where(pos, c, -np.inf).max(axis=1)
+                any_pos = maxc > 0
+                safe_min = np.where(any_pos, np.maximum(minc, 1e-9), 1.0)
+                val = np.where(
+                    c_at == minc,
+                    np.where(minc == maxc, -1.0, (maxc - minc) / safe_min),
+                    (minc - c_at) / safe_min,
+                )
+                val = np.where(any_pos, val, 0.0)
+            else:
+                val = 0.0
+            boost[s:s + step] += np.where(v >= 0, val, -1.0)
+    return boost, spread_any
+
+
 def _instance_components_vec(capacity, used0, a, rows, mine, algorithm_spread):
-    """Vectorized per-instance breakdowns for one lane's committed rows —
-    the blocks-free fast path of the finalize replay. Instance i on row
-    r sees ``used0[r] + mine[i] * ask``, the same state the sequential
-    overlay would hold when it scored that instance. Returns
-    (components, final) pairs aligned with ``rows``."""
+    """Per-instance breakdowns for one lane's committed rows, the whole
+    lane in one array pass. Instance i on row r is scored against the
+    state a sequential overlay would hold when it reached i, and that
+    state is a running sum over the placement order: usage
+    ``used0[r] + mine[i] * ask`` (``mine`` = the lane's earlier instances
+    on r), collisions ``job_counts[r] + mine[i]``, and for a lane with
+    value blocks the per-value counts of ``_instance_block_boost``. Same
+    component keys, insertion order and join rules as ``_components_at``.
+    Returns (components, final) pairs aligned with ``rows``."""
     fit_name = "spread-fit" if algorithm_spread else "binpack"
     rows = np.asarray(rows, dtype=np.int64)
     mine_i = np.asarray(mine, dtype=np.int64)
     cap = capacity[rows]
     prop = used0[rows] + (mine_i + 1).astype(np.float32)[:, None] * a.ask[None, :]
-    free = np.where(cap > 0, (cap - prop) / np.maximum(cap, 1e-9), 1.0)
+    free = np.where(
+        cap > 0, (cap - prop) / np.maximum(cap, 1e-9), 1.0
+    ).astype(np.float64)
     pow_sum = 10.0 ** free[:, 0] + 10.0 ** free[:, 1]
     binpack = np.clip(20.0 - pow_sum, 0.0, BINPACK_MAX_SCORE)
     spread_fit = np.clip(pow_sum - 2.0, 0.0, BINPACK_MAX_SCORE)
     fit = (spread_fit if algorithm_spread else binpack) / BINPACK_MAX_SCORE
     jc = np.asarray(a.job_counts)[rows] + mine_i
-    anti = np.where(jc > 0, -(jc + 1.0) / max(a.desired_total, 1.0), 0.0)
+    collided = jc > 0
+    anti = np.where(collided, -(jc + 1.0) / max(a.desired_total, 1.0), 0.0)
     pen = np.asarray(a.penalty_nodes, dtype=bool)[rows]
     num = fit + anti + np.where(pen, -1.0, 0.0)
-    den = 1.0 + (jc > 0) + pen
+    den = 1.0 + collided + pen
     aff = None
     if a.has_affinities:
-        aff = np.asarray(a.affinity_scores)[rows]
+        aff = np.asarray(a.affinity_scores, dtype=np.float64)[rows]
         num = num + aff
         den = den + 1.0
-    finals = num / den
+    boost, spread_any = (
+        _instance_block_boost(a.blocks, rows)
+        if a.blocks is not None
+        else (np.zeros(len(rows)), False)
+    )
+    spread_on = spread_any & (boost != 0.0)
+    num = num + np.where(spread_on, boost, 0.0)
+    den = den + spread_on
+    finals = (num / den).tolist()
+    # plain Python values from here on: the dicts are built per instance
+    fit, anti, collided, pen, boost, spread_on = (
+        x.tolist() for x in (fit, anti, collided, pen, boost, spread_on)
+    )
+    if aff is not None:
+        aff = aff.tolist()
     out = []
-    for i in range(len(rows)):
-        comps = {fit_name: float(fit[i])}
-        if jc[i] > 0:
-            comps["job-anti-affinity"] = float(anti[i])
+    for i, final in enumerate(finals):
+        comps = {fit_name: fit[i]}
+        if collided[i]:
+            comps["job-anti-affinity"] = anti[i]
         if pen[i]:
             comps["node-reschedule-penalty"] = -1.0
         if aff is not None:
-            comps["node-affinity"] = float(aff[i])
-        out.append((comps, float(finals[i])))
+            comps["node-affinity"] = aff[i]
+        if spread_on[i]:
+            comps["allocation-spread"] = boost[i]
+        out.append((comps, final))
     return out
 
 
-def finalize_explanations(cluster, asks, results, used_override=None) -> None:
+def finalize_explanations(cluster, asks, results, used_override=None) -> dict:
     """Post-repair pass: stamp committed rows into each lane's
     explanation and derive per-instance score breakdowns by replaying
-    the lane's placements against a lane-local usage overlay (the same
-    evolution the greedy scan applied). Conflict repair mutates
-    ``node_rows`` in place after the kernel returned, so this runs
-    AFTER ``repair_batch_conflicts`` — ``placed_nodes`` reflects what
-    will actually commit."""
+    the lane's placements against a lane-local overlay (the same
+    evolution the greedy scan applied). The overlay is not stepped
+    through: the placement order is fixed once repair is done, so what
+    instance i was scored against (usage on its row, collisions, the
+    per-value spread counts) is the base snapshot plus a running sum
+    over the instances before it, and ``_instance_components_vec``
+    forms those sums for the whole lane at once. Hetero lanes carry
+    their per-instance score from the joint pass and are stamped one by
+    one. Conflict repair mutates ``node_rows`` in place after the kernel
+    returned, so this runs AFTER ``repair_batch_conflicts`` —
+    ``placed_nodes`` reflects what will actually commit.
+
+    Returns the tags of the ``explain`` span's final step:
+    ``instances`` (rows stamped) and ``sequential_lanes`` (lanes stamped
+    instance by instance, outside the array replay)."""
     used0 = np.asarray(
         cluster.used if used_override is None else used_override
     )
     capacity = np.asarray(cluster.capacity)
+    stats = {"instances": 0, "sequential_lanes": 0}
     for a, res in zip(asks, results):
         ex = getattr(res, "explanation", None)
         if ex is None:
             continue
-        hetero = bool(ex.policy)
         rows_list = np.asarray(res.node_rows).tolist()
+        placed_idx = [i for i, r in enumerate(rows_list) if r >= 0]
+        prows = [rows_list[i] for i in placed_idx]
         placed_on: dict[int, int] = {}
-        ex.placed_nodes = []
-        if not hetero and a.blocks is None:
-            # fast path: no spread counts evolve per placement, so every
-            # instance's state is used0 + (prior instances on its row) *
-            # ask — computable for the whole lane in one vectorized pass
-            placed_idx = [i for i, r in enumerate(rows_list) if r >= 0]
-            prows = [rows_list[i] for i in placed_idx]
-            mine = []
-            for r in prows:
-                mine.append(placed_on.get(r, 0))
-                placed_on[r] = placed_on.get(r, 0) + 1
-            instance_meta = [None] * len(rows_list)
-            if prows:
-                breakdown = _instance_components_vec(
-                    capacity, used0, a, prows, mine,
-                    ex.algorithm == "spread",
-                )
-                for i, r, (comps, final) in zip(
-                    placed_idx, prows, breakdown
-                ):
-                    node_id = cluster.node_ids[r]
-                    ex.placed_nodes.append(node_id)
-                    instance_meta[i] = NodeScoreMeta(
-                        node_id=node_id, scores=comps, norm_score=final
-                    )
+        mine = []
+        for r in prows:
+            k = placed_on.get(r, 0)
+            mine.append(k)
+            placed_on[r] = k + 1
+        ex.placed_nodes = [cluster.node_ids[r] for r in prows]
+        stats["instances"] += len(prows)
+        if ex.policy:
+            stats["sequential_lanes"] += 1
+            breakdown = [
+                ({"throughput": float(res.scores[i])}, float(res.scores[i]))
+                for i in placed_idx
+            ]
         else:
-            used = used0.copy()
-            counts = (
-                a.blocks.counts0.copy() if a.blocks is not None else None
+            breakdown = _instance_components_vec(
+                capacity, used0, a, prows, mine, ex.algorithm == "spread"
             )
-            instance_meta = []
-            for i, row in enumerate(rows_list):
-                if row < 0:
-                    instance_meta.append(None)
-                    continue
-                node_id = cluster.node_ids[row]
-                ex.placed_nodes.append(node_id)
-                if hetero:
-                    comps = {"throughput": float(res.scores[i])}
-                    final = float(res.scores[i])
-                else:
-                    (comps, final), = _components_at(
-                        capacity, used, a, [row],
-                        [placed_on.get(row, 0)], counts,
-                        ex.algorithm == "spread",
-                    )
-                instance_meta.append(
-                    NodeScoreMeta(
-                        node_id=node_id,
-                        scores={k: float(v) for k, v in comps.items()},
-                        norm_score=float(final),
-                    )
-                )
-                used[row] += a.ask
-                placed_on[row] = placed_on.get(row, 0) + 1
-                if counts is not None:
-                    for b in range(a.blocks.num_blocks):
-                        v = a.blocks.value_ids[b, row]
-                        if v >= 0:
-                            counts[b, v] += 1
         # per-instance metas ride as a plain attribute (not a dataclass
         # field) so API encodings of the explanation stay bounded
-        ex.instance_meta = instance_meta
+        ex.instance_meta = instance_meta = [None] * len(rows_list)
+        first_meta: dict[int, NodeScoreMeta] = {}
+        for i, r, node_id, (comps, final) in zip(
+            placed_idx, prows, ex.placed_nodes, breakdown
+        ):
+            instance_meta[i] = meta = NodeScoreMeta(
+                node_id=node_id, scores=comps, norm_score=final
+            )
+            first_meta.setdefault(r, meta)
         by_row = {c.node_row: c for c in ex.top_candidates}
         for row, k in placed_on.items():
             cand = by_row.get(row)
@@ -633,11 +708,7 @@ def finalize_explanations(cluster, asks, results, used_override=None) -> None:
                 # repair (or a later greedy step) committed a node
                 # outside the first-instance top-k: append it so
                 # `alloc why` always finds its breakdown
-                meta = next(
-                    m
-                    for m in instance_meta
-                    if m is not None and m.node_id == cluster.node_ids[row]
-                )
+                meta = first_meta[row]
                 ex.top_candidates.append(
                     CandidateExplanation(
                         node_id=meta.node_id,
@@ -647,6 +718,7 @@ def finalize_explanations(cluster, asks, results, used_override=None) -> None:
                         placed=k,
                     )
                 )
+    return stats
 
 
 def score_meta_for_row(

@@ -228,10 +228,12 @@ class GenericScheduler:
                     # stamped from the POST-repair (= committed) rows
                     from ..obs.explain import finalize_explanations
 
-                    with tracer.span("explain", tags={"step": "final"}):
-                        finalize_explanations(
+                    with tracer.span("explain", tags={"step": "final"}) as sp:
+                        stamped = finalize_explanations(
                             ct, asks, results, used_override=used_override
                         )
+                        if sp is not None:
+                            sp.tags.update(stamped)
             if self.overlay is not None:
                 for a, res in zip(asks, results):
                     rows = res.node_rows[res.node_rows >= 0]

@@ -687,11 +687,15 @@ class Worker:
                         # lane's explanation (obs/explain.py)
                         from ..obs.explain import finalize_explanations
 
-                        with tracer.span("explain", tags={"step": "final"}):
-                            finalize_explanations(
+                        with tracer.span(
+                            "explain", tags={"step": "final"}
+                        ) as sp:
+                            stamped = finalize_explanations(
                                 ct, all_asks, results,
                                 used_override=used_override,
                             )
+                            if sp is not None:
+                                sp.tags.update(stamped)
             except Exception as e:
                 # shared pass failed — every prepared eval falls back to
                 # the individual path rather than dying unacked

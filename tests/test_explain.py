@@ -7,7 +7,9 @@ ring, the HTTP/plan surfaces, and lint rule NTA014.
 All tests here are CPU-only and ride tier-1.
 """
 
+import copy
 import json
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -16,8 +18,12 @@ from bench import build_asks, build_cluster
 from nomad_tpu import mock
 from nomad_tpu.analysis import retrace
 from nomad_tpu.device.score import PlacementKernel, repair_batch_conflicts
+from nomad_tpu.obs import explain as explain_mod
 from nomad_tpu.obs.explain import (
     EXPLAIN_SCHEMA_VERSION,
+    CandidateExplanation,
+    _components_at,
+    explain_group,
     explanation_to_dict,
     finalize_explanations,
 )
@@ -169,6 +175,457 @@ class TestProvenanceParity:
                 else:
                     assert meta.node_id == ct.node_ids[int(row)]
                     assert "binpack" in meta.scores
+
+
+# -- array replay vs the sequential oracle ----------------------------------
+
+
+def _sequential_replay(ct, a, res, ex):
+    """The oracle: the replay as it was before it became an array pass.
+    One ``_components_at`` call per placed instance against an overlay
+    that is stepped through the placement order (usage, the lane's own
+    instances per row, the per-value counts ``_host_block_tables`` reads),
+    then the candidates' ``placed`` counts and the appended rows. Stamps
+    ``ex`` the way ``finalize_explanations`` does."""
+    used = np.asarray(ct.used).copy()
+    capacity = np.asarray(ct.capacity)
+    counts = a.blocks.counts0.copy() if a.blocks is not None else None
+    placed_on = {}
+    ex.placed_nodes, ex.instance_meta = [], []
+    for row in np.asarray(res.node_rows).tolist():
+        if row < 0:
+            ex.instance_meta.append(None)
+            continue
+        ex.placed_nodes.append(ct.node_ids[row])
+        ((comps, final),) = _components_at(
+            capacity, used, a, [row], [placed_on.get(row, 0)], counts,
+            ex.algorithm == "spread",
+        )
+        ex.instance_meta.append(NodeScoreMeta(
+            node_id=ct.node_ids[row],
+            scores={k: float(v) for k, v in comps.items()},
+            norm_score=float(final),
+        ))
+        used[row] += a.ask
+        placed_on[row] = placed_on.get(row, 0) + 1
+        if counts is not None:
+            for b in range(a.blocks.num_blocks):
+                v = a.blocks.value_ids[b, row]
+                if v >= 0:
+                    counts[b, v] += 1
+    by_row = {c.node_row: c for c in ex.top_candidates}
+    for row, k in placed_on.items():
+        if row in by_row:
+            by_row[row].placed = k
+            continue
+        meta = next(
+            m for m in ex.instance_meta
+            if m is not None and m.node_id == ct.node_ids[row]
+        )
+        ex.top_candidates.append(CandidateExplanation(
+            node_id=meta.node_id, node_row=int(row),
+            final_score=meta.norm_score, components=dict(meta.scores),
+            placed=k,
+        ))
+
+
+def _value_blocks(ct, kinds, values=5, counts0=None, desired=None,
+                  valueless_every=0):
+    """Stacked blocks over ``rack = row % values``, one per entry of
+    ``kinds``; block b shifts the racks by b so blocks disagree."""
+    from nomad_tpu.device.flatten import ValueBlocks
+
+    n, pn, b = ct.num_nodes, ct.padded_n, len(kinds)
+    vids = np.full((b, pn), -1, dtype=np.int32)
+    for i in range(b):
+        vids[i, :n] = (np.arange(n) + i) % values
+    if valueless_every:
+        vids[:, :n:valueless_every] = -1
+    return ValueBlocks(
+        value_ids=vids,
+        counts0=(np.zeros((b, values), dtype=np.float32)
+                 if counts0 is None else np.asarray(counts0, np.float32)),
+        desired=(np.full((b, values), -1.0, dtype=np.float32)
+                 if desired is None else np.asarray(desired, np.float32)),
+        caps=np.full((b, values), np.inf, dtype=np.float32),
+        weights=np.full(b, 1.0 / b, dtype=np.float32),
+        kinds=np.array(kinds, dtype=np.int32),
+    )
+
+
+def _replay_case(name):
+    """(cluster, ask, committed rows) of one equivalence case: 40 slots
+    on 300 nodes, the rows drawn from the seed (a replay does not ask
+    whether greedy placement would have picked them)."""
+    from nomad_tpu.device.score import (
+        BLOCK_DISTINCT_CAP,
+        BLOCK_EVEN_SPREAD,
+        BLOCK_TARGET_SPREAD,
+    )
+
+    ct = build_cluster(300, seed=11)
+    (a,) = build_asks(ct, 1, 40, seed=3)
+    rng = np.random.default_rng(5)
+    rows = rng.choice(ct.num_nodes, size=40, replace=False).astype(np.int32)
+    if name == "plain":
+        pass
+    elif name == "even-rack-spread":
+        a.blocks = _value_blocks(ct, [BLOCK_EVEN_SPREAD])
+    elif name == "even-spread-live-counts":
+        # the job already runs: uneven counts, and collisions on 12 rows
+        a.blocks = _value_blocks(
+            ct, [BLOCK_EVEN_SPREAD], counts0=[[3, 0, 1, 1, 7]]
+        )
+        a.job_counts[rows[:12]] = 1
+    elif name == "target-spread-desired":
+        a.blocks = _value_blocks(
+            ct, [BLOCK_TARGET_SPREAD], counts0=[[2, 0, 0, 5, 0]],
+            # value 3 is over its target, value 4 has none (-1)
+            desired=[[16, 12, 8, 4, -1]],
+        )
+    elif name == "two-spreads":
+        a.blocks = _value_blocks(
+            ct, [BLOCK_TARGET_SPREAD, BLOCK_EVEN_SPREAD],
+            desired=[[10, 10, 10, 5, 5], [-1] * 5],
+        )
+    elif name == "spread-and-distinct-cap":
+        a.blocks = _value_blocks(
+            ct, [BLOCK_DISTINCT_CAP, BLOCK_EVEN_SPREAD, BLOCK_DISTINCT_CAP]
+        )
+    elif name == "distinct-cap-alone":
+        a.blocks = _value_blocks(ct, [BLOCK_DISTINCT_CAP])
+    elif name == "valueless-nodes":
+        a.blocks = _value_blocks(
+            ct, [BLOCK_EVEN_SPREAD, BLOCK_TARGET_SPREAD],
+            desired=[[-1] * 5, [8] * 5], valueless_every=3,
+        )
+    elif name == "unplaced-slots":
+        a.blocks = _value_blocks(ct, [BLOCK_EVEN_SPREAD])
+        rows[[0, 7, 8, 39]] = -1
+    elif name == "two-on-one-node":
+        a.blocks = _value_blocks(ct, [BLOCK_EVEN_SPREAD])
+        rows[5], rows[20], rows[21] = rows[2], rows[2], rows[9]
+    elif name == "spread-affinity-penalty":
+        a.blocks = _value_blocks(ct, [BLOCK_EVEN_SPREAD])
+        a.has_affinities = True
+        a.affinity_scores = rng.uniform(-1, 1, ct.padded_n).astype(np.float32)
+        a.penalty_nodes[rows[[1, 4, 30]]] = True
+    elif name == "nothing-placed":
+        a.blocks = _value_blocks(ct, [BLOCK_EVEN_SPREAD])
+        rows[:] = -1
+    else:
+        raise AssertionError(name)
+    return ct, a, rows
+
+
+def _first_step(ct, a, rows, algorithm="binpack"):
+    """A lane as ``finalize_explanations`` finds it: the first step's
+    explanation on a result that holds the committed rows."""
+    ex = explain_group(
+        ct, a, ct.used, algorithm=algorithm,
+        algorithm_spread=algorithm == "spread",
+    )
+    return SimpleNamespace(node_rows=rows, scores=None, explanation=ex)
+
+
+REPLAY_CASES = [
+    "plain", "even-rack-spread", "even-spread-live-counts",
+    "target-spread-desired", "two-spreads", "spread-and-distinct-cap",
+    "distinct-cap-alone", "valueless-nodes", "unplaced-slots",
+    "two-on-one-node", "spread-affinity-penalty", "nothing-placed",
+]
+
+
+class TestArrayReplayMatchesSequential:
+    @pytest.mark.parametrize("algorithm", ["binpack", "spread"])
+    @pytest.mark.parametrize("case", REPLAY_CASES)
+    def test_same_provenance_as_the_sequential_replay(self, case, algorithm):
+        ct, a, rows = _replay_case(case)
+        res = _first_step(ct, a, rows, algorithm)
+        ex, want = res.explanation, copy.deepcopy(res.explanation)
+        _sequential_replay(ct, a, res, want)
+        stamped = finalize_explanations(ct, [a], [res])
+
+        placed = int((rows >= 0).sum())
+        assert stamped == {"instances": placed, "sequential_lanes": 0}
+        assert ex.placed_nodes == want.placed_nodes
+        assert len(ex.placed_nodes) == placed
+        assert len(ex.instance_meta) == len(want.instance_meta) == len(rows)
+        for i, (got, exp) in enumerate(
+            zip(ex.instance_meta, want.instance_meta)
+        ):
+            if exp is None:
+                assert got is None, i
+                continue
+            assert got.node_id == exp.node_id, i
+            # same keys in the same order of insertion
+            assert list(got.scores) == list(exp.scores), (i, got, exp)
+            assert got.scores == pytest.approx(exp.scores, abs=1e-6), i
+            assert got.norm_score == pytest.approx(
+                exp.norm_score, abs=1e-6
+            ), i
+            assert all(type(v) is float for v in got.scores.values())
+            assert type(got.norm_score) is float
+        assert [
+            (c.node_id, c.node_row, c.placed, list(c.components))
+            for c in ex.top_candidates
+        ] == [
+            (c.node_id, c.node_row, c.placed, list(c.components))
+            for c in want.top_candidates
+        ]
+        for got, exp in zip(ex.top_candidates, want.top_candidates):
+            assert got.final_score == pytest.approx(exp.final_score, abs=1e-6)
+            assert got.components == pytest.approx(exp.components, abs=1e-6)
+
+    def test_cases_reach_the_branches_they_name(self):
+        """The oracle agrees on whatever it is shown; this pins that the
+        cases show it the spread boost, both of its signs, a node without
+        a value, the collision and the penalty."""
+        seen = {}
+        for case in REPLAY_CASES:
+            ct, a, rows = _replay_case(case)
+            res = _first_step(ct, a, rows)
+            finalize_explanations(ct, [a], [res])
+            seen[case] = [
+                m.scores for m in res.explanation.instance_meta
+                if m is not None
+            ]
+        boosts = [
+            m["allocation-spread"] for m in seen["even-spread-live-counts"]
+            if "allocation-spread" in m
+        ]
+        assert min(boosts) < 0 < max(boosts)
+        assert not any(
+            "allocation-spread" in m for m in seen["distinct-cap-alone"]
+        )
+        # the first instance of an even spread with no counts sees boost 0
+        assert "allocation-spread" not in seen["even-rack-spread"][0]
+        assert "allocation-spread" in seen["even-rack-spread"][-1]
+        assert any(
+            m.get("allocation-spread") == -2.0
+            for m in seen["valueless-nodes"]
+        )
+        assert sum(
+            "job-anti-affinity" in m for m in seen["two-on-one-node"]
+        ) == 3
+        assert sum(
+            "job-anti-affinity" in m
+            for m in seen["even-spread-live-counts"]
+        ) == 12
+        assert sum(
+            "node-reschedule-penalty" in m
+            for m in seen["spread-affinity-penalty"]
+        ) == 3
+        assert seen["nothing-placed"] == []
+
+    def test_a_long_lane_is_replayed_in_chunks_that_carry_the_counts(
+        self, monkeypatch
+    ):
+        """The [instances, values] count array is bounded; a lane longer
+        than one chunk gives what one pass gives."""
+        ct, a, rows = _replay_case("two-spreads")
+        metas = []
+        for elems in (explain_mod._REPLAY_CHUNK_ELEMS, 5 * 7):
+            monkeypatch.setattr(explain_mod, "_REPLAY_CHUNK_ELEMS", elems)
+            res = _first_step(ct, a, rows)
+            finalize_explanations(ct, [a], [res])
+            metas.append([
+                (m.node_id, m.scores, m.norm_score)
+                for m in res.explanation.instance_meta
+            ])
+        assert metas[0] == metas[1]
+
+    def test_spread_lane_finalizes_without_a_call_per_instance(
+        self, monkeypatch
+    ):
+        """250 instances under an even rack spread on 2,000 nodes, placed
+        by the kernel and repaired: the finalize step reaches neither
+        ``_components_at`` nor ``_host_block_tables`` once per instance
+        (at most top_k calls; no wall-clock assertion), and still agrees
+        with the sequential replay."""
+        from nomad_tpu.device import score as score_mod
+        from nomad_tpu.device.score import BLOCK_EVEN_SPREAD
+
+        ct = build_cluster(2_000)
+        (a,) = build_asks(ct, 1, 250)
+        a.blocks = _value_blocks(ct, [BLOCK_EVEN_SPREAD], values=25)
+        kernel = PlacementKernel("binpack")
+        (res,) = kernel.place(ct, [a], explain=True)
+        repair_batch_conflicts(ct, [a], [res], algorithm_spread=False)
+        assert int((np.asarray(res.node_rows) >= 0).sum()) == 250
+        want = copy.deepcopy(res.explanation)
+        _sequential_replay(ct, a, res, want)
+
+        calls = {"_components_at": 0, "_host_block_tables": 0}
+
+        def counted(mod, name):
+            inner = getattr(mod, name)
+
+            def wrapper(*args, **kwargs):
+                calls[name] += 1
+                return inner(*args, **kwargs)
+
+            monkeypatch.setattr(mod, name, wrapper)
+
+        counted(explain_mod, "_components_at")
+        counted(score_mod, "_host_block_tables")
+        stamped = finalize_explanations(ct, [a], [res])
+        assert stamped == {"instances": 250, "sequential_lanes": 0}
+        assert calls["_components_at"] <= explain_mod.DEFAULT_TOP_K
+        assert calls["_host_block_tables"] <= explain_mod.DEFAULT_TOP_K
+        ex = res.explanation
+        assert ex.placed_nodes == want.placed_nodes
+        # a spread lane commits far outside the first-instance top 5
+        assert len(ex.top_candidates) > 200
+        assert [(c.node_row, c.placed) for c in ex.top_candidates] == [
+            (c.node_row, c.placed) for c in want.top_candidates
+        ]
+        for got, exp in zip(ex.instance_meta, want.instance_meta):
+            assert list(got.scores) == list(exp.scores)
+            assert got.scores == pytest.approx(exp.scores, abs=1e-6)
+            assert got.norm_score == pytest.approx(exp.norm_score, abs=1e-6)
+
+
+@pytest.fixture(scope="module")
+def final_step_spans():
+    """The ``explain`` spans with ``step: final`` of one solo pass (a
+    rack-spread job of 6) and one batched pass (a plain job of 3 and a
+    rack-spread job of 4, enqueued while the worker is paused), read from
+    the recorded traces: {"solo": [...], "batched": [...]}."""
+    import time
+
+    from nomad_tpu.obs.trace import global_tracer
+    from nomad_tpu.server import Server, ServerConfig
+    from nomad_tpu.structs.job import Spread
+
+    def job(job_id, count, spread):
+        j = mock.job()
+        j.id = job_id
+        j.task_groups[0].count = count
+        if spread:
+            j.spreads = [Spread(attribute="${meta.rack}", weight=100)]
+        return j
+
+    global_tracer.set_enabled(True)
+    got = {}
+
+    def keep(trace):
+        got[trace["eval_id"]] = trace
+
+    flight_recorder.add_listener(keep)
+    server = Server(ServerConfig(num_workers=1))
+    server.establish_leadership()
+    try:
+        for i in range(8):
+            server.register_node(mock.node(meta={"rack": f"r{i % 4}"}))
+        solo = server.register_job(job("tags-solo", 6, spread=True))
+        assert server.wait_for_evals(timeout=60)
+        for w in server.workers:
+            w.pause()
+        # the worker may be inside one more dequeue poll (0.2 s): an eval
+        # enqueued during it would be taken alone
+        time.sleep(0.3)
+        batched = [
+            server.register_job(job("tags-plain", 3, spread=False)),
+            server.register_job(job("tags-spread", 4, spread=True)),
+        ]
+        for w in server.workers:
+            w.resume()
+        assert server.wait_for_evals(timeout=60)
+        want = {solo.id, *(ev.id for ev in batched)}
+        deadline = time.time() + 5.0
+        while time.time() < deadline and not want <= set(got):
+            time.sleep(0.02)
+        allocs = {
+            j: server.store.allocs_by_job("default", j)
+            for j in ("tags-solo", "tags-plain", "tags-spread")
+        }
+    finally:
+        server.shutdown()
+        flight_recorder.remove_listener(keep)
+    assert {j: len(v) for j, v in allocs.items()} == {
+        "tags-solo": 6, "tags-plain": 3, "tags-spread": 4,
+    }
+
+    def final_steps(eval_ids):
+        return [
+            s for eid in eval_ids for s in got[eid]["spans"]
+            if s["name"] == "explain" and s["tags"].get("step") == "final"
+        ]
+
+    assert got[solo.id]["tags"]["path"] == "solo"
+    assert {got[ev.id]["tags"]["path"] for ev in batched} == {"batched"}
+    return {
+        "solo": final_steps([solo.id]),
+        "batched": final_steps(ev.id for ev in batched),
+        "allocs": allocs,
+    }
+
+
+class TestFinalStepTags:
+    @pytest.mark.parametrize(
+        "path,instances", [("solo", 6), ("batched", 3 + 4)]
+    )
+    def test_span_says_how_many_rows_it_stamped_and_how(
+        self, final_step_spans, path, instances
+    ):
+        """One final step a pass, written once (a batched pass's in its
+        leader's trace): ``instances`` = the rows the pass placed,
+        ``sequential_lanes`` = 0 for a spread lane and a plain lane alike
+        (both take the array replay)."""
+        (span,) = final_step_spans[path]
+        assert span["tags"]["instances"] == instances
+        assert span["tags"]["sequential_lanes"] == 0
+
+    def test_every_alloc_commits_with_its_own_score_row(
+        self, final_step_spans
+    ):
+        """Solo or batched, spread or plain: one ``score_meta`` row an
+        allocation, for the node it landed on; a spread job's rows carry
+        the boost once a rack holds an instance."""
+        for job_id, allocs in final_step_spans["allocs"].items():
+            for alloc in allocs:
+                (meta,) = alloc.metrics.score_meta
+                assert meta.node_id == alloc.node_id
+                assert "binpack" in meta.scores
+            boosted = sum(
+                "allocation-spread" in alloc.metrics.score_meta[0].scores
+                for alloc in allocs
+            )
+            # all but the first instance, which sees no count yet
+            assert boosted == (0 if job_id == "tags-plain" else len(allocs) - 1)
+
+    def test_hetero_lane_counts_as_sequential(self):
+        """A hetero lane's per-instance score is the joint pass's own;
+        it is stamped one by one and says so."""
+        from nomad_tpu.scheduler.hetero import (
+            HeteroPlacementKernel,
+            build_mixed_asks,
+            build_mixed_fleet,
+        )
+
+        ct = build_mixed_fleet(200, seed=42)
+        asks = build_mixed_asks(ct, 2, 5, seed=7)
+        results = HeteroPlacementKernel("maxmin").place(
+            ct, asks, explain=True
+        )
+        repair_batch_conflicts(ct, asks, results, algorithm_spread=False)
+        stamped = finalize_explanations(ct, asks, results)
+        explained = [r for r in results if r.explanation is not None]
+        assert explained
+        assert stamped == {
+            "instances": sum(
+                int((np.asarray(r.node_rows) >= 0).sum()) for r in explained
+            ),
+            "sequential_lanes": len(explained),
+        }
+        for r in explained:
+            for i, meta in zip(
+                np.flatnonzero(np.asarray(r.node_rows) >= 0),
+                [m for m in r.explanation.instance_meta if m is not None],
+            ):
+                assert meta.scores == {"throughput": float(r.scores[i])}
+                assert meta.norm_score == float(r.scores[i])
 
 
 # -- observational invariance ----------------------------------------------
