@@ -55,7 +55,10 @@ class Driver:
     """Sends the cell's traffic to ``server`` and records every request."""
 
     def __init__(self, server, specs, make_job, live_jobs,
-                 steady_jobs: int, patient: bool = False):
+                 steady_jobs: int, patient: bool = False,
+                 traffic=None, seed=None):
+        # ``traffic`` and ``seed`` are the harness's to every driver; this
+        # one's rule (register, deregister the oldest) needs neither
         self.server = server
         self.specs = specs  # iterator of plain job specs
         self.make_job = make_job

@@ -52,9 +52,10 @@ def fleet_node_id(i: int) -> str:
     return f"00000000-0000-4000-8000-{i:012d}"
 
 
-def seed_fleet(server, fleet: dict) -> dict:
-    """Upsert the fleet straight into state (set-up, not the measured
-    path), as ``bench.seed_fleet`` does. Returns the plain spec."""
+def seed_fleet(server, config: dict) -> dict:
+    """Upsert the configuration's fleet straight into state (set-up, not
+    the measured path), as ``bench.seed_fleet`` does. Returns the plain
+    spec."""
     from nomad_tpu.structs import (
         NODE_STATUS_READY,
         Node,
@@ -62,6 +63,7 @@ def seed_fleet(server, fleet: dict) -> dict:
         NodeResources,
     )
 
+    fleet = config["fleet"]
     spec = fleet_spec(fleet)
     reserved = fleet["reserved"]
     racks = int(fleet.get("racks", 0))

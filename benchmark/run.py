@@ -14,6 +14,41 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name in ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<mix>.json``, ``metrics/<name>.json``
 naming a reader module under ``readers/``.
+
+So is the code that knows what a deployment is. A configuration file may
+hold a ``parts`` block and a traffic file one that overlays it; each entry
+names a module under ``benchmark/`` (``PARTS`` has the module an entry
+left out stands for). This file imports none of the five by name, reads no
+key of ``traffic["job"]`` or ``traffic["cycle"]`` and no field of a node or
+an allocation. The calls it makes are the contract of a part:
+
+``fleet``   ``seed_fleet(server, config) -> fleet``: upserts the nodes (and
+            sets what of the scheduler's configuration the deployment
+            states); returns the plain table the judge and the readers get,
+            a dict that holds ``n``.
+``jobs``    ``job_specs(traffic, seed, tag)``: endless iterator of plain
+            specs, dicts that hold ``id`` and ``count``;
+            ``make_job(spec)``: the program's job for one spec.
+``warm``    ``warm_shapes(server, traffic, make_job, log) -> requests``;
+            ``prefill(server, config, traffic, specs, make_job, seed, log)
+            -> (live, requests, steady_jobs)``: ``live`` and ``steady_jobs``
+            go to the driver unread; ``settle_admission(server, log)``.
+``driver``  ``Driver(server, specs, make_job, live, steady_jobs,
+            traffic=, seed=)`` with ``run_open(due, lead_in_s, seconds,
+            on_open, on_close)`` and ``run_closed(in_flight, lead_in_s,
+            seconds, on_open, on_close)``, both returning ``t_open`` and
+            ``t_close``; ``requests``: records with ``kind`` ("register"
+            carries the latency), ``job_id``, ``due``, ``sent``, ``done``,
+            ``ok``, ``placed``, ``note`` (and ``eval_id``, which the
+            ``latency_untraced`` reader looks up); ``live_alloc_track``:
+            ``(t, live allocations)`` at each completion.
+``judge``   ``extract_answers(store, job_ids) -> answers`` (arrays, one row
+            an allocation, ``node`` among them);
+            ``judge(fleet, specs, requests, answers, window, seed) ->
+            numbers``, which ``limits`` in the configuration's file holds.
+
+``make_job`` as the other parts get it also records the spec for the judge.
+``check.program_failures`` and ``check.verdict`` belong to no deployment.
 """
 
 from __future__ import annotations
@@ -36,6 +71,16 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 OUT_DIR = os.path.join(ROOT, ".bench_out")  # traces; listed in .gitignore
+
+# part -> (the module under benchmark/ an entry left out stands for, what
+# the module has to hold); the docstring above has the calls
+PARTS = {
+    "fleet": ("gen.fleet", ("seed_fleet",)),
+    "jobs": ("gen.jobs", ("job_specs", "make_job")),
+    "warm": ("warm", ("warm_shapes", "prefill", "settle_admission")),
+    "driver": ("driver", ("Driver",)),
+    "judge": ("check", ("extract_answers", "judge")),
+}
 
 
 def log(msg: str) -> None:
@@ -63,6 +108,41 @@ def load_cell(workload: str, rehearse: bool) -> tuple:
     if rehearse:
         apply_rehearsal(config, traffic)
     return cell, bench, config, traffic
+
+
+def resolve_parts(cell: dict, config: dict, traffic: dict) -> dict:
+    """part -> module, the traffic file's ``parts`` over the
+    configuration's over ``PARTS``. A part nobody has, a module that does
+    not import or one that lacks a function of its part ends the run here,
+    naming the file and the key."""
+    named = {}  # part -> (module name, the file that names it)
+    for kind, file_name, block in (
+        ("configs", cell["config"], config),
+        ("traffic", cell["traffic"], traffic),
+    ):
+        where = f"benchmark/{kind}/{file_name}.json"
+        for part, name in block.get("parts", {}).items():
+            if part not in PARTS:
+                raise SystemExit(
+                    f"{where}: parts.{part}: no such part; have {sorted(PARTS)}"
+                )
+            named[part] = (name, where)
+    parts = {}
+    for part, (default, needs) in PARTS.items():
+        name, where = named.get(part, (default, "benchmark/run.py PARTS"))
+        try:
+            module = importlib.import_module(f"benchmark.{name}")
+        except ImportError as e:
+            raise SystemExit(
+                f"{where}: parts.{part}: benchmark.{name} does not import: {e}"
+            )
+        lacks = [f for f in needs if not callable(getattr(module, f, None))]
+        if lacks:
+            raise SystemExit(
+                f"{where}: parts.{part}: benchmark.{name} lacks {lacks}"
+            )
+        parts[part] = module
+    return parts
 
 
 def apply_rehearsal(*blocks: dict) -> None:
@@ -132,14 +212,13 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     cell, bench, config, traffic = load_cell(args.workload, args.rehearse)
+    parts = resolve_parts(cell, config, traffic)
+    fleet_part, jobs, warm, driver_part, judge = (parts[p] for p in PARTS)
     device = device_block(int(cell["chips"]), args.rehearse)
     log(f"{args.workload} seed={args.seed} on {device}")
 
-    from benchmark import check, trace_reduce, warm
-    from benchmark.driver import Driver
+    from benchmark import check, trace_reduce
     from benchmark.gen.arrivals import arrival_times
-    from benchmark.gen.fleet import seed_fleet
-    from benchmark.gen.jobs import job_specs, make_job
     from benchmark.readers import latency_quantile
     from benchmark.spans import quantile
     from nomad_tpu.obs.recorder import flight_recorder
@@ -152,19 +231,16 @@ def main(argv=None) -> int:
 
     def remember(spec: dict):
         sent_specs[len(sent_specs)] = spec
-        return make_job(spec)
+        return jobs.make_job(spec)
 
     traces: list = []
     try:
-        fleet = seed_fleet(server, config["fleet"])
+        fleet = fleet_part.seed_fleet(server, config)
         log(f"fleet of {fleet['n']} nodes seeded")
         setup_requests = warm.warm_shapes(server, traffic, remember, log)
-        per_job = int(traffic["job"]["count"])
-        steady_jobs = int(config["live_allocs"]) // per_job
-        specs = job_specs(traffic, args.seed, "j")
-        live, prefill_requests = warm.prefill(
-            server, traffic, specs, remember, steady_jobs,
-            int(traffic.get("prefill_in_flight", 32)), log,
+        specs = jobs.job_specs(traffic, args.seed, "j")
+        live, prefill_requests, steady_jobs = warm.prefill(
+            server, config, traffic, specs, remember, args.seed, log
         )
         setup_requests += prefill_requests
         warm.settle_admission(server, log)
@@ -174,7 +250,10 @@ def main(argv=None) -> int:
         gc.collect()
         gc.freeze()
 
-        driver = Driver(server, specs, remember, live, steady_jobs)
+        driver = driver_part.Driver(
+            server, specs, remember, live, steady_jobs,
+            traffic=traffic, seed=args.seed,
+        )
         before: dict = {}
         after: dict = {}
         trace_dir = os.path.join(OUT_DIR, args.workload)
@@ -233,7 +312,7 @@ def main(argv=None) -> int:
         device["memory_peak_bytes"] = memory_peak_bytes()
         program = check.program_failures(server)
         job_ids = {s["id"]: j for j, s in sent_specs.items()}
-        answers = check.extract_answers(server.store, job_ids)
+        answers = judge.extract_answers(server.store, job_ids)
     finally:
         server.shutdown()
     log(f"answers read: {answers['node'].shape[0]} allocations")
@@ -271,7 +350,7 @@ def main(argv=None) -> int:
     }
 
     # -- correct -------------------------------------------------------------
-    numbers = check.judge(
+    numbers = judge.judge(
         fleet, sent_specs, setup_requests + driver.requests, answers,
         (t_open, t_close), args.seed,
     )

@@ -76,14 +76,11 @@ def main(argv=None) -> int:
 
     quiet = lambda _msg: None  # noqa: E731
     try:
-        fleet = seed_fleet(server, config["fleet"])
+        fleet = seed_fleet(server, config)
         setup = warm.warm_shapes(server, traffic, remember, quiet)
-        per_job = int(traffic["job"]["count"])
-        steady = int(config["live_allocs"]) // per_job
         specs = job_specs(traffic, args.seed, "j")
-        live, more = warm.prefill(
-            server, traffic, specs, remember, steady,
-            int(traffic["prefill_in_flight"]), quiet,
+        live, more, steady = warm.prefill(
+            server, config, traffic, specs, remember, args.seed, quiet
         )
         driver = Driver(server, specs, remember, live, steady)
         window = driver.run_closed(

@@ -51,6 +51,19 @@ def test_cell_runs_end_to_end(cell, trace):
     assert result["steady"]["admission_level_at_open"] == "normal"
 
 
+@pytest.mark.parametrize("cell", cells())
+def test_cell_names_no_part_and_runs_todays_modules(cell):
+    from benchmark import run
+
+    entry, _bench, config, traffic = run.load_cell(cell, rehearse=False)
+    assert "parts" not in config and "parts" not in traffic
+    parts = run.resolve_parts(entry, config, traffic)
+    assert [m.__name__ for m in parts.values()] == [
+        "benchmark.gen.fleet", "benchmark.gen.jobs", "benchmark.warm",
+        "benchmark.driver", "benchmark.check",
+    ]
+
+
 def test_no_tpu_no_result():
     rc, result, err = run_script(
         "run.py", "--workload", cells()[0], "--seed", "1", "--seconds", "1",

@@ -92,11 +92,15 @@ def warm_shapes(server, traffic: dict, make_job, log) -> list:
     return driver.requests
 
 
-def prefill(server, traffic: dict, specs, make_job, n_jobs: int,
-            in_flight: int, log) -> tuple:
-    """Register ``n_jobs`` of the cell's own mix through the served path,
-    ``in_flight`` at a time; returns the live FIFO ``[(job_id, count)]``
-    and the requests sent."""
+def prefill(server, config: dict, traffic: dict, specs, make_job,
+            seed: int, log) -> tuple:
+    """Register the configuration's live allocations in jobs of the cell's
+    own mix (``specs``, the window's stream: it needs no ``seed`` of its
+    own) through the served path, ``prefill_in_flight`` at a time; returns
+    the live FIFO ``[(job_id, count)]``, the requests sent and the number
+    of jobs the driver then keeps live."""
+    n_jobs = int(config["live_allocs"]) // int(traffic["job"]["count"])
+    in_flight = int(traffic.get("prefill_in_flight", 32))
     driver = Driver(server, specs, make_job, [], 0, patient=True)
     store = server.store
     sent = 0
@@ -115,7 +119,7 @@ def prefill(server, traffic: dict, specs, make_job, n_jobs: int,
             sent += 1
         driver._wait(seen, 0.25)
     log(f"pre-fill: {n_jobs} jobs live")
-    return list(driver.live), driver.requests
+    return list(driver.live), driver.requests, n_jobs
 
 
 def settle_admission(server, log) -> None:
