@@ -191,7 +191,8 @@ def run_preemption():
     )
     return found, choose_preemption_node_kernel(
         capacity, used, ask, eligible, victim_res, victim_prio,
-        victim_mask,
+        victim_mask, np.zeros((n, v), dtype=np.int32),
+        np.zeros(n, dtype=np.int32),
     )
 
 

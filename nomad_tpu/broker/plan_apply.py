@@ -388,6 +388,8 @@ def preemption_evals(store, result: PlanResult) -> list:
                 status=EVAL_STATUS_PENDING,
             )
         )
+    if evals:
+        metrics.incr("nomad.plan.preemption_evals", len(evals))
     return evals
 
 

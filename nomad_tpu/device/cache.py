@@ -27,6 +27,7 @@ from ..obs.trace import global_tracer
 from ..structs.resources import node_comparable_capacity
 from ..utils.metrics import global_metrics
 from .flatten import ClusterTensors, flatten_cluster
+from .preempt import carry_victim_cache
 
 
 def _node_used(snap, node_id: str, dims: int) -> np.ndarray:
@@ -125,6 +126,12 @@ class DeviceStateCache:
                 # pin depends on.
                 out.score_cache = self
             return out
+
+    def resident(self):
+        """The resident generation as it stands, or ``None``: its row
+        table, for a reader that has node ids and needs rows."""
+        with self._lock:
+            return self._ct
 
     def invalidate(self) -> None:
         with self._lock:
@@ -585,7 +592,22 @@ class DeviceStateCache:
                 continue  # alloc on an unknown node — nothing resident
             used[row] = _node_used(snap, nid, dims)
 
+        # the lazily filled per-row tables follow the rows: what was
+        # learned about an untouched node stays, a touched one is looked
+        # at again by whoever needs it next
+        touched = sorted(
+            {node_row[nid] for nid in alloc_nodes | node_keys
+             if nid in node_row}
+        )
+        device_caps = {}
+        for key, table in ct.device_caps.items():
+            table = table.copy()
+            table[touched] = np.nan
+            device_caps[key] = table
+        victim_cache = carry_victim_cache(ct.victim_cache, touched)
         self._ct = ClusterTensors(
+            device_caps=device_caps,
+            victim_cache=victim_cache,
             node_ids=node_ids,
             index=snap.index,
             num_nodes=num_nodes,

@@ -138,6 +138,17 @@ class ClusterTensors:
     # tensors anywhere but the DeviceStateCache refresh API is banned
     # (lint rule NTA019).
     score_cache: object = None
+    # device-instance accounting per ask shape (``_device_slot_caps``):
+    # key → f32[N] placements a node can still take device-wise, -1 where
+    # it has no such hardware, NaN where nobody has looked since the
+    # node's allocations last changed. Filled lazily in place, shared by
+    # the per-call wrappers; an incremental refresh carries it over with
+    # the touched rows set back to NaN, a rebuild starts empty.
+    device_caps: dict = field(default_factory=dict)
+    # preemption candidates per priority ceiling (device/preempt.py
+    # ``build_victim_tensors``), kept the same way: the padded victim
+    # tensors of the whole fleet, the rows touched since marked stale.
+    victim_cache: dict = field(default_factory=dict)
     # row-layout generation: bumped ONLY by a full reflatten (which may
     # re-sort rows); preserved across incremental refreshes and the
     # per-call used-copy. Consumers holding row-indexed overlays (the
@@ -790,6 +801,9 @@ def _value_blocks(
     )
 
 
+_NO_CAP = 1 << 30  # feasible_sets ends when the node's instances do
+
+
 def _device_slot_caps(
     ct, nodes_sorted, snap, tg, count, eligible, filter_stats
 ):
@@ -807,36 +821,66 @@ def _device_slot_caps(
         node_device_affinity,
     )
 
-    if not group_device_asks(tg):
+    asks = group_device_asks(tg)
+    if not asks:
         return None, np.zeros(ct.padded_n, dtype=np.float32), False
 
-    slot_caps = np.zeros(ct.padded_n, dtype=np.float32)
-    dev_aff = np.zeros(ct.padded_n, dtype=np.float32)
-    has_dev_aff = False
-    filtered = 0
-    for i in range(ct.num_nodes):
-        if not eligible[i]:
-            continue
+    # what a node can take device-wise depends on its hardware, on who
+    # holds its instances and on the shape of the ask, not on the job: one
+    # table per ask shape serves every eval until the node's allocations
+    # change (a snapshot older or newer than the tensors looks afresh)
+    n = ct.num_nodes
+    key = tuple(
+        (
+            a.name,
+            a.count,
+            tuple((c.l_target, c.r_target, c.operand) for c in a.constraints),
+            tuple(
+                (f.l_target, f.r_target, f.operand, f.weight)
+                for f in a.affinities
+            ),
+        )
+        for a in asks
+    )
+    shared = snap is not None and getattr(snap, "index", None) == ct.index
+    table = ct.device_caps.get(key) if shared else None
+    if table is None:
+        table = np.full(ct.padded_n, np.nan, dtype=np.float32)
+        if shared:
+            ct.device_caps[key] = table
+    for i in np.flatnonzero(np.isnan(table[:n])):
         node = nodes_sorted[i]
+        if not node.node_resources.devices:
+            table[i] = -1.0
+            continue
         in_use = (
             collect_in_use(snap.allocs_by_node(node.id))
             if snap is not None
             else {}
         )
-        sets = feasible_sets(node, in_use, tg, count)
-        slot_caps[i] = sets
+        sets = feasible_sets(node, in_use, tg, _NO_CAP)
         if sets == 0 and feasible_sets(node, {}, tg, 1) == 0:
             # no matching device *hardware* at all — hard filter
             # (DeviceChecker, feasible.go:1173). Nodes whose devices are
             # merely held by other allocs keep eligible=True with
             # slot_caps=0: the scan can't place there, but the preemption
             # fallback still may (PreemptForDevice's candidate set).
-            eligible[i] = False
-            filtered += 1
-        elif sets > 0:
-            s, has = node_device_affinity(node, tg)
+            sets = -1
+        table[i] = sets
+    no_hardware = eligible[:n] & (table[:n] < 0)
+    filtered = int(no_hardware.sum())
+    eligible[:n] &= ~no_hardware
+    slot_caps = np.zeros(ct.padded_n, dtype=np.float32)
+    slot_caps[:n] = np.where(
+        eligible[:n], np.clip(table[:n], 0, count), 0.0
+    )
+    dev_aff = np.zeros(ct.padded_n, dtype=np.float32)
+    has_dev_aff = False
+    if any(a.affinities for a in asks):
+        for i in np.flatnonzero(slot_caps[:n] > 0):
+            s_aff, has = node_device_affinity(nodes_sorted[i], tg)
             if has:
-                dev_aff[i] = s
+                dev_aff[i] = s_aff
                 has_dev_aff = True
     if filtered:
         cf = filter_stats.setdefault("constraint_filtered", {})

@@ -1739,6 +1739,13 @@ def _decorrelate_lanes(
         else:
             jn = np.full(pn, float(a.count))
         jn = np.where(a.eligible, jn, 0.0)
+        if a.slot_caps is not None:
+            # a node takes no more instances than its free device
+            # instances allow (the kernel's jmax has the same cap): on a
+            # fleet whose instances are nearly all held, headroom counted
+            # in cpu and memory alone cuts a stripe that holds almost none
+            # of the free instances, and the lane leaves them unused
+            jn = np.minimum(jn, a.slot_caps)
 
         # full-set value vocabulary per block, computed ONCE per ask —
         # the reachability closure runs up to twice per lane in the hot

@@ -338,7 +338,6 @@ class GenericScheduler:
         self.failed_tg_allocs = {}
         self.explanations = {}  # tg_name → PlacementExplanation
         self.followup_evals = []
-        self._preempt_rank_cache = {}  # per-attempt: ct/used change
         self.job = self.snapshot.job_by_id(ev.namespace, ev.job_id)
         self.plan = ev.make_plan(self.job)
         self.plan.snapshot_index = getattr(self.snapshot, "index", 0)
@@ -546,40 +545,17 @@ class GenericScheduler:
             # device assignment is per-ALLOC; skip the whole path for the
             # common deviceless group (profiled at 23µs × every alloc)
             tg_has_devices = bool(group_device_asks(tg))
+            unplaced = []  # instances the kernel found no room for
             for i, (pr, row, score) in enumerate(
                 zip(prs, res.node_rows, res.scores)
             ):
+                if row < 0:
+                    unplaced.append(pr)
+                    continue
                 metric = AllocMetric(
                     nodes_evaluated=ct.num_nodes,
                     nodes_available=dict(nodes_available),
                 )
-                if row < 0:
-                    # second pass with preemption enabled
-                    # (generic_sched.go:773-792 selectNextOption)
-                    placed = self._try_preempt(ct, pr, tg_name, ga, comparable)
-                    if placed:
-                        continue
-                    metric.coalesced_failures = 0
-                    # explainability: why nodes were filtered/exhausted
-                    # (AllocMetric, structs.go:10034-10079)
-                    fs = ga.filter_stats
-                    metric.nodes_filtered = fs.get("nodes_filtered", 0)
-                    metric.constraint_filtered = dict(
-                        fs.get("constraint_filtered", {})
-                    )
-                    metric.class_filtered = dict(fs.get("class_filtered", {}))
-                    self._record_exhaustion(metric, ct, ga)
-                    if explanation is not None:
-                        # near-miss table + structured rejection histogram
-                        # ride the failed metric into the blocked eval
-                        from ..obs.explain import candidates_as_score_meta
-
-                        metric.score_meta = candidates_as_score_meta(
-                            explanation
-                        )
-                        metric.rejections = dict(explanation.rejections)
-                    self._record_failure(tg_name, metric)
-                    continue
                 node_id = ct.node_ids[row]
                 metric.scores[f"{node_id}.score"] = float(score)
                 if instance_meta is not None and instance_meta[i] is not None:
@@ -643,6 +619,40 @@ class GenericScheduler:
                         )
                         alloc.reschedule_tracker = RescheduleTracker(events=events)
                 self.plan.append_alloc(alloc)
+            if unplaced:
+                # second pass with preemption enabled
+                # (generic_sched.go:773-792 selectNextOption), once the
+                # group's own placements are in the plan: distinct_hosts
+                # then sees every node the group already took
+                unplaced = self._preempt_group(
+                    ct, tg, unplaced, ga, comparable
+                )
+            for _pr in unplaced:
+                existing = self.failed_tg_allocs.get(tg_name)
+                if existing is not None:
+                    existing.coalesced_failures += 1
+                    continue
+                metric = AllocMetric(
+                    nodes_evaluated=ct.num_nodes,
+                    nodes_available=dict(nodes_available),
+                )
+                # explainability: why nodes were filtered/exhausted
+                # (AllocMetric, structs.go:10034-10079)
+                fs = ga.filter_stats
+                metric.nodes_filtered = fs.get("nodes_filtered", 0)
+                metric.constraint_filtered = dict(
+                    fs.get("constraint_filtered", {})
+                )
+                metric.class_filtered = dict(fs.get("class_filtered", {}))
+                self._record_exhaustion(metric, ct, ga)
+                if explanation is not None:
+                    # near-miss table + structured rejection histogram
+                    # ride the failed metric into the blocked eval
+                    from ..obs.explain import candidates_as_score_meta
+
+                    metric.score_meta = candidates_as_score_meta(explanation)
+                    metric.rejections = dict(explanation.rejections)
+                self._record_failure(tg_name, metric)
         self._enforce_gang_atomicity(ct)
 
     GANG_RELEASE_DESC = "alloc released: gang member group failed placement"
@@ -780,26 +790,27 @@ class GenericScheduler:
             else cfg.preemption_service_enabled
         )
 
-    def _try_preempt(self, ct, pr, tg_name, ga, comparable) -> bool:
-        """Preemption fallback for one failed placement: one device pass
-        per GROUP ranks every node's cheapest feasible victim set
-        (device/preempt.py — the shortlist is cached across this plan's
-        failures, so G failed placements cost one [N, V] kernel pass, not
-        G); the final victim set on a shortlisted node is chosen by the
+    def _preempt_group(self, ct, tg, prs, ga, comparable) -> list:
+        """Preemption fallback for the instances of one group the kernel
+        found no room for; returns those still unplaced. One device pass
+        per GROUP orders every node by its cheapest feasible victim set
+        (device/preempt.rank_preemption_nodes: G failed instances cost one
+        [N, V] kernel pass, not G); each instance then walks that order
+        and the final victim set on a node is chosen by the
         reference-exact host greedy (preempt_host.select_victims:
         maxParallel penalty, reserved ports, device instances). Victims
         are evicted in-plan and the placement lands on their node
         (generic_sched.go:795 handlePreemptions)."""
         if not self._preemption_enabled() or self.job is None:
-            return False
+            return prs
         from ..device.preempt import (
             PREEMPTION_PRIORITY_DELTA,
             rank_preemption_nodes,
         )
-        from .preempt_host import select_victims
+        from ..utils.metrics import global_metrics
 
         if self.job.priority < PREEMPTION_PRIORITY_DELTA:
-            return False
+            return prs
         # hard constraints still bind under preemption: distinct_hosts
         # excludes nodes already holding this job (snapshot + in-plan)
         eligible = ga.eligible
@@ -809,7 +820,6 @@ class GenericScheduler:
                 if any(a.job_id == self.job.id for a in allocs):
                     r = ct.node_row.get(node_id)
                     if r is not None:
-                        eligible = eligible.copy()
                         eligible[r] = False
         # allocs already evicted by this plan free capacity exactly once
         already_preempted = {
@@ -817,55 +827,119 @@ class GenericScheduler:
             for allocs in self.plan.node_preemptions.values()
             for a in allocs
         }
-        cache = getattr(self, "_preempt_rank_cache", None)
-        if cache is None:
-            cache = self._preempt_rank_cache = {}
-        shortlist = cache.get(tg_name)
-        if shortlist is None:
-            shortlist = rank_preemption_nodes(
-                ct,
-                self.snapshot,
-                self.job,
-                ga.ask,
-                eligible,
-                exclude_ids=already_preempted,
-            )
-            cache[tg_name] = shortlist
-        tg = self.job.lookup_task_group(tg_name)
-        row, victim_ids = None, []
-        for cand_row in shortlist:
-            # the shortlist is cached per group, but eligibility is
-            # recomputed per failure (distinct_hosts excludes nodes this
-            # plan already used) — stale rows are skipped, not trusted
-            if not eligible[cand_row]:
-                continue
-            ids = select_victims(
-                ct,
-                self.snapshot,
-                self.job,
-                tg,
-                ga.ask,
-                cand_row,
-                plan=self.plan,
-                exclude_ids=already_preempted,
-            )
-            if ids:
-                row, victim_ids = cand_row, ids
-                break
-        if row is None or not victim_ids:
-            return False
+        order, rank_score = rank_preemption_nodes(
+            ct,
+            self.snapshot,
+            self.job,
+            ga.ask,
+            eligible,
+            exclude_ids=already_preempted,
+            ask_devices=sum(
+                d.count for t in tg.tasks for d in t.resources.devices
+            ),
+        )
+        left = []
+        victims = rollbacks = 0
+        with tracer.span("preempt.select", tags={"instances": len(prs)}) as sp:
+            # rows before ``cursor`` are spent for the whole group: struck
+            # by distinct_hosts, or with nothing left that evicting would
+            # make fit. The order is not ranked again after a placement:
+            # a placement changes its own node only, which distinct_hosts
+            # strikes; without it a node is taken again while victims last
+            cursor = 0
+            for pr in prs:
+                placed, i = 0, cursor
+                while i < len(order) and not placed:
+                    row = order[i]
+                    placed = (
+                        self._preempt_on_row(
+                            ct, tg, pr, ga, comparable, row,
+                            already_preempted, float(rank_score[row]),
+                        )
+                        if eligible[row]
+                        else 0
+                    )
+                    if placed < 0:
+                        # the victims freed no device instance: the
+                        # reference moves on to the next node for this
+                        # placement and offers this one again to the next
+                        rollbacks += 1
+                        placed = 0
+                        i += 1
+                    elif not placed:
+                        if i == cursor:
+                            cursor += 1
+                        i += 1
+                    elif ga.distinct_hosts:
+                        eligible[row] = False
+                if placed:
+                    victims += placed
+                else:
+                    left.append(pr)
+            if sp is not None:
+                sp.tags.update(victims=victims, device_rollbacks=rollbacks)
+        global_metrics.incr("nomad.preempt.placements", len(prs) - len(left))
+        global_metrics.incr("nomad.preempt.victims", victims)
+        if rollbacks:
+            global_metrics.incr("nomad.preempt.device_rollbacks", rollbacks)
+        if left:
+            global_metrics.incr("nomad.preempt.unplaced", len(left))
+        return left
+
+    def _preempt_on_row(
+        self, ct, tg, pr, ga, comparable, row, already_preempted, rank_score
+    ) -> int:
+        """Evict and place one instance on node ``row``: the number of
+        victims (they join ``already_preempted``), 0 where no victim set
+        makes the ask fit there, -1 where one did and freed no device
+        instance the group needs (rolled back). ``rank_score`` is what the
+        ranking kernel gave the row; the allocation records it."""
+        from .preempt_host import select_victims
+
+        victim_ids = select_victims(
+            ct,
+            self.snapshot,
+            self.job,
+            tg,
+            ga.ask,
+            row,
+            plan=self.plan,
+            exclude_ids=already_preempted,
+        )
+        if not victim_ids:
+            return 0
+        victims = [self.snapshot.alloc_by_id(vid) for vid in victim_ids]
+        if any(v is None for v in victims):
+            return 0
         node_id = ct.node_ids[row]
         alloc_id = new_id()
-        victim_total = None
-        for vid in victim_ids:
-            victim = self.snapshot.alloc_by_id(vid)
-            if victim is None:
-                return False
+        for victim in victims:
             self.plan.append_preempted_alloc(victim, alloc_id)
-            vec = victim.comparable_resources().to_vector()
-            victim_total = vec if victim_total is None else victim_total + vec
+        devices, dev_ok = self._assign_devices(tg, node_id)
+        if not dev_ok:
+            # victims chosen by resource distance didn't free the needed
+            # device instances: abandon this preemption rather than
+            # shipping a device-less alloc
+            from .device import rollback_plan_preemptions
+
+            rollback_plan_preemptions(self.plan, node_id, victim_ids)
+            return -1
+        from ..device.preempt import preemption_option_score
+
+        # the device-resident usage follows the plan for later fallbacks
+        ct.used[row] += ga.ask - sum(
+            v.comparable_resources().to_vector() for v in victims
+        )
         metric = AllocMetric(nodes_evaluated=ct.num_nodes)
         metric.scores[f"{node_id}.preemption"] = 1.0
+        # the kernel's own number for the node (its victim set at the
+        # ranking), beside the host's for the victims chosen
+        metric.scores[f"{node_id}.preemption-rank"] = rank_score
+        metric.scores[f"{node_id}.score"] = preemption_option_score(
+            ct.capacity[row],
+            ct.used[row],
+            sum(v.job.priority if v.job is not None else 50 for v in victims),
+        )
         alloc = Allocation(
             id=alloc_id,
             namespace=self.job.namespace,
@@ -875,7 +949,7 @@ class GenericScheduler:
             job_id=self.job.id,
             job=self.job,
             job_version=self.job.version,
-            task_group=tg_name,
+            task_group=tg.name,
             resources=comparable.copy(),
             desired_status=ALLOC_DESIRED_RUN,
             client_status="pending",
@@ -884,23 +958,11 @@ class GenericScheduler:
         )
         if pr.previous_alloc is not None:
             alloc.previous_allocation = pr.previous_alloc.id
-        tg = self.job.lookup_task_group(tg_name)
-        if tg is not None:
-            devices, dev_ok = self._assign_devices(tg, node_id)
-            if not dev_ok:
-                # victims chosen by resource distance didn't free the
-                # needed device instances — abandon this preemption
-                # rather than shipping a device-less alloc
-                from .device import rollback_plan_preemptions
-
-                rollback_plan_preemptions(self.plan, node_id, victim_ids)
-                return False
-            if devices:
-                alloc.allocated_devices = devices
+        if devices:
+            alloc.allocated_devices = devices
         self.plan.append_alloc(alloc)
-        # keep the device-resident usage honest for subsequent fallbacks
-        ct.used[row] += ga.ask - (victim_total if victim_total is not None else 0)
-        return True
+        already_preempted.update(victim_ids)
+        return len(victims)
 
     def _record_failure(self, tg_name: str, metric: AllocMetric) -> None:
         existing = self.failed_tg_allocs.get(tg_name)
@@ -912,9 +974,11 @@ class GenericScheduler:
     # -- completion -------------------------------------------------------
     def _finalize(self) -> None:
         ev = self.eval
-        if self.failed_tg_allocs and not self.batch:
-            # create/update blocked eval to hold unplaced work
-            # (generic_sched.go:193-212)
+        if self.failed_tg_allocs:
+            # create/update blocked eval to hold unplaced work, for a
+            # batch job as for a service job (generic_sched.go:193-212
+            # makes no exception: an evicted batch allocation comes back
+            # when room returns)
             blocked = ev.create_blocked_eval({}, True, "", self.failed_tg_allocs)
             blocked.status_description = BLOCKED_EVAL_FAILED_PLACEMENTS_DESC
             # carry the unplaced counts so parked blocked evals are

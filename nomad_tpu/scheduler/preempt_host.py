@@ -220,12 +220,15 @@ def _filter_superset(
 
 
 def preempt_for_devices(
-    snap, node, job, tg, exclude_ids=frozenset()
+    snap, node, job, tg, exclude_ids=frozenset(), plan=None
 ) -> Optional[list[Candidate]]:
     """PreemptForDevice (:472-555): per device ask, free held instances by
     preempting their holders in priority order; among sufficient options
     pick minimal net unique-priority (selectBestAllocs :558-604).
-    Returns None when an ask can't be covered even with preemption."""
+    Returns None when an ask can't be covered even with preemption.
+    Instances the in-flight ``plan`` has already handed out on this node
+    (an earlier instance of the same group took a victim's) count as held,
+    by holders that are no candidates."""
     from .device import collect_in_use, device_group_matches, group_device_asks
 
     asks = group_device_asks(tg)
@@ -239,7 +242,8 @@ def preempt_for_devices(
         and a.id not in exclude_ids
         and not (a.job_id == job.id and a.namespace == job.namespace)
     ]
-    in_use = collect_in_use(live)
+    placed = plan.node_allocation.get(node.id, []) if plan is not None else []
+    in_use = collect_in_use(live + list(placed))
     victims: dict[str, Candidate] = {}
     for ask in asks:
         # free instances per matching device group
@@ -334,7 +338,9 @@ def select_victims(
     )
     if port_victims is None:
         return None
-    dev_victims = preempt_for_devices(snap, node, job, tg, exclude_ids)
+    dev_victims = preempt_for_devices(
+        snap, node, job, tg, exclude_ids, plan=plan
+    )
     if dev_victims is None:
         return None
     seed = {c.alloc.id: c for c in port_victims}

@@ -191,7 +191,7 @@ class SystemScheduler:
                 # victims enter the plan BEFORE device assignment so
                 # collect_in_use sees their instances as freed; a failed
                 # assignment rolls the eviction back (the generic path's
-                # dev_ok contract, generic.py _try_preempt)
+                # dev_ok contract, generic.py _preempt_on_row)
                 victim_total = None
                 for vid in preempted_ids:
                     victim = self.snapshot.alloc_by_id(vid)

@@ -44,6 +44,9 @@ class SharedOverlay:
         self._base: Optional[np.ndarray] = None
         self._delta: Optional[np.ndarray] = None
         self._layout_gen = -1
+        # state index of the tensors the base was frozen from: a stop
+        # committed at or before it is in the base already
+        self._base_index = -1
         self._commits = 0
         self._passes = 0
         # lane mode: the one batching worker allowed to write deltas
@@ -65,6 +68,7 @@ class SharedOverlay:
                 self._base = None
                 self._delta = None
                 self._layout_gen = -1
+                self._base_index = -1
                 self._pending_nodes.clear()
                 return True
             return False
@@ -82,6 +86,7 @@ class SharedOverlay:
                 self._base = None
                 self._delta = None
                 self._layout_gen = -1
+                self._base_index = -1
                 self._pending_nodes.clear()
             if self._base is None:
                 return None
@@ -115,6 +120,7 @@ class SharedOverlay:
                 self._base = np.asarray(ct.used).copy()
                 self._delta = np.zeros_like(self._base)
                 self._layout_gen = ct.layout_gen
+                self._base_index = int(getattr(ct, "index", -1))
             if self._layout_gen != ct.layout_gen:
                 return  # layout changed mid-pass; skip (applier resolves)
             np.add.at(self._delta, rows, ask)
@@ -126,6 +132,42 @@ class SharedOverlay:
                     ri = int(r)
                     if 0 <= ri < len(ct_nodes):
                         self._pending_nodes.add(ct_nodes[ri].id)
+
+    def holds_base_before(self, layout_gen: int, index: int) -> bool:
+        """Whether ``release`` would take a stop committed at ``index``
+        off a base: the caller sums what was freed only then."""
+        with self._lock:
+            return (
+                self._base is not None
+                and self._layout_gen == layout_gen
+                and index > self._base_index
+            )
+
+    def release(
+        self, node_row: dict, layout_gen: int, freed: dict, index: int
+    ) -> None:
+        """A stop committed at state index ``index`` frees capacity that
+        the frozen base still counts: take it off the base, so the passes
+        of this epoch see the room (``freed``: node id → resource vector
+        of what was stopped). Without it the room a deregistration frees
+        stays invisible until the pipeline next goes idle, and the blocked
+        evals the stop unblocks run, find nothing and block again. A
+        placement of this epoch that is stopped again nets out: its ask
+        stays in the delta. A base frozen from tensors that saw the stop
+        (a worker's snapshot taken between the commit and this call) has
+        the room already: taking it off twice would show room that is not
+        there for the rest of the epoch."""
+        with self._lock:
+            if (
+                self._base is None
+                or self._layout_gen != layout_gen
+                or index <= self._base_index
+            ):
+                return
+            for node_id, vec in freed.items():
+                row = node_row.get(node_id)
+                if row is not None:
+                    self._base[row] -= vec
 
     def commit_started(self) -> None:
         with self._lock:
@@ -207,6 +249,15 @@ class LaneOverlays:
 
     def add_delta(self, ct, rows, ask, writer=None) -> None:
         self._overlays[0].add_delta(ct, rows, ask, writer=writer)
+
+    def holds_base_before(self, layout_gen, index) -> bool:
+        return any(
+            ov.holds_base_before(layout_gen, index) for ov in self._overlays
+        )
+
+    def release(self, node_row, layout_gen, freed, index) -> None:
+        for ov in self._overlays:
+            ov.release(node_row, layout_gen, freed, index)
 
     def commit_started(self) -> None:
         self._overlays[0].commit_started()

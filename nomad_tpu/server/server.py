@@ -369,7 +369,45 @@ class Server:
             self._msg.PLAN_RESULT,
             {"result": result, "eval_id": eval_id, "evals": evals},
         )
+        self._unblock_on_stops([result], index)
         return index
+
+    def _unblock_on_stops(self, results, index: int) -> None:
+        """A committed stop (a deregistration, a scale-down, a destructive
+        update) frees capacity the moment it lands: the scheduler counts
+        an allocation with desired status stop as gone. Blocked evals get
+        their chance then, as they do when a client reports an allocation
+        terminal (blocked_evals.go:55). An eviction frees nothing: the
+        preemptor's placement in the same plan takes the room."""
+        stopped: dict = {}
+        for r in results:
+            for node_id, allocs in r.node_update.items():
+                gone = [a for a in allocs if not a.client_terminal_status()]
+                if gone:
+                    stopped.setdefault(node_id, []).extend(gone)
+        if not stopped:
+            return
+        # the optimistic overlay scores an epoch's passes on a base frozen
+        # at its start: hand it the room first, then wake who waits for it
+        ct = self.device_cache.resident()
+        if ct is not None and self.placement_overlay.holds_base_before(
+            ct.layout_gen, index
+        ):
+            freed = {
+                node_id: sum(a.comparable_resources().to_vector() for a in gone)
+                for node_id, gone in stopped.items()
+            }
+            self.placement_overlay.release(
+                ct.node_row, ct.layout_gen, freed, index
+            )
+        # by the class of the nodes that gained room, as a client's
+        # terminal update does (blocked_evals.go:55 Unblock(computedClass))
+        classes = set()
+        for node_id in stopped:
+            node = self.store.node_by_id(node_id)
+            classes.add(node.computed_class if node is not None else "")
+        for cls in sorted(classes):
+            self.blocked_evals.unblock(computed_class=cls, index=index)
 
     def _commit_merged_plan_result(self, results, eval_ids, evals) -> int:
         """One batched pass's member results land as ONE log entry — the
@@ -378,6 +416,7 @@ class Server:
             self._msg.MERGED_PLAN_RESULT,
             {"results": results, "eval_ids": eval_ids, "evals": evals},
         )
+        self._unblock_on_stops(results, index)
         return index
 
     def _fresh_evals(self, evals):

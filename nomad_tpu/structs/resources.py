@@ -278,9 +278,18 @@ def _device_accounting_fits(node, allocs) -> tuple[bool, str]:
             1 for i in dev.instances if i.healthy
         )
     asks: dict[str, int] = {}
+    held: set = set()  # (device id, instance id) handed out concretely
     for alloc in allocs:
         for dev_id, count in getattr(alloc, "device_asks", lambda: {})().items():
             asks[dev_id] = asks.get(dev_id, 0) + count
+        # one instance, one holder (DeviceAccounter.AddAllocs reports a
+        # collision when an instance is used twice): two plans made on one
+        # snapshot each hand out the first free instance
+        for ad in getattr(alloc, "allocated_devices", None) or ():
+            for inst in ad.device_ids:
+                if (ad.id(), inst) in held:
+                    return False, f"device {ad.id()} instance {inst}"
+                held.add((ad.id(), inst))
     for dev_id in sorted(asks, key=lambda d: -d.count("/")):
         need = asks[dev_id]
         for cid in sorted(c for c in cap if _dev_id_matches(c, dev_id)):

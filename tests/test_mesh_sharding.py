@@ -352,6 +352,8 @@ _PREEMPT_SPECS = dict(
     victim_res=P("nodes", None, None),
     victim_prio=P("nodes", None),
     victim_mask=P("nodes", None),
+    victim_dev=P("nodes", None),
+    dev_need=P("nodes"),
 )
 
 
@@ -373,6 +375,12 @@ class TestPreemptKernelsSharded:
         """The knapsack's final argmax runs over the sharded node axis —
         the cross-shard tie-break must stay lowest-index."""
         case = _preempt_case(seed=9)
+        rng = np.random.default_rng(9)
+        n, v = case["victim_mask"].shape
+        # a third of the victims hold a device instance, half of the
+        # nodes lack one for the ask
+        case["victim_dev"] = (rng.random((n, v)) < 0.33).astype(np.int32)
+        case["dev_need"] = (rng.random(n) < 0.5).astype(np.int32)
         ref = choose_preemption_node_kernel(**case)
         mesh = _mesh(dp, mp)
         sharded = _shard(case, mesh, _PREEMPT_SPECS)
