@@ -23,7 +23,12 @@ candidate space is a dense [N, J] plane computed in one shot
   placement steps that does only O(N) *gather* work per step — the heads
   of each node's precomputed column plus a [B, V] per-value boost table —
   instead of rescoring every node against every resource dim. Exact
-  stepwise-greedy semantics at a fraction of the serial cost.
+  stepwise-greedy semantics at a fraction of the serial cost. The
+  one-per-value chunked kernel, the one a served cell's window reaches,
+  reads the same heads and tables by dense selects over node-minor
+  arrays instead: a TPU walks a gather element by element (123–171 us
+  for each [16384] vector on a v5e, against 3 us for the select over the
+  whole [24, 16384] plane).
 
 Batch dimension = concurrent evals/groups, replacing Nomad's worker-per-
 core optimistic concurrency (nomad/worker.go:85): every group in a batch
@@ -293,8 +298,9 @@ def _score_planes(
         # same node order and the applier bounces the later plans
         num = num + jitter[:, None]
     den = 1.0 + has_coll + pen[:, None] + jnp.where(has_aff, 1.0, 0.0)
-    # slim [1]-shaped lane inputs leave den rank-deficient; the gather
-    # paths index it per node, so materialize the broadcast
+    # slim [1]-shaped lane inputs leave den rank-deficient; the scan
+    # paths read it per node (by gather, or transposed to [J, N] and by
+    # select), so materialize the broadcast
     num = jnp.broadcast_to(num, fits.shape)
     den = jnp.broadcast_to(den, fits.shape)
     return num, den, fits
@@ -710,6 +716,33 @@ def place_spread_chunked_kernel(
     )
 
 
+def _column_heads(num_t, den_t, fits_t, jn):
+    """Each node's column head out of the node-minor ``[J, N]`` planes:
+    a one-hot select over J reduced along the sublane axis. A sum of one
+    selected value and zeros is exact, so these are bit for bit the
+    values ``take_along_axis(plane, min(jn, J-1))`` reads from ``[N, J]``
+    (tests/test_opv_dense_reads.py keeps that gather as the reference) —
+    without a gather, which the TPU serializes element by element."""
+    max_j = num_t.shape[0]
+    head_j = jnp.minimum(jn, max_j - 1)
+    sel = jnp.arange(max_j, dtype=jn.dtype)[:, None] == head_j[None, :]
+    head_num = jnp.sum(jnp.where(sel, num_t, 0.0), axis=0)
+    head_den = jnp.sum(jnp.where(sel, den_t, 0.0), axis=0)
+    head_fit = jnp.any(sel & fits_t, axis=0) & (jn < max_j)
+    return head_num, head_den, head_fit
+
+
+def _value_reads(member, tbl, allow):
+    """Per-node reads ``[B, N]`` of the ``[B, V]`` boost and allowance
+    tables through the membership compare ``member[b, v, n] = (vids[b, n]
+    == v)``: a masked sum / any over V. A node without a value matches no
+    v and reads 0.0 / False; the callers overwrite both under ``vids >=
+    0`` as they did the gather's."""
+    per_block = jnp.sum(jnp.where(member, tbl[:, :, None], 0.0), axis=1)
+    allow_pb = jnp.any(member & allow[:, :, None], axis=1)
+    return per_block, allow_pb
+
+
 @functools.partial(traced_jit, retrace_budget=RETRACE_BUDGET,
                    static_argnames=("max_j", "k_seg", "n_chunks"))
 def place_spread_opv_kernel(
@@ -752,6 +785,17 @@ def place_spread_opv_kernel(
     [V+1] segment maxima (the +1 segment holds value-less nodes).
     Depth count/min(CHUNK, V) instead of count — for the BASELINE
     config-3 shape (250 instances × 25 racks) that is 18 steps vs 512.
+
+    Inside the loop the node axis is the minor axis of everything a step
+    reads, and nothing is indexed dynamically: the column heads are a
+    one-hot select over the [J, N] planes (``_column_heads``), the boost
+    and allowance of a node's values a masked sum / any over the
+    [B, V, N] membership of ``block_value_ids`` (``_value_reads``), and
+    what a step needs of the nodes it picked (their values, the best
+    score) a masked reduce over a one-hot of the row. Every one is exact,
+    so picks and scores are those of the gathers these replaced
+    (tests/test_opv_dense_reads.py), at 0.04 ms a step on a v5e where the
+    seven gathers took 0.94 (PERF.md section 6, PR 30).
     """
 
     eligible, job_counts, penalty_nodes = _unpack_lane_inputs(
@@ -772,7 +816,12 @@ def place_spread_opv_kernel(
         nv = c0.shape[1]
         is_spread = (kinds == BLOCK_TARGET_SPREAD) | (kinds == BLOCK_EVEN_SPREAD)
         has_spread_any = jnp.any(is_spread)
-        safe_vids = jnp.maximum(vids, 0)  # [B, N]
+        # what the loop selects from, node axis minor, built once: the
+        # planes as [J, N], the [B, V, N] membership of ``vids`` and the
+        # row ids that the picks' one-hots compare against
+        num_jn, den_jn, fits_jn = num.T, den.T, fits.T
+        member = vids[:, None, :] == jnp.arange(nv)[None, :, None]
+        node_ids = jnp.arange(n)
         evids = jnp.take(vids, eidx, axis=0)  # [N] enforce-block values
         seg = jnp.where(evids >= 0, evids, nv)  # [N]; nv = no-value segment
         # which enforce-block values actually exist on an eligible node:
@@ -786,12 +835,11 @@ def place_spread_opv_kernel(
 
         def node_scores(head_num, head_den, head_ok, c):
             tbl, allow = _block_tables(c, desired, vcaps, weights, kinds)
-            per_block = jnp.take_along_axis(tbl, safe_vids, axis=1)
+            per_block, allow_pb = _value_reads(member, tbl, allow)
             contrib = jnp.where(vids >= 0, per_block, -1.0)
             boost = jnp.sum(
                 jnp.where(is_spread[:, None], contrib, 0.0), axis=0
             )
-            allow_pb = jnp.take_along_axis(allow, safe_vids, axis=1)
             allowed = jnp.all(
                 jnp.where(
                     (kinds == BLOCK_DISTINCT_CAP)[:, None] & (vids >= 0),
@@ -807,13 +855,9 @@ def place_spread_opv_kernel(
 
         def step(state, _):
             jn, c, n_placed = state
-            head_j = jnp.minimum(jn, max_j - 1)
-            gather = lambda plane: jnp.take_along_axis(
-                plane, head_j[:, None], axis=1
-            )[:, 0]
-            head_num = gather(num)
-            head_den = gather(den)
-            head_fit = gather(fits) & (jn < max_j)
+            head_num, head_den, head_fit = _column_heads(
+                num_jn, den_jn, fits_jn, jn
+            )
 
             # Two-phase chunk: spread counts sit at symmetric states (all
             # values even ⇒ every even-boost −1) at chunk boundaries, and
@@ -825,9 +869,13 @@ def place_spread_opv_kernel(
             # table, then pick the remaining k−1 one-per-value.
             score0 = node_scores(head_num, head_den, head_fit, c)
             first = jnp.argmax(score0).astype(jnp.int32)
-            ok0 = (score0[first] > -jnp.inf) & (n_placed < count)
-            v_first = seg[first]  # segment (nv = value-less)
-            first_vals = vids[:, first]  # [B]
+            first_hot = node_ids == first  # [N]
+            best0 = jnp.max(score0)
+            ok0 = (best0 > -jnp.inf) & (n_placed < count)
+            v_first = jnp.sum(jnp.where(first_hot, seg, 0))
+            first_vals = jnp.sum(
+                jnp.where(first_hot[None, :], vids, 0), axis=1
+            )  # [B]
             c1 = c + jnp.where(
                 (ok0 & (first_vals >= 0))[:, None],
                 jax.nn.one_hot(
@@ -894,14 +942,15 @@ def place_spread_opv_kernel(
 
             rows = jnp.concatenate([first[None], rows_r])
             take = jnp.concatenate([ok0[None], take_r])
-            vals_all = jnp.concatenate([score0[first][None], vals])
+            vals_all = jnp.concatenate([best0[None], vals])
 
+            rows_hot = node_ids[None, :] == rows[:, None]  # [k_seg, N]
             jn = jn + jnp.sum(
-                (jnp.arange(n)[None, :] == rows[:, None])
-                & take[:, None],
-                axis=0,
+                rows_hot & take[:, None], axis=0
             ).astype(jnp.int32)
-            picked_vals = vids[:, rows_r]  # [B, k_seg-1]
+            picked_vals = jnp.sum(
+                jnp.where(rows_hot[1:], vids[:, None, :], 0), axis=2
+            )  # [B, k_seg-1]
             upd = take_r[None, :] & (picked_vals >= 0)
             c = c1 + jnp.sum(
                 jnp.where(
