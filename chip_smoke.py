@@ -33,6 +33,7 @@ import time
 FLEET_NODES = 10_000
 PARITY_BAR_PCT = 0.5  # BASELINE's own bar
 EVAL_WAIT_S = 600.0  # a phase's first pass may hold a cold compile
+ADMISSION_PATIENCE_S = 120.0  # how long a client keeps re-sending
 SPREAD_KERNELS = ("place_spread_opv_kernel", "place_spread_chunked_kernel")
 
 
@@ -110,11 +111,33 @@ def _new_traces(before: dict, after: dict) -> dict:
     return out
 
 
+def device_path_failures() -> list[str]:
+    """Reasons this run did NOT stay on the device path: a kernel
+    breaker tripped or is not closed (its calls finished on the eager
+    reference path), or a scoring pass ran while degraded. Read from the
+    breaker registry as well as the counters: a ``global_metrics.reset()``
+    does not clear a trip."""
+    from nomad_tpu.resilience.breaker import snapshot_all
+    from nomad_tpu.utils.metrics import global_metrics
+
+    out = []
+    for name, b in sorted(snapshot_all().items()):
+        if b["trips"] or b["state"] != "closed":
+            out.append(
+                f"breaker {name}: state={b['state']} trips={b['trips']} "
+                f"last_error={b['last_error']}"
+            )
+    counters = global_metrics.snapshot()["counters"]
+    for key in ("fallback_calls", "fallback_passes"):
+        n = int(counters.get(f"nomad.resilience.{key}", 0))
+        if n:
+            out.append(f"nomad.resilience.{key}={n}")
+    return out
+
+
 def check_device_path(server) -> None:
     """The checks that hold after every phase: nothing fell off the
     device path, nothing was swallowed, nothing was nacked or failed."""
-    from bench import device_path_failures
-
     failures = device_path_failures()
     check(not failures, "; ".join(failures))
     c = _counters()
@@ -133,11 +156,50 @@ def check_device_path(server) -> None:
     check(not failed, f"{len(failed)} evals ended failed: {failed[:3]}")
 
 
+def alloc_accounting(server, expected: dict) -> dict:
+    """Full alloc accounting for the jobs in ``expected`` (job id ->
+    desired count): live allocs + allocs queued on blocked evals (with
+    their per-TG failure reasons) must equal the total asked;
+    ``unaccounted_allocs`` > 0 is a bug surface, not fine print."""
+    placed = sum(
+        1
+        for a in server.store.allocs()
+        if a.job_id in expected and not a.terminal_status()
+    )
+    blocked = [
+        bev
+        for bev in server.blocked_evals.captured()
+        if bev.job_id in expected
+    ]
+    blocked_queued = 0
+    failed_reasons: dict = {}
+    for bev in blocked:
+        blocked_queued += sum(bev.queued_allocations.values())
+        for metric in bev.failed_tg_allocs.values():
+            m = getattr(metric, "metric", metric)
+            for reason, cnt in (m.dimension_exhausted or {}).items():
+                failed_reasons[f"exhausted:{reason}"] = (
+                    failed_reasons.get(f"exhausted:{reason}", 0) + cnt
+                )
+            for reason, cnt in (m.constraint_filtered or {}).items():
+                failed_reasons[f"filtered:{reason}"] = (
+                    failed_reasons.get(f"filtered:{reason}", 0) + cnt
+                )
+    total = sum(expected.values())
+    return {
+        "placed": placed,
+        "total": total,
+        "blocked_evals": len(blocked),
+        "blocked_queued_allocs": blocked_queued,
+        "unaccounted_allocs": total - placed - blocked_queued,
+        "failed_tg_reasons": failed_reasons,
+    }
+
+
 def check_store(server, expected: dict) -> dict:
     """From the store: every alloc asked for is placed or queued on a
     blocked eval, and no node's summed alloc resources exceed its
     capacity (the host AllocsFit reference)."""
-    from bench import alloc_accounting
     from nomad_tpu.structs.resources import allocs_fit
 
     acct = alloc_accounting(server, expected)
@@ -158,12 +220,29 @@ def check_store(server, expected: dict) -> dict:
 # -- the served path ---------------------------------------------------------
 
 
+def send(request):
+    """One client request (a thunk calling a server entry point). A
+    register or scale the admission controller defers or sheds (HTTP:
+    429 + Retry-After) is re-sent after the delay it names, as a client
+    would — a cold compile inside a pass is a latency spike the
+    controller answers with exactly that."""
+    from nomad_tpu.server.admission import AdmissionRejected
+
+    deadline = time.monotonic() + ADMISSION_PATIENCE_S
+    while True:
+        try:
+            return request()
+        except AdmissionRejected as e:
+            if time.monotonic() >= deadline:
+                raise
+            time.sleep(e.retry_after)
+
+
 def _submit(server, requests, batch=None) -> None:
     """Send ``requests`` (thunks calling a server entry point) and wait
     for the broker to drain. With ``batch`` the workers are held until
     every request is enqueued, so the pass that follows carries exactly
     that many evals — the way warm-up reaches each compiled G bucket."""
-    from bench import send
     from nomad_tpu.server.admission import AdmissionRejected
     from nomad_tpu.server.worker import EVAL_BATCH_SIZE
 
@@ -203,7 +282,7 @@ def _warm_jobs(tag: str, g: int, per_job: int, spread_affinity: bool):
     alone in a pass (g = 1) gets the J bucket of its own ask, so that
     bucket is warmed once per ask class; in a wider pass the largest
     ask sets J for everyone."""
-    from bench import JOB_CPU_CHOICES, make_job
+    from nomad_tpu.mock import JOB_CPU_CHOICES, make_job
 
     seeds = iter(range(10_000_000 + 1000 * g, 10_000_000 + 1000 * (g + 1)))
     if g > 1:
@@ -228,7 +307,7 @@ def run_registration_phase(
 ) -> dict:
     """Warm every G bucket with throw-away jobs of the phase's own
     shape, drain them, then register the phase's jobs in one burst."""
-    from bench import make_job
+    from nomad_tpu.mock import make_job
 
     warm_ids = []
     for g in _warm_batches(server):
@@ -354,7 +433,7 @@ def run_phases(
     n_nodes: int, jobs_a: int, jobs_b: int, per_job: int, down_nodes: int
 ) -> dict:
     """Phases A–C against one live server on a seeded ``n_nodes`` fleet."""
-    from bench import seed_fleet
+    from nomad_tpu.mock import seed_fleet
     from nomad_tpu.server import Server, ServerConfig
 
     server = Server(ServerConfig(num_workers=1, num_batch_workers=1))
@@ -503,8 +582,8 @@ def run_direct_place(n_nodes: int, n_jobs: int, count: int) -> dict:
     ``run_smoke`` judges that last, after everything else is reported."""
     import numpy as np
 
-    from bench import build_asks, build_cluster
     from nomad_tpu.device.score import PlacementKernel
+    from nomad_tpu.mock import build_asks, build_cluster
     from nomad_tpu.utils.backend import MeshConfig, get_mesh
 
     ct = build_cluster(n_nodes)

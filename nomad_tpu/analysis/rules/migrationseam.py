@@ -19,7 +19,7 @@ Flagged: any call whose dotted leaf is ``migrate_plan_kernel``,
 inside ``nomad_tpu/scheduler/`` or ``nomad_tpu/server/``.
 
 Exempt: ``scheduler/migrate.py`` (the seam itself — batch assembly,
-oracle cross-check, and the ``bench.py defrag`` A/B harness) and
+oracle cross-check, and the ``run_defrag_ab`` A/B harness) and
 ``server/defrag.py`` (the controller that owns the two-phase protocol).
 ``nomad_tpu/device/`` is out of scope, as for NTA016: the rule polices
 dispatch, not implementation or parity pinning.

@@ -277,6 +277,26 @@ def _quiesce(server, timeout: float) -> bool:
     return False
 
 
+def _await_migrate_faults(server, plane, timeout: float) -> None:
+    """Hold the quiesce until every fault the caller scheduled at a
+    ``migrate.*`` site has fired. Only the defrag thread reaches those
+    sites, when one of its cycles plans a move; on a busy host that can
+    be after the op stream has drained, and a run that quiesced first
+    never exercised the seam it was scheduled for. Pending allocations
+    keep coming up meanwhile (only running ones migrate). Bounded: a
+    fault that never fires is the caller's finding, read off
+    ``triggered``."""
+    want = {
+        (s.site, s.index, s.action)
+        for s in plane.schedule
+        if s.site.startswith("migrate.")
+    }
+    deadline = time.time() + timeout
+    while want - set(plane.triggered) and time.time() < deadline:
+        _flip_pending(server)
+        time.sleep(0.02)
+
+
 def run_chaos(
     seed: int = 7,
     steps: int = 200,
@@ -364,6 +384,8 @@ def run_chaos(
         install(plane)
         try:
             workload = _drive_workload(server, seed, steps)
+            if schedule is not None:
+                _await_migrate_faults(server, plane, quiesce_timeout)
             quiesced = _quiesce(server, quiesce_timeout)
         finally:
             uninstall()

@@ -601,7 +601,7 @@ def place_spread_chunked_kernel(
     The exact gather-scan (place_value_scan_kernel) pays one sequential
     ``lax.scan`` step per placement — 250-instance groups compile to
     512-deep scans whose per-step work is a trivial gather+argmax, the
-    exact wrong shape for a TPU (the r3 e2e p99 of 11.6 s lives here).
+    exact wrong shape for a TPU.
     This kernel instead freezes the per-value boost/allowance tables for
     ``chunk`` placements at a time and selects each chunk with the same
     running-min-clamp + top-k used by the closed-form path, so a
@@ -891,10 +891,9 @@ def place_spread_opv_kernel(
             # dominant even block; each placement removes that value from
             # the min set. A chunk that keeps taking beyond the min set
             # pays the symmetric-state −1 boost for its tail picks and
-            # diverges from greedy (measured 11% corpus score loss at
-            # config-3). Restrict the one-per-value picks to the rotating
-            # set; the chunk under-fills and later chunks (or the host
-            # repair re-score) finish the remainder exactly.
+            # diverges from greedy. Restrict the one-per-value picks to
+            # the rotating set; the chunk under-fills and later chunks (or
+            # the host repair re-score) finish the remainder exactly.
             ecounts = c1[eidx]  # [V] enforce-block counts after the bump
             pos1 = ecounts > 0
             minc1 = jnp.min(jnp.where(pos1, ecounts, jnp.inf))
@@ -1283,7 +1282,6 @@ class PlacementKernel:
         overflow: int = OVERFLOW_CANDIDATES,
         decorrelate: bool = False,
         decorrelate_salt: int = 0,
-        decorrelate_workers: int = 1,  # concurrent batching workers
         used_override=None,  # [pn, D] optimistic usage (pipelined passes)
         explain: bool = False,  # attach score provenance (obs/explain)
     ) -> list[PlacementResult]:
@@ -1293,9 +1291,10 @@ class PlacementKernel:
         same nodes — the vector analog of the reference's per-worker
         shuffle sampling (stack.go:74-90); repair re-scores any shortfall
         against the full node set, so partitioning is purely an
-        optimization. ``decorrelate_salt`` (worker id) permutes the
-        stripes so CONCURRENT WORKERS' batches collide at ~1/stripes
-        instead of stripe-for-stripe."""
+        optimization. ``decorrelate_salt`` rotates which lane gets which
+        stripe and seeds the tie-break jitter; the worker derives it
+        from the work (the first eval's job lane in lane mode), not from
+        how many workers run."""
         if not asks:
             return []
         from ..resilience.breaker import degraded
@@ -1315,8 +1314,7 @@ class PlacementKernel:
         jitter = None
         if decorrelate:
             work = _decorrelate_lanes(
-                cluster, asks, salt=decorrelate_salt, used0=used0,
-                n_workers=decorrelate_workers,
+                cluster, asks, salt=decorrelate_salt, used0=used0
             )
             rows = np.arange(cluster.padded_n, dtype=np.int64)
             h = (rows * 2654435761 + (decorrelate_salt + 1) * 40503) & 0xFFFFFFFF
@@ -1416,12 +1414,11 @@ class PlacementKernel:
 
     @staticmethod
     def _j_bucket(n: int) -> int:
-        """Multiples of 16 up to 128, then multiples of 64. The r4
-        coarsening ({16,24,32,48,64,96,...}) cost a measured 1.6× on the
-        headline CPU kernel (J=96 where 80 suffices — plane work scales
-        with J and the padding waste is pure overhead); multiples of 16
-        keep padding ≤ 20% at the shapes that matter while a typical
-        workload still touches only 1-2 compiled variants."""
+        """Multiples of 16 up to 128, then multiples of 64. Plane work
+        scales with J and padding is pure overhead, so the buckets stay
+        fine (a sixteenth at a time) while a typical workload still
+        touches only 1-2 compiled variants; every bucket is a cold
+        compile (ROADMAP A8, C4: not re-measured on the chip)."""
         if n <= 16:
             return 16
         if n <= 24:
@@ -1646,9 +1643,8 @@ class PlacementKernel:
             # step picks DISTINCT nodes (the first pick and the one-per-value
             # segment picks are disjoint), so one node gains at most one
             # instance per step — head_j never exceeds n_chunks. At the
-            # config-3 shape this cuts the [N, J] planes ~3× (J 80 → 24):
-            # plane construction dominates the pass, so it's ~linear
-            # wall-clock.
+            # config-3 shape that is J 24 where the ask alone gives 80;
+            # what the kernel costs at that shape is in PERF.md §5–6.
             max_j = min(max_j, self._j_bucket(n_chunks + 1))
 
             batch["counts"] = np.minimum(
@@ -1711,58 +1707,44 @@ class PlacementKernel:
         return out
 
 
-def _decorrelate_lanes(
-    cluster, asks: list, salt: int = 0, used0=None, n_workers: int = 1
-) -> list:
+def _decorrelate_lanes(cluster, asks: list, salt: int = 0, used0=None) -> list:
     """Stripe each batch lane onto a disjoint subset of node rows
-    (row % n_lanes == lane). Concurrent lanes scoring the same snapshot
-    otherwise compute near-identical greedy sequences and pile onto the
-    same nodes — the r3 bench measured a 92.9% conflict-fallback rate.
-    The reference decorrelates its parallel workers by per-worker node
+    (hash(row) % n_lanes == lane). Concurrent lanes scoring the same
+    snapshot otherwise compute near-identical greedy sequences, pile onto
+    the same nodes and leave the conflicts to the host repair. The
+    reference decorrelates its parallel workers by per-worker node
     shuffling + limit sampling (stack.go:74-90); a 1/L stripe of a 10k
     cluster still offers each lane more candidates than the reference's
     ≥100-node sample. Lanes whose stripe leaves thin headroom (or whose
     constraints concentrate eligibility) keep the full node set — repair
-    resolves whatever conflicts remain."""
+    resolves whatever conflicts remain. What a stripe costs in placement
+    quality is in PERF.md §2 and §7 (jobs of a pass with several
+    registrations, against the best on offer)."""
     from dataclasses import replace
 
     n_lanes = len(asks)
     if n_lanes < 2:
         return asks
     pn = cluster.padded_n
-    # stripes decorrelate lanes WITHIN one batch; concurrent workers are
-    # decorrelated by the score jitter (mod-l permutations of the row
-    # index only relabel the same congruence classes, so salting the
-    # stripe math cross-worker is a no-op — the salt instead rotates
-    # which lane gets which class, and seeds the jitter in place())
+    # stripes decorrelate lanes WITHIN one batch. The salt rotates which
+    # lane gets which stripe and seeds the score jitter in place(); it is
+    # a function of the work (the first eval's job lane), so a run with
+    # more batching workers reproduces the one-worker placements. Across
+    # workers, lane ownership and claims (server/lanes.py) keep passes
+    # apart; nothing here knows the worker count.
     rows = np.arange(pn)
     # Stripe on a HASHED row index, not the raw row: raw `rows % l_eff`
     # interacts arithmetically with any attribute laid out periodically
     # over rows (racks assigned round-robin: rack = row % n_racks). When
     # gcd(l_eff, n_racks) > 1 each stripe reaches only n_racks/gcd of the
     # rack values, the reachability guard below rejects every lane, and
-    # the whole batch falls back to the full node set — measured as a
-    # 34× repair blow-up at 64 lanes × 25 racks. A multiplicative hash
-    # de-correlates stripe membership from any row-periodic attribute, so
-    # each stripe samples all values ~uniformly.
+    # the whole batch falls back to the full node set and to repair. A
+    # multiplicative hash de-correlates stripe membership from any
+    # row-periodic attribute, so each stripe samples all values
+    # ~uniformly.
     row_hash = (rows.astype(np.uint64) * np.uint64(2654435761)) & np.uint64(
         0xFFFFFFFF
     )
-    # CONCURRENT batching workers must not share stripes at all: the salt
-    # only rotates lane→stripe assignment within the same congruence
-    # classes, so two workers' passes land one lane from each on every
-    # stripe and argmax the same best nodes (measured 0.83+ conflict at
-    # 2×32 deep). Partition the node universe by worker FIRST (a second,
-    # independent hash so it doesn't alias the lane stripes), then stripe
-    # within each worker's slice.
-    worker_universe = None
-    if n_workers > 1:
-        h2 = (rows.astype(np.uint64) * np.uint64(0x9E3779B1)) & np.uint64(
-            0xFFFFFFFF
-        )
-        worker_universe = (h2 % np.uint64(n_workers)).astype(np.int64) == (
-            salt % n_workers
-        )
     free = np.asarray(cluster.capacity) - (
         np.asarray(cluster.used) if used0 is None else np.asarray(used0)
     )  # [pn, D]
@@ -1774,12 +1756,11 @@ def _decorrelate_lanes(
         # Widest stripe count that still leaves this lane comfortable
         # headroom, measured in feasible INSTANCE SLOTS (Σ per-node jmax),
         # not node count — a node holds many instances of one ask, and
-        # sizing by nodes (the old 2×count heuristic) capped l_eff at
-        # ~N/(2·count), forcing lanes to share stripes and collide (the
-        # measured 11.7 s repair blow-up at 64 lanes). When even the
-        # slot-based 1/n_lanes stripe is too thin, lanes SHARE coarser
-        # stripes (conflicts only within a stripe group) instead of
-        # abandoning decorrelation entirely.
+        # sizing by nodes caps l_eff at ~N/(2·count), which makes lanes
+        # share stripes and collide. When even the slot-based 1/n_lanes
+        # stripe is too thin, lanes SHARE coarser stripes (conflicts only
+        # within a stripe group) instead of abandoning decorrelation
+        # entirely.
         pos = a.ask > 0
         if pos.any():
             jn = np.floor(
@@ -1796,9 +1777,7 @@ def _decorrelate_lanes(
             # of the free instances, and the lane leaves them unused
             jn = np.minimum(jn, a.slot_caps)
 
-        # full-set value vocabulary per block, computed ONCE per ask —
-        # the reachability closure runs up to twice per lane in the hot
-        # decorrelation path
+        # full-set value vocabulary per block, computed ONCE per ask
         full_vals_per_block = (
             [
                 np.unique(
@@ -1827,21 +1806,8 @@ def _decorrelate_lanes(
                     return False
             return True
 
-        # this worker's node slice first (cross-worker disjointness),
-        # provided it still holds the lane's ask comfortably — else fall
-        # back to the full set and let repair/applier arbitrate
-        base_elig = a.eligible
-        if worker_universe is not None:
-            wu_elig = a.eligible & worker_universe
-            if (
-                float(jn[wu_elig].sum()) >= 2 * a.count
-                and int(wu_elig.sum()) >= 8
-                and values_reachable(wu_elig)
-            ):
-                base_elig = wu_elig
-        jn_w = np.where(base_elig, jn, 0.0)
-        total_elig = int(base_elig.sum())
-        slots = float(jn_w.sum())
+        total_elig = int(a.eligible.sum())
+        slots = float(jn.sum())
         l_eff = min(
             n_lanes,
             max(1, min(
@@ -1849,31 +1815,20 @@ def _decorrelate_lanes(
             )),
         )
         if l_eff < 2:
-            out.append(
-                replace(a, eligible=base_elig)
-                if base_elig is not a.eligible
-                else a
-            )
+            out.append(a)
             continue
         in_stripe = (
             (row_hash % np.uint64(l_eff)).astype(np.int64)
             == ((i + salt) % l_eff)
         )
-        elig = base_elig & in_stripe
+        elig = a.eligible & in_stripe
         # the stripe must still hold 2× the lane's ask in feasible slots
-        ok = float(jn_w[elig].sum()) >= 2 * a.count and int(
+        ok = float(jn[elig].sum()) >= 2 * a.count and int(
             elig.sum()
         ) >= 8
         if ok:
             ok = values_reachable(elig)
-        if ok:
-            out.append(replace(a, eligible=elig))
-        elif base_elig is not a.eligible:
-            # stripe rejected but the worker slice is viable: keep
-            # cross-worker disjointness at least
-            out.append(replace(a, eligible=base_elig))
-        else:
-            out.append(a)
+        out.append(replace(a, eligible=elig) if ok else a)
     return out
 
 

@@ -15,12 +15,12 @@ utils/backend.py):
   the recorder's trace feed into bounded histograms; ``run_soak``
   replays a seeded Poisson traffic schedule against a live cluster and
   reports against declared ``SloTargets`` (``/v1/agent/slo``,
-  ``nomad-tpu slo report``, ``bench.py soak``).
+  ``nomad-tpu slo report``; tier-1: ``tests/test_slo.py``).
 - **Calibration plane**: ``CalibrationTable`` gives every operational
   constant a provenance (``default``/``probe``/``learned``);
   ``ThroughputEstimator`` learns per-(device class × job profile)
   throughputs from the recorder's trace feed (``/v1/agent/calibration``,
-  ``nomad-tpu calibrate``, ``bench.py calib``).
+  ``nomad-tpu calibrate``, ``run_calib_ab``).
 """
 
 # calibrate imports before loadgen: loadgen pulls in the server stack,

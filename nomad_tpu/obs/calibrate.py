@@ -23,11 +23,13 @@ already measures the sustainable rate. This module closes both loops:
   NTA018 lint bans bare threshold literals outside this module, so a
   constant without provenance can't quietly reappear.
 * :func:`derive_admission_thresholds` + the ``CALIB_r01.json`` probe
-  artifact — ``bench.py soak --saturation`` persists the measured
-  sustainable rate; loading the artifact rewrites the admission
-  enter/exit backlog thresholds from Little's law (backlog = rate ×
-  tolerated delay) with ``source: probe``.
-* :func:`run_calib_ab` — the ``bench.py calib`` gate: rerun the hetero
+  artifact — a saturation probe (``obs.loadgen.saturation_search``)
+  measures the sustainable rate and :func:`write_probe_artifact`
+  persists it (no in-tree caller: ROADMAP C8); loading the artifact
+  rewrites the admission enter/exit backlog thresholds from Little's
+  law (backlog = rate × tolerated delay) with ``source: probe``.
+* :func:`run_calib_ab` — the calibration A/B gate (tier-1:
+  ``tests/test_calibrate.py::TestCalibAB``): rerun the hetero
   A/B with throughputs learned ONLINE from span telemetry (declared
   coefficients hidden from the policies) and require the Gavel wins to
   reproduce within tolerance of the declared run, with
@@ -585,7 +587,7 @@ def calibration_overview(table=None, estimator=None) -> dict:
     }
 
 
-# -- the bench.py calib A/B gate ---------------------------------------------
+# -- the calibration A/B gate (run_calib_ab) ----------------------------------
 
 
 def _profile_of(job_index: int) -> str:
@@ -648,8 +650,8 @@ def run_calib_ab(
     samples_per_cell: int = 24,
     tolerance: float = 0.25,
 ) -> dict:
-    """The ``bench.py calib`` block: the PR-9 hetero A/B rerun with
-    throughputs learned ONLINE from span telemetry.
+    """The calibration A/B report: the hetero A/B (``run_hetero_ab``)
+    rerun with throughputs learned ONLINE from span telemetry.
 
     Declared coefficients are hidden from the policies (asks carry only
     a profile key); the estimator learns each (class × profile) cell

@@ -436,8 +436,9 @@ def run_soak(
             # by the schedule's down/up events instead
             heartbeat_ttl=3600.0,
             admission_overrides=admission_overrides,
-            # probe-derived thresholds (bench.py soak --saturation
-            # writes the artifact; this run admits under them)
+            # probe-derived thresholds (an artifact written by
+            # obs.calibrate.write_probe_artifact; this run admits
+            # under them)
             calibration_artifact=calibration_artifact,
         )
     )

@@ -29,7 +29,8 @@ capacity (``nomad.cp.*`` counters). Chaos site ``cp.round_perturb``
 perturbs the solver's initial prices — the solution may legitimately
 shift, but law 13 must still hold.
 
-``run_cp_ab`` is the ``bench.py cp`` acceptance harness: binpack vs
+``run_cp_ab`` is the acceptance harness (tier-1:
+``tests/test_cp.py::TestBenchCpSmoke``): binpack vs
 cp-pack on the seeded 1k-node mixed fleet, device kernel cross-checked
 byte-identical against the NumPy oracle, canonical byte-reproducible
 report.
@@ -560,7 +561,7 @@ class CpGangPlacementKernel(CpPlacementKernel):
         return results
 
 
-# -- seeded A/B harness (bench.py cp) ----------------------------------------
+# -- seeded A/B harness (run_cp_ab) ------------------------------------------
 
 
 def build_cp_asks(ct, n_jobs: int, count_per_job: int, seed: int = 7):
@@ -609,7 +610,7 @@ def run_cp_ab(
     count_per_job: int = 40,
     seed: int = 42,
 ) -> dict:
-    """The ``bench.py cp`` A/B block: greedy binpack vs cp-pack on one
+    """The cp-pack A/B report: greedy binpack vs cp-pack on one
     seeded contended mixed fleet. Placements are deterministic for a
     seed, so the whole report is byte-reproducible. The device kernel is
     cross-checked byte-identical against the NumPy host oracle on two
@@ -729,7 +730,7 @@ def cp_schema_of(report: dict) -> tuple[str, ...]:
     return tuple(sorted(paths))
 
 
-# -- seeded gang A/B harness (bench.py gang) ---------------------------------
+# -- seeded gang A/B harness (run_gang_ab) -----------------------------------
 
 
 def build_topo_fleet(
@@ -918,7 +919,8 @@ def run_gang_ab(
     groups: int = 3,
     seed: int = 42,
 ) -> dict:
-    """The ``bench.py gang`` A/B block: topology-blind greedy binpack vs
+    """The gang A/B report (tier-1: ``tests/test_gang.py``
+    ``TestBenchGangSmoke``): topology-blind greedy binpack vs
     cp-gang on one seeded rack/pod fleet of multi-group gang jobs. Both
     assignments are re-valued under the shared objective (score matrix +
     signed topology terms); the gate demands binpack fragment ≥ 1 gang

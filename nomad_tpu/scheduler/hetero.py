@@ -438,7 +438,7 @@ class HeteroPlacementKernel:
         return results
 
 
-# -- seeded mixed-fleet A/B harness (bench.py hetero) ------------------------
+# -- seeded mixed-fleet A/B harness (run_hetero_ab) --------------------------
 
 
 def build_mixed_fleet(
@@ -447,7 +447,7 @@ def build_mixed_fleet(
     )
 ):
     """Seeded synthetic mixed fleet as ClusterTensors (≥3 device
-    classes), mirroring bench.py's build_cluster but with a populated
+    classes), mirroring ``mock.build_cluster`` but with a populated
     device-class column."""
     from ..device.flatten import ClusterTensors, node_bucket
 
@@ -608,7 +608,8 @@ def run_hetero_ab(
     count_per_job: int = 25,
     seed: int = 42,
 ) -> dict:
-    """The `bench.py hetero` A/B block: binpack vs each hetero policy on
+    """The hetero A/B report (tier-1: ``tests/test_hetero.py``
+    ``TestMixedFleetAB``): binpack vs each hetero policy on
     one seeded mixed fleet. Placements are deterministic for a seed, so
     the whole report is byte-reproducible (chaos/soak-report style).
     Also cross-checks each policy's device pass against its host oracle

@@ -1,4 +1,4 @@
-"""Defrag batch assembly + the ``bench.py defrag`` A/B harness.
+"""Defrag batch assembly + the ``run_defrag_ab`` A/B harness.
 
 The host half of the migration plane's solver seam (the server half —
 two-phase move sequencing against the live store — is
@@ -7,8 +7,9 @@ two-phase move sequencing against the live store — is
 - ``build_defrag_batch``: dense (allocs × nodes) tensors for one defrag
   pass — consolidation scores, per-alloc sizes/current rows, and the
   conservative ``used`` the kernel prices against;
-- ``run_defrag_ab``: the bench gate. A seeded churned fleet is left
-  fragmented (load smeared thinly across most nodes); bounded-budget
+- ``run_defrag_ab``: the gate (tier-1:
+  ``tests/test_migrate.py::TestBenchGate``). A seeded churned fleet is
+  left fragmented (load smeared thinly across most nodes); bounded-budget
   defrag cycles then run the ``migrate_plan_kernel`` → apply → free
   loop and the gate asserts a measured fraction of packing efficiency
   comes back, byte-reproducibly, with the kernel pinned to its NumPy
@@ -134,7 +135,7 @@ def run_defrag_ab(
     max_cycles: int = 12,
     seed: int = 42,
 ) -> dict:
-    """The ``bench.py defrag`` gate: fragment → cycle the kernel with a
+    """The defrag gate: fragment → cycle the kernel with a
     bounded per-cycle budget → measure recovered packing efficiency.
     Each cycle is the controller's two-phase shape in miniature: the
     kernel commits every replacement on top of live ``used`` (capacity

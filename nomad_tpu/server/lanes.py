@@ -3,10 +3,10 @@ multi-worker commit path.
 
 The optimistic posture (reference Nomad, and this repo through r5) lets
 any worker place on any node and relies on the serialized plan applier
-to bounce whatever went stale. That is correct but not *stable*: two
-pipelined batching workers racing commits under CPU starvation swung
-the conflict rate 0.0–0.96 run to run (builder's CPU-rig reading).
-This module replaces hope with a contract:
+to bounce whatever went stale. That is correct but not *stable*: with
+two pipelined batching workers racing commits, how many plans bounce
+depends on the host's scheduling and varies run to run. This module
+replaces hope with a contract:
 
 ``LaneMap``
     every job and every node hash onto exactly one of ``num_lanes``

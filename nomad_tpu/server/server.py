@@ -112,6 +112,13 @@ class ServerConfig:
         self.lane_mode = (
             self.num_batch_workers > 1 if lane_mode is None else bool(lane_mode)
         )
+        if self.num_batch_workers > 1 and not self.lane_mode:
+            # without lanes two batching workers share one eval queue and
+            # nothing keeps their passes off each other's jobs and nodes
+            raise ValueError(
+                "lane_mode=False needs num_batch_workers == 1 "
+                f"(got {self.num_batch_workers})"
+            )
         # threshold/dwell overrides for the admission controller
         # (server/admission.py); None keeps the production defaults,
         # under which NORMAL behavior is identical to pre-admission.
@@ -216,6 +223,11 @@ class Server:
         )
         self._raft_lock = threading.Lock()
         self._leader = False
+        # establish and revoke run on raft's callback threads and on the
+        # caller of shutdown(); one transition at a time (the reference
+        # runs both from monitorLeadership's one goroutine, leader.go),
+        # or a revoke stops workers that establish has not yet started
+        self._leadership_lock = threading.Lock()
         from ..broker.event_broker import EventBroker as StreamBroker
         from .core_gc import CoreScheduler
         from .deployment_watcher import DeploymentWatcher
@@ -448,45 +460,49 @@ class Server:
     # -- leadership --------------------------------------------------------
     def establish_leadership(self) -> None:
         """leader.go:230-347."""
-        self._leader = True
-        self.plan_queue.set_enabled(True)
-        self.plan_apply_loop.start()
-        self.eval_broker.set_enabled(True)
-        self.blocked_evals.set_enabled(True)
-        self.heartbeater.initialize_from_store()
-        self.heartbeater.start()
-        self.deployment_watcher.start()
-        self.drainer.start()
-        self.defrag.start()
-        self.periodic.restore()
-        self.periodic.start()
-        self.core_gc.start()
-        self.volume_watcher.start()
-        self._restore_evals()
-        for i in range(self.config.num_workers):
-            w = Worker(self, worker_id=i)
-            self.workers.append(w)
-            w.start()
+        with self._leadership_lock:
+            self._leader = True
+            self.plan_queue.set_enabled(True)
+            self.plan_apply_loop.start()
+            self.eval_broker.set_enabled(True)
+            self.blocked_evals.set_enabled(True)
+            self.heartbeater.initialize_from_store()
+            self.heartbeater.start()
+            self.deployment_watcher.start()
+            self.drainer.start()
+            self.defrag.start()
+            self.periodic.restore()
+            self.periodic.start()
+            self.core_gc.start()
+            self.volume_watcher.start()
+            self._restore_evals()
+            for i in range(self.config.num_workers):
+                w = Worker(self, worker_id=i)
+                self.workers.append(w)
+                w.start()
 
     def revoke_leadership(self) -> None:
-        for w in self.workers:
-            w.stop()
-        self.workers.clear()
-        self.heartbeater.stop()
-        self.deployment_watcher.stop()
-        self.drainer.stop()
-        self.defrag.stop()
-        self.periodic.stop()
-        self.core_gc.stop()
-        self.volume_watcher.stop()
-        self.plan_apply_loop.stop()
-        self.plan_queue.set_enabled(False)
-        self.eval_broker.set_enabled(False)
-        self.blocked_evals.set_enabled(False)
-        self._leader = False
+        with self._leadership_lock:
+            for w in self.workers:
+                w.stop()
+            self.workers.clear()
+            self.heartbeater.stop()
+            self.deployment_watcher.stop()
+            self.drainer.stop()
+            self.defrag.stop()
+            self.periodic.stop()
+            self.core_gc.stop()
+            self.volume_watcher.stop()
+            self.plan_apply_loop.stop()
+            self.plan_queue.set_enabled(False)
+            self.eval_broker.set_enabled(False)
+            self.blocked_evals.set_enabled(False)
+            self._leader = False
 
     def shutdown(self) -> None:
-        if self._leader:
+        with self._leadership_lock:
+            leader = self._leader
+        if leader:
             self.revoke_leadership()
         # release this server's hold on the process-global estimator
         # (refcounted; the listener detaches with the last server)

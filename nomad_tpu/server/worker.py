@@ -51,32 +51,25 @@ SCHEDULER_TYPES = ["service", "batch", "system", "sysbatch", "_core"]
 # batch dimension of the placement kernel replaces the reference's
 # worker-per-core concurrency (nomad/config.go:468). Each eval still
 # submits its own plan; the serialized applier resolves conflicts exactly
-# as it does for the reference's parallel workers. Sized so a burst of
-# registrations drains in a handful of passes — each pass costs one
-# upload and one fetch regardless of depth, and lane decorrelation + host
-# repair keep wide batches conflict-free.
+# as it does for the reference's parallel workers. Each pass costs one
+# upload and one fetch regardless of depth; lane decorrelation
+# (device/score.py `_decorrelate_lanes`) and the host repair keep the
+# lanes of a pass off each other's nodes.
 #
-# Workers 0..num_batch_workers-1 run batched passes, each on a disjoint
-# JOB-HASH PARTITION of the eval stream (broker n_partitions), a disjoint
-# hashed NODE UNIVERSE, and its own lane-stripe salt — r3 measured a
-# 0.46 conflict rate with two batching workers sharing one stream;
-# partitioning removes the shared hot set (measured 6.8× single-worker
-# eval throughput with conflict 0 at the 8-deep repro shape). Remaining
-# workers drain solo evals through the same shared optimistic overlay.
+# Workers 0..num_batch_workers-1 run batched passes. With more than one
+# of them the server runs in lane mode (server/lanes.py): each owns a
+# disjoint JOB-HASH PARTITION of the eval stream (broker n_partitions),
+# so two workers never score the same job, and reserves a peer lane's
+# nodes (`LaneClaims`) before a placement on them rides a merged commit;
+# the stripe salt comes from the job's lane, not the worker.
+# Remaining workers drain solo evals through the same shared optimistic
+# overlay. Every benchmark cell and chip_smoke.py pin one worker of
+# either kind: a second one fails today (ROADMAP N2, A6; PERF.md §7), so
+# no record prices more than one.
 #
-# Concurrency caveat, measured honestly: on a SINGLE-core host at the
-# 10k-node config-3 shape, any second worker (solo or batching) races
-# the pipelined commits under CPU starvation and conflict rates swing
-# run-to-run (0.0–0.96); one pipelined batching worker is bit-stable
-# there (conflict 0.0 across every instrumented run). The bench pins
-# num_workers=1 for reproducibility; multi-worker batching is for
-# multi-core servers.
-#
-# Depth 16 beats 64 on BOTH axes with the single pipelined worker at
-# the config-3 shape (true-CPU A/B: 5.5 vs 4.7 evals/s and invoke p99
-# 2.7 s vs 9.0 s, conflict 0.0 in every run): the pipeline hides the
-# extra pass dispatches while smaller passes commit sooner and cap the
-# p99 at one-quarter the device time.
+# The depth is a constant chosen before the chip and not re-measured in
+# a cell: no window holds a pass of more than a few evals (PERF.md §5,
+# `evals_per_pass.lat`; ROADMAP A1 lists it among the sizes to re-measure).
 EVAL_BATCH_SIZE = 16
 
 
@@ -636,9 +629,8 @@ class Worker:
                 # The tie-break salt must be a function of the WORK, not
                 # the worker: lane mode derives it from the first eval's
                 # job lane so an N-worker run reproduces the 1-worker
-                # reference byte for byte, and the legacy cross-worker
-                # node-universe carving (decorrelate_workers) is retired
-                # — structural claims replace it.
+                # reference byte for byte; across workers, lane claims
+                # keep passes apart.
                 with ps.phase(
                     "invoke_scheduler",
                     timer="nomad.worker.invoke_scheduler",
@@ -655,13 +647,6 @@ class Worker:
                             )
                             if lane_mode
                             else self.id
-                        ),
-                        decorrelate_workers=(
-                            1
-                            if lane_mode
-                            else getattr(
-                                self.server.config, "num_batch_workers", 1
-                            )
                         ),
                         overflow=32,
                         used_override=used_override,

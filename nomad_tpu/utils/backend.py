@@ -496,9 +496,8 @@ class MeshConfig:
         return self.mp if self.mesh is not None else 1
 
     def describe(self) -> dict:
-        """The self-describing ``mesh`` block bench.py embeds in every
-        JSON record (per-shard node counts are filled in by the caller
-        that knows the padded bucket)."""
+        """The self-describing ``mesh`` block of ``chip_smoke.py``'s
+        report (``mesh_report`` adds which devices hold the shards)."""
         return {
             "active": self.active,
             "shape": [self.dp, self.mp],

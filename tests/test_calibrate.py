@@ -7,8 +7,8 @@ probe-artifact ingestion, Little's-law threshold derivation, the
 admission/breaker consumer seams), the scheduler throughput-source seam
 (declared mode byte-identical with zero added retraces, learned mode
 substituting estimator values), the HTTP/CLI/SLO surfaces, invariant
-law 14 (``calibration_sanity``) tamper detection, and the ``bench.py
-calib`` A/B harness at smoke scale.
+law 14 (``calibration_sanity``) tamper detection, and the
+``run_calib_ab`` A/B harness at smoke scale.
 """
 
 import json
@@ -743,7 +743,7 @@ class TestWallclockObsScope:
         assert findings == [], [f.render() for f in findings]
 
 
-# -- the bench.py calib gate -------------------------------------------------
+# -- the run_calib_ab gate ---------------------------------------------------
 
 
 class TestCalibAB:

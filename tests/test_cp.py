@@ -348,7 +348,7 @@ class TestRegistry:
         assert len({a.node_id for a in allocs}) >= 1
 
 
-# -- seeded A/B smoke (the bench.py cp gate) ---------------------------------
+# -- seeded A/B smoke (the run_cp_ab gate) -----------------------------------
 
 
 class TestBenchCpSmoke:

@@ -14,10 +14,10 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from bench import build_asks, build_cluster
 from nomad_tpu import mock
 from nomad_tpu.analysis import retrace
 from nomad_tpu.device.score import PlacementKernel, repair_batch_conflicts
+from nomad_tpu.mock import build_asks, build_cluster
 from nomad_tpu.obs import explain as explain_mod
 from nomad_tpu.obs.explain import (
     EXPLAIN_SCHEMA_VERSION,

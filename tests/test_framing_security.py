@@ -1,5 +1,5 @@
 """RPC framing hardening: restricted deserialization + HMAC transport
-auth + snapshot atomicity (ADVICE round-1 findings).
+auth + snapshot atomicity.
 
 The reference's trust boundary here is msgpack + TLS (nomad/rpc.go);
 ours is an allowlisted unpickler (no arbitrary-callable resolution ⇒ no

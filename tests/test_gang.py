@@ -455,7 +455,7 @@ class TestChaosCommitDrop:
         assert _counter("nomad.gang.releases") > before
 
 
-# -- seeded A/B smoke (the bench.py gang gate) --------------------------------
+# -- seeded A/B smoke (the run_gang_ab gate) ----------------------------------
 
 
 class TestBenchGangSmoke:

@@ -20,7 +20,7 @@ Coverage map (ISSUE 9):
   kernel onto the job's fast classes.
 
 All tests are CPU-fast tier-1 (the mixed-fleet A/B runs a small fleet;
-the 1k-node version lives in `bench.py hetero`).
+`run_hetero_ab`'s defaults are the 1k-node version).
 """
 
 import numpy as np

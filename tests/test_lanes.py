@@ -291,6 +291,20 @@ class TestLaneClaims:
         assert snap["counters"]["reserves"] == 1
 
 
+class TestLaneModeConfig:
+    def test_two_batchers_without_lanes_are_refused(self):
+        """Lane mode is what keeps two batching workers' passes apart
+        (partitioned broker, claims); switching it off is only allowed
+        where there is nothing to keep apart."""
+        with pytest.raises(ValueError, match="lane_mode=False"):
+            ServerConfig(num_workers=2, num_batch_workers=2, lane_mode=False)
+        assert ServerConfig(num_workers=2, num_batch_workers=2).lane_mode
+        assert not ServerConfig(
+            num_workers=2, num_batch_workers=1, lane_mode=False
+        ).lane_mode
+        assert not ServerConfig(num_workers=1).lane_mode
+
+
 # -- byte-identity: 2 workers ≡ 1 worker -------------------------------------
 
 

@@ -422,11 +422,11 @@ class TestProductionPathSharded:
         """The full PlacementKernel.place path (batch build, shard_put
         seam, hierarchical top-k, overflow repair) through the registry
         must place bit-identically to the single-device reference."""
-        import bench
+        from nomad_tpu import mock
         from nomad_tpu.scheduler.algorithms import make_kernel
 
-        ct = bench.build_cluster(1000, seed=seed)
-        asks = bench.build_asks(ct, 16, 64, seed=seed + 1)
+        ct = mock.build_cluster(1000, seed=seed)
+        asks = mock.build_asks(ct, 16, 64, seed=seed + 1)
         ref = make_kernel("binpack", mesh=_degenerate_cfg()).place(ct, asks)
         got = make_kernel("binpack", mesh=_mesh_cfg(2, 4)).place(ct, asks)
         assert len(ref) == len(got)
@@ -438,11 +438,11 @@ class TestProductionPathSharded:
 
     @pytest.mark.parametrize("seed", [42, 7])
     def test_spread_kernel_bit_identical_under_mesh(self, seed):
-        import bench
+        from nomad_tpu import mock
         from nomad_tpu.scheduler.algorithms import make_kernel
 
-        ct = bench.build_cluster(500, seed=seed)
-        asks = bench.build_asks(ct, 8, 32, seed=seed + 1)
+        ct = mock.build_cluster(500, seed=seed)
+        asks = mock.build_asks(ct, 8, 32, seed=seed + 1)
         ref = make_kernel("spread", mesh=_degenerate_cfg()).place(ct, asks)
         got = make_kernel("spread", mesh=_mesh_cfg(2, 4)).place(ct, asks)
         for r, g in zip(ref, got):
@@ -497,13 +497,13 @@ class TestExplainUnderMesh:
         top pick the kernel placed, (b) add ZERO retraces — the
         provenance path is host-side numpy over the gathered candidate
         columns only."""
-        import bench
+        from nomad_tpu import mock
         from nomad_tpu.analysis import retrace
         from nomad_tpu.scheduler.algorithms import make_kernel
 
         mesh_env("2,4")
-        ct = bench.build_cluster(500, seed=3)
-        asks = bench.build_asks(ct, 4, 16, seed=4)
+        ct = mock.build_cluster(500, seed=3)
+        asks = mock.build_asks(ct, 4, 16, seed=4)
         kernel = make_kernel("binpack")
         assert kernel.mesh_cfg().active
         kernel.place(ct, asks)  # warm the shape bucket

@@ -1,7 +1,7 @@
 """Migration plane device/host contract: ``migrate_plan_kernel`` is
 byte-identical to its NumPy oracle across seeds and meshes, budget is a
 dynamic operand (sweeping it never retraces), the oracle honours its
-budget/capacity model, and the ``bench.py defrag`` gate is a
+budget/capacity model, and the ``run_defrag_ab`` gate is a
 byte-reproducible tier-1 smoke."""
 
 import json
@@ -226,7 +226,7 @@ class TestPackingEfficiency:
         ) == 1.0
 
 
-# -- bench gate smoke (tier-1) -----------------------------------------------
+# -- run_defrag_ab gate smoke (tier-1) ---------------------------------------
 
 
 def _flatten(d, prefix=""):

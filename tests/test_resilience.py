@@ -252,7 +252,7 @@ class TestDeadlineExecutor:
 
 
 def _tiny_workload(n_nodes=200, n_jobs=4, count=25):
-    from bench import build_asks, build_cluster
+    from nomad_tpu.mock import build_asks, build_cluster
 
     ct = build_cluster(n_nodes)
     return ct, build_asks(ct, n_jobs, count)

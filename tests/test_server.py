@@ -3,6 +3,8 @@ nomad.TestServer pattern (nomad/testing.go:44): a real Server with real
 workers, broker, plan queue and applier, driven through its API."""
 
 import copy
+import threading
+import time
 
 import pytest
 
@@ -181,3 +183,36 @@ class TestServerEndToEnd:
         server.update_node_status(n2.id, "ready")
         assert server.wait_for_evals(timeout=15)
         assert {a.node_id for a in live_allocs(server, job)} == {n1.id, n2.id}
+
+
+class TestLeadershipTransitions:
+    def test_shutdown_waits_for_a_running_establish(self):
+        """Raft runs ``establish_leadership`` on a callback thread; a
+        ``shutdown`` that arrives mid-way must wait for it and then stop
+        everything it started (it used to stop a worker whose thread was
+        not started yet: ``tests/test_federation.py``'s teardown under
+        load, ROADMAP C9)."""
+        s = Server(ServerConfig(num_workers=1))
+        entered, release = threading.Event(), threading.Event()
+        restore = s._restore_evals
+
+        def held_restore():
+            entered.set()
+            release.wait(10)
+            restore()
+
+        s._restore_evals = held_restore
+        establish = threading.Thread(target=s.establish_leadership)
+        establish.start()
+        assert entered.wait(10)
+        shutdown = threading.Thread(target=s.shutdown)
+        shutdown.start()
+        time.sleep(0.2)
+        try:
+            assert shutdown.is_alive()  # held behind the transition
+        finally:
+            release.set()
+            establish.join(10)
+            shutdown.join(20)
+        assert not establish.is_alive() and not shutdown.is_alive()
+        assert not s._leader and s.workers == []
