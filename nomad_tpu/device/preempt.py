@@ -19,7 +19,7 @@ Victims are padded to ``[N, V]``; one pass does
     must    = cheapest holders of the device instances the ask lacks
     taken   = must, then V steps: the nearest of the lowest priority
               group left, on every node that does not fit yet
-    victims = filterSuperset(taken) ∪ must             # sort + prefix scan
+    victims = filterSuperset(taken) ∪ must             # V x V compare + sum
     net[n]  = sum of the victims' priorities
     score   = fit(used − freed + ask) · logistic(net)  # preemption penalty
 
@@ -30,6 +30,16 @@ priority group holds allocations of different sizes (it ranked nodes up
 to 2 % under the reference's best on a fleet filled at one priority).
 The host pass (scheduler/preempt_host.py) stays the authority on the
 nodes a placement takes.
+
+Where the reference sorts (the holders of a device by priority and
+distance, the chosen by distance for the superset filter, the victims
+ahead of the rest for the host), nothing here is sorted or permuted:
+``_precedes`` compares every pair of a node's slots ([N, V, V]), a sum
+over the slots that come before one is the prefix scan at that slot, and
+their count is its place in the order. On the chip a permutation gather
+walks its result element by element and cost more than everything else
+in the kernel together; the dense planes are array work like the rest
+(measured in PERF.md §6, PR 32; the same finding as PR 30's there).
 
 The victim tensors are kept on the ``ClusterTensors`` generation
 (``VictimTensors``): a ranking walks the nodes whose allocations changed
@@ -105,6 +115,20 @@ def _superset(available, ask):
     return jnp.all(available + 1e-6 >= ask, axis=-1)
 
 
+def _precedes(*keys):
+    """bool[N, V, V]: ``[n, i, j]`` says that slot ``i`` of node ``n`` comes
+    before its slot ``j`` in a stable sort by ``keys`` (each ``[N, V]``, the
+    first decides first): equal keys leave the lower slot first, and -0.0
+    equals 0.0 as it does to the sort. The permutation as one compare over
+    the victim axis; nothing is moved."""
+    v = keys[0].shape[1]
+    before = (jnp.arange(v)[:, None] < jnp.arange(v)[None, :])[None]
+    for key in reversed(keys):
+        k_i, k_j = key[:, :, None], key[:, None, :]
+        before = (k_i < k_j) | ((k_i == k_j) & before)
+    return before
+
+
 def _victim_sets(
     capacity, used, ask, eligible, victim_res, victim_prio, victim_mask,
     victim_dev, dev_need,
@@ -125,7 +149,12 @@ def _victim_sets(
     first, the shortest prefix that covers; device victims stay. Left to
     the host pass (scheduler/preempt_host.py), which is exact on the
     nodes a placement takes: the maxParallel penalty, reserved ports, the
-    match of device instances by vendor, type and name."""
+    match of device instances by vendor, type and name.
+
+    Nothing is sorted and nothing gathered (``_precedes``): a prefix scan
+    in sorted order is a sum over the slots that come before (``[N, V, V]``
+    array work, ``[N, V, V, D]`` for the freed resources), and a slot's
+    place in an order is the count of those slots."""
     big = jnp.float32(1e9)
     v = victim_mask.shape[1]
     slots = jnp.arange(v)[None, :]
@@ -138,16 +167,13 @@ def _victim_sets(
         # holders of the lacking instances, cheapest first
         key = victim_prio.astype(jnp.float32) * 1e4 + jnp.minimum(dist_ask, 9e3)
         holder = victim_mask & (victim_dev > 0)
-        by_prio = jnp.argsort(jnp.where(holder, key, big), axis=1)
-        held = jnp.take_along_axis(
-            jnp.where(holder, victim_dev, 0), by_prio, axis=1
-        )
-        before = jnp.cumsum(held, axis=1) - held  # freed by cheaper ones
-        must_sorted = (held > 0) & (before < dev_need[:, None])
-        rank = jnp.argsort(by_prio, axis=1)  # inverse permutation
-        must = jnp.take_along_axis(must_sorted, rank, axis=1)
+        held = jnp.where(holder, victim_dev, 0)
+        cheaper = _precedes(jnp.where(holder, key, big))
+        # freed by cheaper ones
+        before = jnp.sum(jnp.where(cheaper, held[:, :, None], 0), axis=1)
+        must = holder & (before < dev_need[:, None])
         eligible = eligible & (
-            jnp.sum(jnp.where(must_sorted, held, 0), axis=1) >= dev_need
+            jnp.sum(jnp.where(must, held, 0), axis=1) >= dev_need
         )
 
     with jax.named_scope("freed_prefix"):
@@ -188,24 +214,19 @@ def _victim_sets(
         )
         # superset filter: farthest from the whole ask first, ties in the
         # order they were taken; the shortest prefix that covers
-        order = jnp.lexsort(
-            (step_of, jnp.where(taken, -dist_ask, big)), axis=1
-        )
-        sorted_taken = jnp.take_along_axis(taken, order, axis=1)
-        sorted_res = jnp.take_along_axis(res, order[:, :, None], axis=1)
-        freed_to = jnp.cumsum(
-            jnp.where(sorted_taken[:, :, None], sorted_res, 0.0), axis=1
+        ahead = _precedes(
+            jnp.where(taken, -dist_ask, big), step_of
+        ) & taken[:, :, None]
+        place = jnp.sum(ahead, axis=1)  # among the taken
+        freed_to = res + jnp.sum(
+            jnp.where(ahead[:, :, :, None], res[:, :, None, :], 0.0), axis=1
         )
         covers = _superset(
             free[:, None, :] + freed_to, ask[None, None, :]
-        ) & sorted_taken
-        n_kept = jnp.where(
-            jnp.any(covers, axis=1), jnp.argmax(covers, axis=1) + 1, 0
-        )
-        kept_sorted = sorted_taken & (slots < n_kept[:, None])
-        kept = jnp.take_along_axis(
-            kept_sorted, jnp.argsort(order, axis=1), axis=1
-        )
+        ) & taken
+        n_kept = jnp.min(jnp.where(covers, place + 1, v + 1), axis=1)
+        n_kept = jnp.where(jnp.any(covers, axis=1), n_kept, 0)
+        kept = taken & (place < n_kept[:, None])
         victims = kept | must
         k = jnp.sum(victims, axis=1).astype(jnp.int32)
         # a node with room needs no victim and is not an option here
@@ -215,7 +236,15 @@ def _victim_sets(
         net = jnp.sum(jnp.where(victims, prio, 0), axis=1).astype(jnp.float32)
         freed = jnp.sum(jnp.where(victims[:, :, None], res, 0.0), axis=1)
         # the victims first, in slot order: what the host maps to ids
-        order = jnp.argsort(~victims, axis=1, stable=True)
+        stands_at = jnp.sum(_precedes(~victims), axis=1)
+        order = jnp.sum(
+            jnp.where(
+                stands_at[:, :, None] == slots[:, None, :],
+                slots[:, :, None],
+                0,
+            ),
+            axis=1,
+        )
     return any_fit, k, net, order.astype(jnp.int32), freed
 
 
