@@ -64,15 +64,16 @@ class BrokerStay:
 
     __slots__ = (
         "ready_at", "dequeued_at", "gate_s", "deferred_s", "register",
-        "_parked_at", "_parked_as",
+        "trace_tags", "_parked_at", "_parked_as",
     )
 
-    def __init__(self, ready_at: float, register=None):
+    def __init__(self, ready_at: float, register=None, trace_tags=None):
         self.ready_at = ready_at
         self.dequeued_at = ready_at
         self.gate_s = 0.0
         self.deferred_s = 0.0
         self.register = register  # (entry, enqueue) stamps, or None
+        self.trace_tags = trace_tags  # the maker's, for the trace's root
         self._parked_at = 0.0
         self._parked_as = ""
 
@@ -184,13 +185,19 @@ class EvalBroker:
 
     # -- enqueue -----------------------------------------------------------
     def enqueue(
-        self, ev: Evaluation, entered_at: Optional[float] = None
+        self,
+        ev: Evaluation,
+        entered_at: Optional[float] = None,
+        trace_tags: Optional[dict] = None,
     ) -> None:
         """``entered_at``: the ``perf_counter`` stamp at which the server
         entry point that made ``ev`` was entered; the broker keeps the
-        interval up to now beside the eval's stay, for its trace."""
+        interval up to now beside the eval's stay, for its trace, and
+        ``trace_tags`` for the trace's root."""
         with self._lock:
-            self._enqueue_locked(ev, entered_at=entered_at)
+            self._enqueue_locked(
+                ev, entered_at=entered_at, trace_tags=trace_tags
+            )
             self._lock.notify_all()
 
     def enqueue_all(self, evals: list[Evaluation]) -> None:
@@ -204,6 +211,7 @@ class EvalBroker:
         ev: Evaluation,
         ignore_job_gate: bool = False,
         entered_at: Optional[float] = None,
+        trace_tags: Optional[dict] = None,
     ) -> None:
         if not self.enabled:
             return
@@ -222,6 +230,7 @@ class EvalBroker:
             stay = self._enqueued_at[ev.id] = BrokerStay(
                 now_mono,
                 None if entered_at is None else (entered_at, now_mono),
+                trace_tags,
             )
         else:
             stay.unpark(now_mono)

@@ -393,6 +393,18 @@ def preemption_evals(store, result: PlanResult) -> list:
     return evals
 
 
+def _count_committed(results) -> None:
+    """What the committed results stop and which rollouts they open."""
+    stops = sum(
+        len(allocs) for res in results for allocs in res.node_update.values()
+    )
+    if stops:
+        metrics.incr("nomad.plan.stops_committed", stops)
+    created = sum(1 for res in results if res.deployment is not None)
+    if created:
+        metrics.incr("nomad.deployment.created", created)
+
+
 class PlanApplier:
     """Serialized apply loop state: evaluate against live store, commit
     through the raft seam (applyPlan → raftApply(ApplyPlanResultsRequest),
@@ -528,6 +540,7 @@ class PlanApplier:
                 # commit-train accounting: one FSM apply, one plan landed
                 metrics.incr("nomad.plan.commits")
                 metrics.incr("nomad.plan.committed_plans")
+                _count_committed((result,))
                 result.alloc_index = index
                 if evals and self.on_evals_created is not None:
                     # re-read post-commit: a consensus FSM applies COPIES,
@@ -623,6 +636,7 @@ class PlanApplier:
                 metrics.incr(
                     "nomad.plan.committed_plans", len(commit_members)
                 )
+                _count_committed(committed)
                 metrics.incr("nomad.plan.merged_commits")
                 metrics.incr(
                     "nomad.plan.merged_members", len(commit_members)

@@ -926,10 +926,18 @@ def flatten_group_ask(
         ct, nodes_sorted, job, tg, snap
     )
 
+    # the job's allocations per node as the plan proposes them: those it
+    # stops are gone (rank.go JobAntiAffinityIterator reads
+    # ctx.ProposedAllocs, which leaves out plan.NodeUpdate)
+    stopped_ids = (
+        {a.id for stops in plan.node_update.values() for a in stops}
+        if plan is not None and plan.node_update
+        else ()
+    )
     job_counts = np.zeros(ct.padded_n, dtype=np.int32)
     if snap is not None:
         for a in snap.allocs_by_job(job.namespace, job.id):
-            if a.terminal_status():
+            if a.terminal_status() or a.id in stopped_ids:
                 continue
             row = ct.node_row.get(a.node_id)
             if row is not None:

@@ -24,6 +24,9 @@ from ..utils.metrics import global_metrics
 
 DEFAULT_CAPACITY = 256
 DEFAULT_ERROR_CAPACITY = 100
+# a window's worth of the spans that belong to no eval (four watcher
+# ticks a second, a client update per rollout round)
+DEFAULT_BACKGROUND_CAPACITY = 4096
 
 
 class FlightRecorder:
@@ -60,6 +63,11 @@ class FlightRecorder:
         self._explanations: "OrderedDict[str, dict]" = OrderedDict()
         self.explanations_total = 0
         self.explanations_evicted = 0
+        # spans of work that belongs to no eval (``Tracer.background``:
+        # the deployment watcher's tick, the clients' alloc sync), oldest
+        # first. Kept apart from the traces: a trace is one eval, with a
+        # pass id, and feeds the SLO latency series
+        self._background: deque = deque(maxlen=DEFAULT_BACKGROUND_CAPACITY)
 
     # -- writes ------------------------------------------------------------
     def add_listener(self, fn: Callable[[dict], None]) -> None:
@@ -110,6 +118,10 @@ class FlightRecorder:
             except Exception:
                 global_metrics.incr("nomad.obs.listener_errors")
 
+    def record_background(self, span: dict) -> None:
+        with self._lock:
+            self._background.append(span)
+
     def record_explanation(self, eval_id: str, payload: dict) -> None:
         """Ring one eval's placement explanation (dict of task group →
         explanation dict, plus eval metadata). Re-records move to the
@@ -158,8 +170,14 @@ class FlightRecorder:
             self._traces.clear()
             self._errors.clear()
             self._explanations.clear()
+            self._background.clear()
 
     # -- reads -------------------------------------------------------------
+    def background(self) -> list[dict]:
+        """The background spans still held, oldest first."""
+        with self._lock:
+            return list(self._background)
+
     def get(self, eval_id: str) -> Optional[dict]:
         with self._lock:
             return self._traces.get(eval_id)

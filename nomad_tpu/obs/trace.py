@@ -22,6 +22,9 @@ cannot carry a trace end to end. The model here:
   batch (a pass's top-level phases are copied into each member's trace,
   tagged ``shared``). The caller hands over where the interval started;
   nothing is counted back from the moment of the call.
+- ``background(name)`` times work that belongs to no eval (the
+  deployment watcher's tick, the clients' alloc sync) as a lone span in
+  the recorder's background ring, beside the traces and not among them.
 - ``phase(name)`` opens a top-level phase of a scheduling pass: a span
   that carries the pass tags (``pass_id``, ``path``, ``evals``) the
   worker put on the trace's root, so every phase of every member of one
@@ -345,6 +348,29 @@ class Tracer:
                 **(tags or {}),
             }
         return self.span(name, tags=tags, timer=timer)
+
+    @contextmanager
+    def background(self, name: str, *, tags: Optional[dict] = None):
+        """Time a block that belongs to no eval (a tick of the deployment
+        watcher, the clients' alloc sync) as a span of its own, handed to
+        the recorder's background ring when the block ends. Yields the
+        Span for its tags, or None with tracing off. It is no trace: it
+        opens nothing on this thread's stack and spans opened inside it
+        parent as they would have without it."""
+        if not self._enabled:
+            yield None
+            return
+        sp = self._span(f"background:{name}", name, None, tags,
+                        time.perf_counter())
+        try:
+            yield sp
+        except BaseException:
+            sp.status = "error"
+            raise
+        finally:
+            sp.finish()
+            if self.recorder is not None:
+                self.recorder.record_background(sp.to_dict())
 
     def _open(self, name, parent, tags, t0) -> Optional[Span]:
         if not self._enabled:

@@ -13,7 +13,10 @@ allocations from the chosen rows (SURVEY.md §7 steps 3+5).
 from __future__ import annotations
 
 import time
+from dataclasses import replace
 from typing import Optional
+
+import numpy as np
 
 from ..device import flatten_group_ask
 from ..device.cache import DeviceStateCache
@@ -175,6 +178,9 @@ class GenericScheduler:
         self._finalize()
 
     _finished = False
+    # [padded_n, D] of what this attempt's plan stops, per node row, or
+    # None: set where the plan's stops are taken off the tensors
+    _plan_freed = None
 
     # -- one attempt ------------------------------------------------------
     def _process_once(self) -> tuple[bool, bool]:
@@ -193,8 +199,20 @@ class GenericScheduler:
         if not asks:
             return self._submit_attempt()
         used_override = None
+        overlay_ct = ct
         if self.overlay is not None:
             used_override = self.overlay.begin_pass(ct)
+            freed = self._plan_freed
+            if freed is not None:
+                # the plan's stops are freed before its placements are
+                # scored (generic_sched.go computePlacements: the stopped
+                # allocation leaves the proposed set first) in what the
+                # kernel is shown too: the epoch's usage does not have
+                # them. A base this pass freezes must not have them
+                # either: ``release`` takes them off when they commit
+                if used_override is not None:
+                    used_override -= freed
+                overlay_ct = replace(ct, used=ct.used + freed)
         try:
             with tracer.phase(
                 "invoke_scheduler",
@@ -238,7 +256,8 @@ class GenericScheduler:
                 for a, res in zip(asks, results):
                     rows = res.node_rows[res.node_rows >= 0]
                     if rows.size:
-                        self.overlay.add_delta(ct, rows, a.ask)
+                        self.overlay.add_delta(overlay_ct, rows, a.ask)
+                self.overlay.end_scoring()
             with tracer.phase("build_plan"):
                 self._finish_placements(ct, tg_order, results)
                 self._adjust_queued()
@@ -347,15 +366,32 @@ class GenericScheduler:
         deployment = self.snapshot.latest_deployment_by_job(
             ev.namespace, ev.job_id
         )
-        results = reconcile(
-            self.job,
-            ev.job_id,
-            existing,
-            tainted,
-            batch=self.batch,
-            now_ns=int(self.clock() * 1e9),
-            deployment=deployment,
-        )
+        with tracer.span("reconcile") as sp:
+            results = reconcile(
+                self.job,
+                ev.job_id,
+                existing,
+                tainted,
+                batch=self.batch,
+                now_ns=int(self.clock() * 1e9),
+                deployment=deployment,
+            )
+            if sp is not None:
+                sp.tags.update(
+                    place=len(results.place),
+                    destructive=len(results.destructive_update),
+                    inplace=len(results.inplace_update),
+                    stop=len(results.stop),
+                    ignore=len(results.ignore),
+                    max_parallel=max(
+                        (
+                            tg.update.max_parallel
+                            for tg in (self.job.task_groups if self.job else ())
+                            if tg.update is not None
+                        ),
+                        default=0,
+                    ),
+                )
 
         # deployment lifecycle (reconcile.go + deploymentwatcher semantics):
         # create one for a gated rollout; cancel one superseded by a newer
@@ -407,6 +443,12 @@ class GenericScheduler:
                 old, "alloc updated in-place failed; destructive update"
             )
             destructive_places.append(pr)
+        if destructive_places:
+            from ..utils.metrics import global_metrics
+
+            global_metrics.incr(
+                "nomad.worker.destructive_updates", len(destructive_places)
+            )
 
         placements = results.place + destructive_places
 
@@ -480,12 +522,23 @@ class GenericScheduler:
             ct = self.cache.tensors(snap)
         nodes_sorted = ct.nodes
         # overlay this plan's own stops (evicted allocs free capacity)
-        for node_id, stops in self.plan.node_update.items():
-            row = ct.node_row.get(node_id)
-            if row is None:
-                continue
-            for a in stops:
-                ct.used[row] -= a.comparable_resources().to_vector()
+        self._plan_freed = None
+        if self.plan.node_update:
+            with tracer.span("plan_stops") as sp:
+                n_stops = 0
+                freed = np.zeros_like(ct.used)
+                for node_id, stops in self.plan.node_update.items():
+                    row = ct.node_row.get(node_id)
+                    if row is None:
+                        continue
+                    for a in stops:
+                        freed[row] += a.comparable_resources().to_vector()
+                    n_stops += len(stops)
+                ct.used -= freed
+                # what the shared overlay's view of usage still lacks
+                self._plan_freed = freed
+                if sp is not None:
+                    sp.tags["stops"] = n_stops
 
         # group placements by task group
         by_tg: dict[str, list] = {}
@@ -739,8 +792,6 @@ class GenericScheduler:
     def _record_exhaustion(metric, ct, ga) -> None:
         """Count eligible nodes that lacked free capacity, per dimension
         (BinPackIterator's 'dimension exhausted' accounting, rank.go:483)."""
-        import numpy as np
-
         from ..structs.resources import RESOURCE_DIMS
 
         elig = ga.eligible[: ct.num_nodes]

@@ -340,6 +340,11 @@ def reconcile(
                 r.inplace_update.append(UpdateRequest(a, job))
                 counts["in_place_update"] += 1
 
+        # lowest name index first (reconcile.go computeGroup:
+        # ``destructive.nameOrder()[:min]``): which allocations a round
+        # replaces does not hang on the order the store lists them in
+        destructive_candidates.sort(key=lambda c: c[0].index())
+
         # rollout gating (reconcile.go computeGroup): with an update
         # strategy, destructive replacements are throttled by the
         # deployment's health signal instead of happening all at once
@@ -386,7 +391,14 @@ def reconcile(
                 ]
             )
             in_flight = len(current) - healthy
-            budget = max(u.max_parallel - in_flight, 0)
+            # placements come first and count against the limit
+            # (reconcile.go computeGroup: ``limit -= min(len(place),
+            # limit)``): after a partial commit the retry fills the names
+            # the job is short of and replaces that many fewer
+            placing = max(desired - len(keep) - len(replace), 0) + len(
+                replace
+            )
+            budget = max(u.max_parallel - in_flight - placing, 0)
             deferred = destructive_candidates[budget:]
             destructive_candidates = destructive_candidates[:budget]
             for a, _pr in deferred:

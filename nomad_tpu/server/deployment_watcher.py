@@ -15,12 +15,13 @@ from __future__ import annotations
 
 import copy
 import threading
-
-from .fsm import MsgType
 import time
 from typing import Optional
 
+from ..obs.trace import global_tracer as tracer
 from ..structs import Evaluation
+from ..utils.metrics import global_metrics
+from .fsm import MsgType
 from ..structs.deployment import (
     DEPLOYMENT_STATUS_FAILED,
     DEPLOYMENT_STATUS_PAUSED,
@@ -35,13 +36,21 @@ from ..structs.evaluation import EVAL_STATUS_PENDING, TRIGGER_DEPLOYMENT_WATCHER
 
 
 class DeploymentWatcher:
-    def __init__(self, server, interval: float = 0.25):
+    def __init__(self, server, interval: float = 0.25, clock=None):
         self.server = server
         self.interval = interval
+        # injectable wall clock (NTA008): health clocks and progress
+        # deadlines read it
+        self._clock = clock if clock is not None else time.time
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # alloc id → first time observed running (health clock)
         self._running_since: dict[str, float] = {}
+        # (namespace, job id) → ``perf_counter`` stamp of the oldest
+        # health commit no eval has answered yet: where a round's lag
+        # starts. The clients' sync writes it, the tick takes it
+        self._health_at: dict[tuple[str, str], float] = {}
+        self._health_lock = threading.Lock()
 
     def start(self) -> None:
         self._stop.clear()
@@ -64,12 +73,47 @@ class DeploymentWatcher:
 
                 logging.getLogger("nomad_tpu.deploy").exception("tick failed")
 
+    def note_client_health(self, applied_at: float, updates) -> None:
+        """The clients' alloc sync committed these updates at
+        ``applied_at``: those that carry a health verdict for an
+        allocation of a deployment free (or fail) its budget, and the
+        next tick answers with an eval. Node.UpdateAlloc carries the
+        client's verdict (``client/allochealth``); the watcher sees it as
+        an alloc update (deploymentwatcher/deployment_watcher.go
+        ``watch``: ``allocsCh`` → ``createBatchedUpdate``)."""
+        verdicts = [
+            u for u in updates
+            if u.deployment_id
+            and u.deployment_status is not None
+            and u.deployment_status.healthy is not None
+        ]
+        if not verdicts:
+            return
+        global_metrics.incr("nomad.deployment.health_applied", len(verdicts))
+        keys = {(u.namespace, u.job_id) for u in verdicts}
+        with self._health_lock:
+            for key in keys:
+                self._health_at.setdefault(key, applied_at)
+
     # -- one scan over active deployments ----------------------------------
     def tick(self) -> None:
+        with tracer.background("deployment.tick") as sp:
+            seen = self._scan()
+            if sp is not None:
+                sp.tags.update(seen)
+        global_metrics.set_gauge("nomad.deployment.active", seen["active"])
+
+    def _scan(self) -> dict:
+        """One walk over every deployment the store holds; returns what
+        the tick saw: deployments ``scanned`` and ``active``, allocations
+        newly ``healthy``, ``evals`` made."""
         store = self.server.store
+        seen = {"scanned": 0, "active": 0, "healthy": 0, "evals": 0}
         for d in list(store.deployments()):
+            seen["scanned"] += 1
             if not d.active():
                 continue
+            seen["active"] += 1
             if d.status == DEPLOYMENT_STATUS_PAUSED:
                 # paused (deployment_endpoint.go Pause): health verdicts,
                 # auto-promotion, and the progress clock all freeze until
@@ -81,7 +125,7 @@ class DeploymentWatcher:
                 for a in store.allocs_by_job(d.namespace, d.job_id)
                 if a.deployment_id == d.id
             ]
-            now = time.time()
+            now = self._clock()
             healthy_ids, unhealthy_ids = [], []
             for a in allocs:
                 if a.deployment_status is not None and (
@@ -116,6 +160,14 @@ class DeploymentWatcher:
                     {"healthy_ids": healthy_ids,
                      "unhealthy_ids": unhealthy_ids},
                 )
+                global_metrics.incr(
+                    "nomad.deployment.health_applied",
+                    len(healthy_ids) + len(unhealthy_ids),
+                )
+                with self._health_lock:
+                    self._health_at.setdefault(
+                        (d.namespace, d.job_id), time.perf_counter()
+                    )
                 for aid in healthy_ids + unhealthy_ids:
                     self._running_since.pop(aid, None)  # verdict settled
                 allocs = [
@@ -124,7 +176,10 @@ class DeploymentWatcher:
                     if a.deployment_id == d.id
                 ]
 
-            self._refresh_counts(d, allocs, progressed=bool(healthy_ids))
+            # newly healthy by this tick's verdicts or by the clients'
+            # own (alloc sync): either frees max_parallel budget
+            newly_healthy = self._refresh_counts(d, allocs)
+            seen["healthy"] += newly_healthy
 
             if any(
                 s.unhealthy_allocs > 0 for s in d.task_groups.values()
@@ -179,6 +234,9 @@ class DeploymentWatcher:
                      "status": DEPLOYMENT_STATUS_SUCCESSFUL,
                      "description": DESC_SUCCESSFUL},
                 )
+                global_metrics.incr("nomad.deployment.successful")
+                with self._health_lock:
+                    self._health_at.pop((d.namespace, d.job_id), None)
                 if job is not None and job.version == d.job_version:
                     stable = copy.copy(job)
                     stable.stable = True
@@ -189,8 +247,10 @@ class DeploymentWatcher:
 
             # progress: newly healthy allocs free max_parallel budget —
             # roll an eval so the scheduler places the next batch
-            if healthy_ids and job is not None:
+            if newly_healthy and job is not None:
                 self._create_eval(job)
+                seen["evals"] += 1
+        return seen
 
     @staticmethod
     def _min_healthy_time(job, tg_name: str) -> float:
@@ -305,6 +365,7 @@ class DeploymentWatcher:
             {"deployment_id": d.id, "status": DEPLOYMENT_STATUS_FAILED,
              "description": desc},
         )
+        global_metrics.incr("nomad.deployment.failed")
         if auto_revert and job is not None and d.job_version > 0:
             # revert to the latest *stable* version (not merely version-1,
             # which may itself be broken — Job.Stable tracking)
@@ -328,9 +389,13 @@ class DeploymentWatcher:
         if job is not None:
             self._create_eval(job)
 
-    def _refresh_counts(self, d, allocs, progressed: bool = False) -> None:
+    def _refresh_counts(self, d, allocs) -> int:
+        """Bring the deployment's per-group counts up to the allocations;
+        returns how many more allocations read healthy than the record
+        held."""
         d2 = copy.deepcopy(d)
         changed = False
+        newly_healthy = 0
         now = time.time()
         for name, s in d2.task_groups.items():
             group = [a for a in allocs if a.task_group == name]
@@ -360,8 +425,9 @@ class DeploymentWatcher:
             ):
                 # each newly healthy alloc extends the progress deadline
                 # (the reference resets requireProgressBy per health event)
-                if progressed and healthy > s.healthy_allocs:
+                if healthy > s.healthy_allocs:
                     s.require_progress_by_unix = now + s.progress_deadline_s
+                    newly_healthy += healthy - s.healthy_allocs
                 s.placed_allocs = placed
                 s.healthy_allocs = healthy
                 s.unhealthy_allocs = unhealthy
@@ -372,6 +438,7 @@ class DeploymentWatcher:
                 MsgType.DEPLOYMENT_UPSERT, {"deployment": d2}
             )
             d.task_groups = d2.task_groups
+        return newly_healthy
 
     def _create_eval(self, job) -> None:
         ev = Evaluation(
@@ -382,4 +449,17 @@ class DeploymentWatcher:
             job_id=job.id,
             status=EVAL_STATUS_PENDING,
         )
-        self.server.apply_eval_create([ev])
+        # the round's lag: the health commit that freed the budget (the
+        # oldest one no eval has answered) → this eval enqueued
+        with self._health_lock:
+            health_at = self._health_at.pop((job.namespace, job.id), None)
+        tags = None
+        if health_at is not None:
+            now = time.perf_counter()
+            tags = {
+                "health_unix": tracer.unix_at(health_at),
+                "enqueue_unix": tracer.unix_at(now),
+                "round_lag_ms": round((now - health_at) * 1000.0, 3),
+            }
+        self.server.apply_eval_create([ev], trace_tags=tags)
+        global_metrics.incr("nomad.deployment.evals_created")

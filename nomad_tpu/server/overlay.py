@@ -23,6 +23,11 @@ as a 0.97 conflict cascade at the 10k-node shape). So:
   usage (base + deltas) or None on a fresh epoch; ALWAYS pair with
   ``pass_finished()`` (finally).
 - ``add_delta(ct, rows, ask)`` — reserve one submitted lane.
+- ``end_scoring()`` — the pass has written its placements: the next pass
+  may read. From ``begin_pass`` to here a pass holds the epoch alone:
+  two passes that read the same usage and then both write (a solo pass on
+  the commit thread beside the worker's next pass) would argmax onto the
+  same freed slot, and the applier would bounce the second.
 - ``commit_started()`` / ``commit_finished()`` — bracket each commit
   thread; finishing only decrements.
 
@@ -36,6 +41,9 @@ import threading
 from typing import Optional
 
 import numpy as np
+
+
+SCORING_WAIT_S = 30.0  # longest a pass waits for another's read-then-write
 
 
 class SharedOverlay:
@@ -56,6 +64,15 @@ class SharedOverlay:
         # cross-lane confirm step asks "does the owner's overlay already
         # predict a placement on this node?" without rescanning arrays
         self._pending_nodes: set[str] = set()
+        # read-then-write of one pass (begin_pass → end_scoring) excludes
+        # another's: held by at most one thread, released by its holder
+        self._scoring = threading.Lock()
+        self._scoring_owner: Optional[int] = None
+
+    def end_scoring(self) -> None:
+        if self._scoring_owner == threading.get_ident():
+            self._scoring_owner = None
+            self._scoring.release()
 
     def maybe_reset(self) -> bool:
         """Drop the epoch iff nothing is in flight. Worker threads call
@@ -77,7 +94,17 @@ class SharedOverlay:
         """Mark a pass in flight and return the usage it should score
         against (base + in-flight deltas), or None when the epoch is
         fresh — then the pass scores on bare ct.used and the first
-        add_delta freezes the base. Pair with pass_finished()."""
+        add_delta freezes the base. Pair with pass_finished(). Waits
+        for a pass that is between its read and its write (bounded: a
+        pass stuck in a kernel must not wedge the others; the applier
+        stays the authority)."""
+        if self._scoring_owner != threading.get_ident():
+            if self._scoring.acquire(timeout=SCORING_WAIT_S):
+                self._scoring_owner = threading.get_ident()
+            else:
+                from ..utils.metrics import global_metrics
+
+                global_metrics.incr("nomad.overlay.scoring_wait_timeouts")
         with self._lock:
             self._passes += 1
             if self._base is not None and self._layout_gen != ct.layout_gen:
@@ -93,6 +120,7 @@ class SharedOverlay:
             return self._base + self._delta
 
     def pass_finished(self) -> None:
+        self.end_scoring()
         with self._lock:
             self._passes = max(0, self._passes - 1)
 
@@ -246,6 +274,9 @@ class LaneOverlays:
 
     def pass_finished(self) -> None:
         self._overlays[0].pass_finished()
+
+    def end_scoring(self) -> None:
+        self._overlays[0].end_scoring()
 
     def add_delta(self, ct, rows, ask, writer=None) -> None:
         self._overlays[0].add_delta(ct, rows, ask, writer=writer)

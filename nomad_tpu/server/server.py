@@ -777,8 +777,21 @@ class Server:
         return bool(ok)
 
     def update_allocs_from_client(self, updates: Iterable[Allocation]) -> None:
+        from ..obs.trace import global_tracer
+
         updates = list(updates)
-        self.raft_apply(self._msg.ALLOC_CLIENT_UPDATE, {"updates": updates})
+        # the clients' alloc sync (Node.UpdateAlloc): entry → applied
+        with global_tracer.background(
+            "client_update", tags={"allocs": len(updates)}
+        ):
+            self.raft_apply(
+                self._msg.ALLOC_CLIENT_UPDATE, {"updates": updates}
+            )
+        # a client's health verdict frees max_parallel budget: the
+        # deployment watcher rolls the next eval from it
+        self.deployment_watcher.note_client_health(
+            time.perf_counter(), updates
+        )
         for u in updates:
             self._publish(
                 "Allocation",
@@ -826,15 +839,17 @@ class Server:
             if ev.status == EVAL_STATUS_BLOCKED:
                 self.blocked_evals.block(ev)
 
-    def apply_eval_create(self, evals: list[Evaluation]) -> None:
+    def apply_eval_create(
+        self, evals: list[Evaluation], trace_tags: Optional[dict] = None
+    ) -> None:
+        """``trace_tags``: what the maker knows of why these evals exist,
+        for the root of their traces (the broker carries it)."""
         self.raft_apply(self._msg.EVAL_UPSERT, {"evals": evals})
         for ev in self._fresh_evals(evals):
             if ev.status == EVAL_STATUS_BLOCKED:
                 self.blocked_evals.block(ev)
-            elif ev.wait_until_unix:
-                self.eval_broker.enqueue(ev)
-            elif ev.should_enqueue():
-                self.eval_broker.enqueue(ev)
+            elif ev.wait_until_unix or ev.should_enqueue():
+                self.eval_broker.enqueue(ev, trace_tags=trace_tags)
 
     # -- state-change fan-out ----------------------------------------------
     def _on_state_change(self, table: str, index: int) -> None:

@@ -403,6 +403,7 @@ class Worker:
                         "priority": ev.priority,
                         "worker": self.id,
                         "batch_size": len(batch),
+                        **((stay.trace_tags if stay else None) or {}),
                     },
                 )
                 if root is not None and stay is not None:

@@ -569,11 +569,17 @@ class TestPassRecord:
         assert {"place.assemble", "place.upload", "place.pull"} <= stages
 
     def test_stages_lie_below_the_leaders_phases_only(self, pass_traces):
-        """What happens below a phase is written once, in the leader's
-        trace; the other member holds the phases, tagged shared."""
+        """What happens below a shared phase is written once, in the
+        leader's trace; the other member holds the phases, tagged shared,
+        and below its own ``prepare`` its own ``reconcile``."""
         member = pass_traces["member"]
         root = member["spans"][0]["span_id"]
-        assert all(s["parent_id"] in (None, root) for s in member["spans"])
+        own = span_by_name(member, "prepare")
+        assert "leader_eval" not in own["tags"]
+        below = [s for s in member["spans"]
+                 if s["parent_id"] not in (None, root)]
+        assert [(s["name"], s["parent_id"]) for s in below] == [
+            ("reconcile", own["span_id"])]
         lead = pass_traces["lead"]
         copies = {s["name"] for s in _top_level(member)
                   if s["tags"].get("leader_eval") == lead["eval_id"]}
@@ -667,6 +673,195 @@ class TestPassRecord:
             for s in _top_level(pass_traces[name])
             if "pass_id" in s["tags"]
         })
+
+
+@pytest.fixture(scope="module")
+def rollout_traces():
+    """A service of 4 with ``max_parallel`` 2 rolled to its next version,
+    this test playing the nodes' clients (each new allocation acknowledged
+    running and healthy, one batch a round): the evals' traces, the
+    background spans, the counters' deltas and the stamps taken around
+    every scan of the watcher."""
+    import copy
+
+    from nomad_tpu.structs.deployment import AllocDeploymentStatus
+    from nomad_tpu.structs.job import UpdateStrategy
+
+    global_tracer.set_enabled(True)
+    global_tracer.reset()
+    flight_recorder.clear()
+    got = []
+    flight_recorder.add_listener(got.append)
+    before = dict(global_metrics.snapshot()["counters"])
+    server = Server(ServerConfig(num_workers=1))
+    scans = []
+    scan = server.deployment_watcher._scan
+
+    def stamped_scan():
+        t0 = time.perf_counter()
+        seen = scan()
+        scans.append((t0, time.perf_counter(), seen))
+        return seen
+
+    server.deployment_watcher._scan = stamped_scan
+    server.establish_leadership()
+
+    def version(v):
+        job = _job("rolling", count=4)
+        job.task_groups[0].update = UpdateStrategy(
+            max_parallel=2, min_healthy_time_s=0.0,
+            health_check="task_states")
+        job.task_groups[0].tasks[0].env = {"VERSION": str(v)}
+        return job
+
+    try:
+        for _ in range(6):
+            server.register_node(mock.node())
+        server.register_job(version(0))
+        assert server.wait_for_evals(timeout=30)
+        server.register_job(version(1))
+        acked, batches = set(), []
+        deadline = time.time() + 30.0
+        while time.time() < deadline:
+            d = server.store.latest_deployment_by_job("default", "rolling")
+            if d is not None and d.status == "successful":
+                break
+            new = [
+                a for a in server.store.allocs_by_job("default", "rolling")
+                if a.job_version == 1 and not a.terminal_status()
+                and a.id not in acked
+            ]
+            if new:
+                updates = []
+                for a in new:
+                    u = copy.copy(a)
+                    u.client_status = "running"
+                    u.deployment_status = AllocDeploymentStatus(healthy=True)
+                    updates.append(u)
+                    acked.add(a.id)
+                server.update_allocs_from_client(updates)
+                batches.append(len(updates))
+            time.sleep(0.01)
+        assert d is not None and d.status == "successful"
+        assert server.wait_for_evals(timeout=30)
+        time.sleep(0.1)  # the last trace reaches the recorder after its ack
+        job = server.store.job_by_id("default", "rolling")
+    finally:
+        server.shutdown()
+        flight_recorder.remove_listener(got.append)
+    after = global_metrics.snapshot()
+    return {
+        "traces": [t for t in got if t["tags"].get("job_id") == "rolling"],
+        "background": flight_recorder.background(),
+        "scans": scans, "batches": batches, "stable": job.stable,
+        "gauges": after["gauges"],
+        "counters": {
+            k: v - before.get(k, 0) for k, v in after["counters"].items()
+            if k.startswith(("nomad.deployment.", "nomad.plan.stops",
+                             "nomad.worker.destructive"))
+        },
+    }
+
+
+class TestRolloutRecord:
+    """The spans and counters of a rollout (PERF.md section 3, layer
+    rollout): present, each starting where its interval started."""
+
+    def test_the_rollout_ran_in_two_rounds_and_ended(self, rollout_traces):
+        r = rollout_traces
+        assert r["batches"] == [2, 2] and r["stable"] is True
+        by = [t["tags"]["triggered_by"] for t in r["traces"]]
+        assert by.count("job-register") == 2
+        assert by.count("deployment-watcher") == 1
+
+    def test_reconcile_lies_under_prepare_with_its_counts(
+            self, rollout_traces):
+        rounds = [
+            t for t in rollout_traces["traces"]
+            if span_by_name(t, "reconcile")["tags"]["destructive"]
+        ]
+        assert len(rounds) == 2
+        for t in rounds:
+            by_id = {s["span_id"]: s for s in t["spans"]}
+            rec = span_by_name(t, "reconcile")
+            assert by_id[rec["parent_id"]]["name"] == "prepare"
+            # the allocations left alone: two deferred in the first
+            # round; in the second the two replaced and their two
+            # replacements
+            tags = dict(rec["tags"])
+            assert tags.pop("ignore") in (2, 4)
+            assert tags == {
+                "place": 0, "destructive": 2, "inplace": 0, "stop": 0,
+                "max_parallel": 2,
+            }
+            assert _outside_parent(t) == []
+
+    def test_plan_stops_is_written_where_the_plans_stops_are_freed(
+            self, rollout_traces):
+        for t in rollout_traces["traces"]:
+            stops = [s for s in t["spans"] if s["name"] == "plan_stops"]
+            rec = span_by_name(t, "reconcile")
+            assert len(stops) == (1 if rec["tags"]["destructive"] else 0)
+            for s in stops:
+                assert s["tags"] == {"stops": 2}
+                by_id = {x["span_id"]: x for x in t["spans"]}
+                assert by_id[s["parent_id"]]["name"] == "prepare"
+                assert s["start_unix"] >= _end(rec) - 2e-6
+
+    def test_a_tick_starts_where_its_interval_started(self, rollout_traces):
+        ticks = [s for s in rollout_traces["background"]
+                 if s["name"] == "deployment.tick"]
+        scans = rollout_traces["scans"]
+        assert len(ticks) == len(scans) >= 2
+        for span, (t0, t1, seen) in zip(ticks, scans):
+            # the span opens before the scan it times and closes after it
+            assert span["start_unix"] <= global_tracer.unix_at(t0)
+            assert global_tracer.unix_at(t0) - span["start_unix"] < 1e-3
+            assert _end(span) >= global_tracer.unix_at(t1) - 2e-6
+            assert span["tags"] == seen
+            assert set(seen) == {"scanned", "active", "healthy", "evals"}
+        assert sum(s["tags"]["healthy"] for s in ticks) == 4
+        assert sum(s["tags"]["evals"] for s in ticks) == 1
+        assert max(s["tags"]["active"] for s in ticks) == 1
+
+    def test_round_lag_is_the_difference_of_two_recorded_times(
+            self, rollout_traces):
+        (t,) = [t for t in rollout_traces["traces"]
+                if t["tags"]["triggered_by"] == "deployment-watcher"]
+        tags = t["tags"]
+        assert tags["round_lag_ms"] == pytest.approx(
+            (tags["enqueue_unix"] - tags["health_unix"]) * 1000.0, abs=2e-3)
+        # the health commit is the first client update's, stamped when it
+        # was applied; the eval was ready in the broker from the enqueue
+        first = [s for s in rollout_traces["background"]
+                 if s["name"] == "client_update"][0]
+        assert 0.0 <= tags["health_unix"] - _end(first) < 5e-3
+        ready = span_by_name(t, "dequeue")["start_unix"]
+        assert -5e-3 < tags["enqueue_unix"] - ready < 5e-3
+        # the watcher polls: the verdict waits for the next tick
+        assert 0.0 < tags["round_lag_ms"] < 2000.0
+
+    def test_client_update_spans_carry_their_batch(self, rollout_traces):
+        updates = [s for s in rollout_traces["background"]
+                   if s["name"] == "client_update"]
+        assert [s["tags"]["allocs"] for s in updates] == [2, 2]
+        assert all(s["duration_ms"] > 0 for s in updates)
+
+    def test_background_spans_are_no_traces(self, rollout_traces):
+        assert all("pass_id" in t["tags"] for t in rollout_traces["traces"])
+        assert all(s["parent_id"] is None
+                   for s in rollout_traces["background"])
+
+    def test_the_rollouts_counters(self, rollout_traces):
+        assert rollout_traces["counters"] == {
+            "nomad.deployment.created": 1,
+            "nomad.deployment.successful": 1,
+            "nomad.deployment.evals_created": 1,
+            "nomad.deployment.health_applied": 4,
+            "nomad.plan.stops_committed": 4,
+            "nomad.worker.destructive_updates": 4,
+        }
+        assert "nomad.deployment.active" in rollout_traces["gauges"]
 
 
 class TestDisabledTracer:
