@@ -18,17 +18,16 @@ candidate space is a dense [N, J] plane computed in one shot
   greedy placement into a single ``lax.top_k`` over the flattened plane.
   One parallel pass replaces ``count`` sequential argmax steps.
 
-- **Gather-scan** (groups whose spread blocks / distinct_property caps
+- **Exact scan** (groups whose spread blocks / distinct_property caps
   couple nodes through global per-value counts): a ``lax.scan`` over
-  placement steps that does only O(N) *gather* work per step — the heads
-  of each node's precomputed column plus a [B, V] per-value boost table —
-  instead of rescoring every node against every resource dim. Exact
-  stepwise-greedy semantics at a fraction of the serial cost. The
-  one-per-value chunked kernel, the one a served cell's window reaches,
-  reads the same heads and tables by dense selects over node-minor
-  arrays instead: a TPU walks a gather element by element (123–171 us
-  for each [16384] vector on a v5e, against 3 us for the select over the
-  whole [24, 16384] plane).
+  placement steps that does only O(N·J) *select* work per step — the
+  heads of each node's precomputed column plus a [B, V] per-value boost
+  table — instead of rescoring every node against every resource dim.
+  Exact stepwise-greedy semantics at a fraction of the serial cost. The
+  scan and the one-per-value chunked kernel read heads and tables by
+  dense selects over node-minor arrays, not by gathers: a TPU walks a
+  gather element by element (123–171 us for each [16384] vector on a
+  v5e, against 3 us for the select over the whole [24, 16384] plane).
 
 Batch dimension = concurrent evals/groups, replacing Nomad's worker-per-
 core optimistic concurrency (nomad/worker.go:85): every group in a batch
@@ -299,8 +298,8 @@ def _score_planes(
         num = num + jitter[:, None]
     den = 1.0 + has_coll + pen[:, None] + jnp.where(has_aff, 1.0, 0.0)
     # slim [1]-shaped lane inputs leave den rank-deficient; the scan
-    # paths read it per node (by gather, or transposed to [J, N] and by
-    # select), so materialize the broadcast
+    # paths read it per node (transposed to [J, N] and by select), so
+    # materialize the broadcast
     num = jnp.broadcast_to(num, fits.shape)
     den = jnp.broadcast_to(den, fits.shape)
     return num, den, fits
@@ -403,7 +402,7 @@ def place_closed_form_kernel(
         )
 
 
-# -- gather-scan (spread / distinct_property groups) -------------------------
+# -- exact scan (spread / distinct_property groups) --------------------------
 
 
 def _block_tables(c, desired, caps, weights, kinds):
@@ -480,11 +479,30 @@ def place_value_scan_kernel(
     """Greedy sequential placement with per-value count coupling.
 
     All heavy scoring is hoisted into the parallel [N, J] plane
-    precompute; each scan step gathers per-node column heads, adds the
+    precompute; each scan step reads every node's column head, adds the
     per-value boost/allowance tables, and argmaxes — the device-resident
     analog of re-running SpreadIterator + DistinctPropertyIterator per
-    placement (scheduler/spread.go:110, feasible.go:645), at O(N) gather
-    cost per step instead of O(N·D·stages) rescoring.
+    placement (scheduler/spread.go:110, feasible.go:645), at one pass
+    over the [J, N] planes per step instead of O(N·D·stages) rescoring.
+
+    Inside the loop the node axis is the minor axis of everything a step
+    reads, and nothing is indexed dynamically (as in
+    ``place_spread_opv_kernel``): the column heads are ``_column_heads``'
+    one-hot select over the [J, N] planes, the boost and allowance of a
+    node's values ``_value_reads``' masked sum / any over the [B, V, N]
+    membership of ``block_value_ids``, the picked node's values a masked
+    sum over a one-hot of the row, and its score the maximum (argmax
+    returns the first maximum; its value is the maximum, −inf included).
+    Picks and scores are those of the five gathers and two dynamic slices
+    these replaced, to the bit (tests/test_scan_dense_reads.py keeps that
+    form as the reference), at 17 us a step on a v5e where they took 772:
+    a gather writes a [16384] vector element by element (PERF.md section
+    6, PR 30 and PR 34). A sum of one selected value and zeros is exact but
+    for −0.0, which would read +0.0. That cannot reach a score: the boost
+    is used only where it is not zero, and a head numerator or
+    denominator is only ever added to +0.0 or to such a boost, which
+    gives the same sum from either zero (no plane holds −0.0 anyway:
+    ``_score_planes`` sums each entry starting from a fit score ≥ +0.0).
     """
 
     eligible, job_counts, penalty_nodes = _unpack_lane_inputs(
@@ -503,25 +521,25 @@ def place_value_scan_kernel(
         n = num.shape[0]
         is_spread = (kinds == BLOCK_TARGET_SPREAD) | (kinds == BLOCK_EVEN_SPREAD)
         has_spread_any = jnp.any(is_spread)
-        safe_vids = jnp.maximum(vids, 0)  # [B, N]
+        # what the loop selects from, node axis minor, built once: the
+        # planes as [J, N], the [B, V, N] membership of ``vids`` and the
+        # row ids that the pick's one-hot compares against
+        num_jn, den_jn, fits_jn = num.T, den.T, fits.T
+        member = vids[:, None, :] == jnp.arange(c0.shape[1])[None, :, None]
+        node_ids = jnp.arange(n)
 
         def step(state, i):
             jn, c = state  # jn i32[N] next column per node; c f32[B, V]
-            head_j = jnp.minimum(jn, max_j - 1)
-            gather = lambda plane: jnp.take_along_axis(
-                plane, head_j[:, None], axis=1
-            )[:, 0]
-            head_num = gather(num)
-            head_den = gather(den)
-            head_fit = gather(fits) & (jn < max_j)
+            head_num, head_den, head_fit = _column_heads(
+                num_jn, den_jn, fits_jn, jn
+            )
 
             tbl, allow = _block_tables(c, desired, vcaps, weights, kinds)
-            per_block = jnp.take_along_axis(tbl, safe_vids, axis=1)  # [B, N]
+            per_block, allow_pb = _value_reads(member, tbl, allow)  # [B, N]
             contrib = jnp.where(vids >= 0, per_block, -1.0)
             boost = jnp.sum(
                 jnp.where(is_spread[:, None], contrib, 0.0), axis=0
             )  # [N]
-            allow_pb = jnp.take_along_axis(allow, safe_vids, axis=1)
             allowed = jnp.all(
                 jnp.where(
                     (kinds == BLOCK_DISTINCT_CAP)[:, None] & (vids >= 0),
@@ -537,10 +555,13 @@ def place_value_scan_kernel(
             score = jnp.where(head_fit & allowed, score, -jnp.inf)
 
             best = jnp.argmax(score)
-            ok = (score[best] > -jnp.inf) & (i < count)
-            onehot = (jnp.arange(n) == best) & ok
-            jn = jn + onehot.astype(jn.dtype)
-            bumped = vids[:, best]  # [B] value per block at the chosen node
+            best_score = jnp.max(score)
+            ok = (best_score > -jnp.inf) & (i < count)
+            best_hot = node_ids == best  # [N]
+            jn = jn + (best_hot & ok).astype(jn.dtype)
+            # [B] value per block at the chosen node (−1 = none: the
+            # one-hot sum returns it unchanged)
+            bumped = jnp.sum(jnp.where(best_hot[None, :], vids, 0), axis=1)
             c = c + jnp.where(
                 (ok & (bumped >= 0))[:, None],
                 jax.nn.one_hot(
@@ -550,7 +571,7 @@ def place_value_scan_kernel(
             )
             return (jn, c), (
                 jnp.where(ok, best, -1).astype(jnp.int32),
-                jnp.where(ok, score[best], -jnp.inf).astype(jnp.float32),
+                jnp.where(ok, best_score, -jnp.inf).astype(jnp.float32),
             )
 
         state0 = (jnp.zeros(n, dtype=jnp.int32), c0)
@@ -598,10 +619,10 @@ def place_spread_chunked_kernel(
 ):
     """Chunked greedy placement for large spread-coupled groups.
 
-    The exact gather-scan (place_value_scan_kernel) pays one sequential
+    The exact scan (place_value_scan_kernel) pays one sequential
     ``lax.scan`` step per placement — 250-instance groups compile to
-    512-deep scans whose per-step work is a trivial gather+argmax, the
-    exact wrong shape for a TPU.
+    512-deep scans whose per-step work is one select over the planes and
+    an argmax, the exact wrong shape for a TPU.
     This kernel instead freezes the per-value boost/allowance tables for
     ``chunk`` placements at a time and selects each chunk with the same
     running-min-clamp + top-k used by the closed-form path, so a
@@ -634,18 +655,19 @@ def place_spread_chunked_kernel(
         nb = vids.shape[0]
         is_spread = (kinds == BLOCK_TARGET_SPREAD) | (kinds == BLOCK_EVEN_SPREAD)
         has_spread_any = jnp.any(is_spread)
-        safe_vids = jnp.maximum(vids, 0)  # [B, N]
+        # the [B, V, N] membership of ``vids`` that the table reads select
+        # through, built once
+        member = vids[:, None, :] == jnp.arange(c0.shape[1])[None, :, None]
         js_row = jnp.arange(max_j, dtype=jnp.int32)[None, :]  # [1, J]
 
         def step(state, _):
             jn, c, n_placed = state  # i32[N], f32[B, V], i32[]
             tbl, allow = _block_tables(c, desired, vcaps, weights, kinds)
-            per_block = jnp.take_along_axis(tbl, safe_vids, axis=1)  # [B, N]
+            per_block, allow_pb = _value_reads(member, tbl, allow)  # [B, N]
             contrib = jnp.where(vids >= 0, per_block, -1.0)
             boost = jnp.sum(
                 jnp.where(is_spread[:, None], contrib, 0.0), axis=0
             )  # [N]
-            allow_pb = jnp.take_along_axis(allow, safe_vids, axis=1)
             allowed = jnp.all(
                 jnp.where(
                     (kinds == BLOCK_DISTINCT_CAP)[:, None] & (vids >= 0),
