@@ -788,11 +788,11 @@ class HTTPAgent:
         elig = body.get("eligibility") if body else None
         if elig not in ("eligible", "ineligible"):
             raise APIError(400, "eligibility must be eligible|ineligible")
-        self.server.raft_apply(
-            MsgType.NODE_ELIGIBILITY,
-            {"node_id": node.id, "eligibility": elig},
-        )
-        return {"eligibility": elig}
+        try:
+            evals = self.server.update_node_eligibility(node.id, elig)
+        except ValueError as e:
+            raise APIError(400, str(e))
+        return {"eligibility": elig, "eval_ids": [e.id for e in evals]}
 
     def handle_node_allocs(self, method, body, query, node_id):
         self._enforce(query, "node_read")

@@ -200,10 +200,21 @@ class EvalBroker:
             )
             self._lock.notify_all()
 
-    def enqueue_all(self, evals: list[Evaluation]) -> None:
+    def enqueue_all(
+        self,
+        evals: list[Evaluation],
+        entered_at: Optional[float] = None,
+        trace_tags: Optional[dict] = None,
+    ) -> None:
+        """The evals of one commit, ready together. ``entered_at`` as in
+        ``enqueue``; ``trace_tags``: eval id -> the root tags of its
+        trace."""
         with self._lock:
             for ev in evals:
-                self._enqueue_locked(ev)
+                self._enqueue_locked(
+                    ev, entered_at=entered_at,
+                    trace_tags=(trace_tags or {}).get(ev.id),
+                )
             self._lock.notify_all()
 
     def _enqueue_locked(

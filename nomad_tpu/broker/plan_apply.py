@@ -25,6 +25,7 @@ import numpy as np
 from ..chaos.plane import active_plane, chaos_site, note_committed
 from ..obs.trace import global_tracer as tracer
 from ..structs import (
+    NODE_SCHED_ELIGIBLE,
     Allocation,
     MergedPlan,
     NetworkIndex,
@@ -45,6 +46,24 @@ class PlanTokenMismatch(Exception):
     on plan submission (plan_endpoint.go / OutstandingReset)."""
 
 
+def _open(node) -> bool:
+    return (
+        node.drain is None
+        and node.scheduling_eligibility == NODE_SCHED_ELIGIBLE
+    )
+
+
+def _closed_to(node, existing, new_allocs) -> bool:
+    """A node that drains or is ineligible takes no new allocation at the
+    index the plan commits at, whatever the snapshot the plan was made on
+    said (plan_apply.go evaluateNodePlan: "node is not eligible"); an
+    update of an allocation it already holds stays where it is."""
+    if _open(node):
+        return False
+    held = {a.id for a in existing}
+    return any(a.id not in held for a in new_allocs)
+
+
 def evaluate_node_plan(snapshot, plan: Plan, node_id: str) -> tuple[bool, str]:
     """Can this node absorb the plan's changes for it?
     (plan_apply.go:638-689). Returns (fits, reason)."""
@@ -55,6 +74,8 @@ def evaluate_node_plan(snapshot, plan: Plan, node_id: str) -> tuple[bool, str]:
         return False, "node is not allowed to receive allocations"
 
     existing = snapshot.allocs_by_node(node_id)
+    if _closed_to(node, existing, plan.node_allocation.get(node_id, ())):
+        return False, "node is not eligible"
     removed = {
         a.id for a in plan.node_update.get(node_id, ())
     } | {a.id for a in plan.node_preemptions.get(node_id, ())}
@@ -220,6 +241,8 @@ def _fast_path_slack(snapshot, node_id, member_plans):
     node = snapshot.node_by_id(node_id)
     if node is None or node.terminal_status():
         return None
+    if not _open(node):
+        return None  # the exact walk refuses what is new to the node
     new_allocs = []
     for mp in member_plans:
         if node_id in mp.node_update or node_id in mp.node_preemptions:
@@ -276,7 +299,7 @@ def _evaluate_node_members(
             if removed:
                 base = [a for a in base if a.id not in removed]
             continue
-        ok = node_ok
+        ok = node_ok and not _closed_to(node, base, new_allocs)
         proposed: list = []
         if ok:
             removed = {a.id for a in stops} | {a.id for a in preempts}

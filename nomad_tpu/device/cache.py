@@ -536,10 +536,11 @@ class DeviceStateCache:
         device_class_ids = device_class_ids.copy()
         device_class_vocab = dict(ct.device_class_vocab)
         num_nodes = ct.num_nodes
-        # attribute columns referencing changed nodes go stale; drop them
-        # (recomputed lazily — node attribute changes are rare next to
-        # alloc churn, which never touches these)
-        attr_cache = dict(ct.attr_cache) if not node_keys else {}
+        # attribute columns: alloc churn never touches them, and a node
+        # write (a drain, a change of eligibility or status, a new
+        # fingerprint) touches its own rows only — filled in below, once
+        # the rows hold their new nodes
+        attr_cache = dict(ct.attr_cache)
 
         for node in new_nodes:
             row = num_nodes
@@ -583,6 +584,26 @@ class DeviceStateCache:
             used[row] = _node_used(snap, nid, dims)
             if region_ids is not None:
                 self._dirty_regions.add(int(region_ids[row]))
+
+        if node_keys:
+            global_metrics.incr(
+                "nomad.device_cache.node_rows_patched", len(node_keys)
+            )
+            rows = [node_row[nid] for nid in node_keys]
+            for attr, (ids, vocab) in attr_cache.items():
+                # copies: the generation before this one keeps its own
+                with global_tracer.span(
+                    "attr_column", tags={"attr": attr, "nodes": len(rows)}
+                ):
+                    ids, vocab = ids.copy(), dict(vocab)
+                    for row in rows:
+                        v = nodes[row].lookup_attribute(attr)
+                        # append-only, as ``attr_column`` fills it
+                        ids[row] = (
+                            -1 if v is None
+                            else vocab.setdefault(str(v), len(vocab))
+                        )
+                    attr_cache[attr] = (ids, vocab)
 
         for nid in alloc_nodes:
             if nid in node_keys:

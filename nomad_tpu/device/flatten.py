@@ -26,8 +26,10 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..obs.trace import global_tracer
 from ..structs import NUM_DIMS, Job, TaskGroup
 from ..structs.resources import node_comparable_capacity
+from ..utils.metrics import global_metrics
 
 
 def _check_constraint(node, c):
@@ -169,13 +171,17 @@ class ClusterTensors:
         cached = self.attr_cache.get(attr)
         if cached is not None:
             return cached
-        ids = np.full(self.padded_n, -1, dtype=np.int32)
-        vocab: dict[str, int] = {}
-        for i in range(self.num_nodes):
-            v = self.nodes[i].lookup_attribute(attr)
-            if v is not None:
-                ids[i] = vocab.setdefault(str(v), len(vocab))
-        self.attr_cache[attr] = (ids, vocab)
+        with global_tracer.span(
+            "attr_column", tags={"attr": attr, "nodes": self.num_nodes}
+        ):
+            ids = np.full(self.padded_n, -1, dtype=np.int32)
+            vocab: dict[str, int] = {}
+            for i in range(self.num_nodes):
+                v = self.nodes[i].lookup_attribute(attr)
+                if v is not None:
+                    ids[i] = vocab.setdefault(str(v), len(vocab))
+            self.attr_cache[attr] = (ids, vocab)
+        global_metrics.incr("nomad.device_cache.attr_columns_rebuilt")
         return ids, vocab
 
     def device_class_column(self) -> tuple[np.ndarray, dict[str, int]]:

@@ -382,6 +382,10 @@ class GenericScheduler:
                     destructive=len(results.destructive_update),
                     inplace=len(results.inplace_update),
                     stop=len(results.stop),
+                    migrate=sum(
+                        c.get("migrate", 0)
+                        for c in results.desired_tg_updates.values()
+                    ),
                     ignore=len(results.ignore),
                     max_parallel=max(
                         (

@@ -164,6 +164,10 @@ class DeploymentWatcher:
                     "nomad.deployment.health_applied",
                     len(healthy_ids) + len(unhealthy_ids),
                 )
+                if healthy_ids:  # frees a draining group's budget too
+                    self.server.drainer.note_health(
+                        time.perf_counter(), d.namespace, d.job_id
+                    )
                 with self._health_lock:
                     self._health_at.setdefault(
                         (d.namespace, d.job_id), time.perf_counter()

@@ -17,38 +17,65 @@ Semantics kept:
 - When nothing migratable remains, the node's DrainStrategy is cleared
   but the node stays ineligible (drainer.go handleDoneNodeDrains,
   NodeDrainEventComplete).
+
+The reference's three watchers are blocking queries: they wake on the
+commit that gives them work. Here the one thread sleeps on an event that
+those commits set (``note_drain``: a strategy set or cleared by
+``Node.UpdateDrain``; ``note_client_update``: the clients' alloc sync,
+which is where a replacement turns healthy and frees its group's budget;
+``note_health``: the deployment watcher's verdicts; ``note_stops``: a plan
+that stopped allocations on a draining node, which may have emptied it)
+and then looks at the draining nodes only, whose ids it keeps. With no
+wake for ``interval`` it walks every node as before: the fallback that
+finds a drain nobody told it about.
 """
 
 from __future__ import annotations
 
 import logging
 
-from .fsm import MsgType
 import threading
 import time
 from typing import Optional
 
+from ..obs.trace import global_tracer as tracer
 from ..structs import Evaluation
 from ..structs.alloc import DesiredTransition
 from ..structs.evaluation import EVAL_STATUS_PENDING, TRIGGER_NODE_DRAIN
 from ..utils.metrics import global_metrics as metrics
+from .fsm import MsgType
 
 log = logging.getLogger("nomad_tpu.drainer")
 
 
 class NodeDrainer:
-    """Polling drainer bound to a Server (the reference's watcher trio
-    collapsed into one scan — blocking-query watches become one pass over
-    draining nodes per interval)."""
+    """The drainer bound to a Server (the reference's watcher trio
+    collapsed into one scan of the draining nodes, woken by the commits
+    the trio's blocking queries would return on)."""
 
     def __init__(self, server, interval: float = 0.25):
         self.server = server
         self.interval = interval
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        self._wake = threading.Event()
+        self._lock = threading.Lock()
+        # ids of the nodes that hold a strategy, as the commits told it
+        self._draining: set[str] = set()
+        self._woken_by: set[str] = set()
+        # ``perf_counter`` stamps of the commits a wave answers: the
+        # strategy's (node id; its first wave) and the oldest client
+        # update of a job no eval has answered yet ((namespace, job id))
+        self._drain_at: dict[str, float] = {}
+        self._freed_at: dict[tuple[str, str], float] = {}
 
     def start(self) -> None:
         self._stop.clear()
+        # a new leader inherits the drains under way
+        with self._lock:
+            self._draining = {
+                n.id for n in self.server.store.nodes() if n.drain is not None
+            }
         self._thread = threading.Thread(
             target=self._run, name="node-drainer", daemon=True
         )
@@ -56,23 +83,112 @@ class NodeDrainer:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()
         if self._thread:
             self._thread.join(timeout=2)
             self._thread = None
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval):
+        while True:
+            woken = self._wake.wait(self.interval)
+            if self._stop.is_set():
+                return
+            self._wake.clear()
             try:
-                self.scan()
+                self.scan(full=not woken)
             except Exception:  # noqa: BLE001
                 log.exception("drainer scan failed")
 
+    # -- the commits that give it work --------------------------------------
+    def _wake_for(self, reason: str) -> None:
+        with self._lock:
+            self._woken_by.add(reason)
+        self._wake.set()
+
+    def note_drain(self, applied_at: float, node_id: str, drain) -> None:
+        """``Node.UpdateDrain`` committed at ``applied_at``."""
+        with self._lock:
+            if drain is not None:
+                self._draining.add(node_id)
+                self._drain_at[node_id] = applied_at
+            else:
+                self._draining.discard(node_id)
+                self._drain_at.pop(node_id, None)
+        if drain is not None:
+            metrics.incr("nomad.drain.started")
+            self._wake_for("node_drain")
+
+    def note_client_update(self, applied_at: float, updates) -> None:
+        """The clients' alloc sync committed at ``applied_at``: a
+        replacement that reads ``running`` counts as healthy from here on
+        (``_alloc_healthy``) and frees its group's budget."""
+        with self._lock:
+            if not self._draining:
+                return
+            for u in updates:
+                if u.client_status == "running":
+                    self._freed_at.setdefault(
+                        (u.namespace, u.job_id), applied_at
+                    )
+        self._wake_for("client_update")
+
+    def note_health(self, applied_at: float, namespace: str,
+                    job_id: str) -> None:
+        """The deployment watcher committed healthy verdicts for
+        allocations of this job."""
+        with self._lock:
+            if not self._draining:
+                return
+            self._freed_at.setdefault((namespace, job_id), applied_at)
+        self._wake_for("health")
+
+    def note_stops(self, node_ids) -> None:
+        """A plan's stops landed on these nodes: a draining one among
+        them may be empty now."""
+        with self._lock:
+            hit = not self._draining.isdisjoint(node_ids)
+        if hit:
+            self._wake_for("plan_stops")
+
     # -- one pass ----------------------------------------------------------
-    def scan(self) -> None:
+    def scan(self, full: bool = True) -> None:
+        """One look at the draining nodes. ``full`` walks every node of
+        the store to find them (the interval's fallback, and what a test
+        that calls this gets); a wake looks up the ids it keeps."""
         store = self.server.store
-        draining = [n for n in store.nodes() if n.drain is not None]
-        for node in draining:
-            self._drain_node(node)
+        with tracer.background("drain.scan") as sp:
+            with self._lock:
+                woken_by = ",".join(sorted(self._woken_by)) or "interval"
+                self._woken_by.clear()
+                ids = sorted(self._draining)
+            if full:
+                nodes = store.nodes()
+                walked = len(nodes)
+                draining = [n for n in nodes if n.drain is not None]
+                with self._lock:
+                    # commits noted since the walk began stay noted
+                    self._draining |= {n.id for n in draining}
+            else:
+                walked = len(ids)
+                draining = [
+                    n for n in map(store.node_by_id, ids)
+                    if n is not None and n.drain is not None
+                ]
+                if len(draining) < len(ids):  # cancelled, or gone
+                    with self._lock:
+                        self._draining -= set(ids) - {n.id for n in draining}
+            seen = {
+                "draining": len(draining), "walked": walked, "marked": 0,
+                "evals": 0, "completed": 0, "woken_by": woken_by,
+            }
+            for node in draining:
+                self._drain_node(node, seen)
+            if sp is not None:
+                sp.tags.update(seen)
+        if not draining:
+            with self._lock:
+                if not self._draining:
+                    self._freed_at.clear()
 
     @staticmethod
     def _alloc_healthy(a) -> bool:
@@ -85,7 +201,7 @@ class NodeDrainer:
             return True
         return a.client_status == "running"
 
-    def _drain_node(self, node) -> None:
+    def _drain_node(self, node, seen: dict) -> None:
         store = self.server.store
         drain = node.drain
         now = time.time()
@@ -111,6 +227,7 @@ class NodeDrainer:
 
         if not remaining:
             self._complete(node, deadlined)
+            seen["completed"] += 1
             return
 
         transitions: dict[str, DesiredTransition] = {}
@@ -191,10 +308,33 @@ class NodeDrainer:
             MsgType.ALLOC_DESIRED_TRANSITION,
             {"transitions": transitions, "evals": evals},
         )
+        metrics.incr("nomad.drain.waves")
+        seen["marked"] += len(transitions)
         if evals:
+            metrics.incr("nomad.drain.evals_created", len(evals))
+            seen["evals"] += len(evals)
+            evals = self.server._fresh_evals(evals)
             self.server.eval_broker.enqueue_all(
-                self.server._fresh_evals(evals)
+                evals, trace_tags=self._wave_lags(node.id, evals)
             )
+
+    def _wave_lags(self, node_id: str, evals) -> dict:
+        """eval id -> the root tags of its trace: the commit that freed
+        the job's budget (the oldest client update no eval has answered;
+        for a node's first wave the strategy's own commit) -> now, the
+        eval about to be enqueued."""
+        now = time.perf_counter()
+        tags = {}
+        with self._lock:
+            first = self._drain_at.pop(node_id, None)
+            for ev in evals:
+                freed = self._freed_at.pop((ev.namespace, ev.job_id), None)
+                start = first if first is not None else freed
+                if start is not None:
+                    tags[ev.id] = {
+                        "wave_lag_ms": round((now - start) * 1000.0, 3),
+                    }
+        return tags
 
     def _complete(self, node, deadlined: bool) -> None:
         """Drain finished: clear the strategy, stay ineligible
@@ -206,6 +346,8 @@ class NodeDrainer:
             {"node_id": node.id, "drain": None,
              "eligibility": NODE_SCHED_INELIGIBLE},
         )
+        self.note_drain(time.perf_counter(), node.id, None)
+        metrics.incr("nomad.drain.completed")
         self.server._publish(
             "Node",
             "NodeDrainComplete",

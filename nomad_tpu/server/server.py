@@ -26,6 +26,7 @@ from ..state import StateStore
 from ..structs import (
     EVAL_STATUS_BLOCKED,
     EVAL_STATUS_PENDING,
+    NODE_SCHED_ELIGIBLE,
     Allocation,
     Evaluation,
     Job,
@@ -40,6 +41,7 @@ from ..structs.evaluation import (
     TRIGGER_JOB_DEREGISTER,
     TRIGGER_RETRY_FAILED_ALLOC,
 )
+from ..utils.metrics import global_metrics
 from .worker import Worker
 
 log = logging.getLogger("nomad_tpu.server")
@@ -399,6 +401,7 @@ class Server:
                     stopped.setdefault(node_id, []).extend(gone)
         if not stopped:
             return
+        self.drainer.note_stops(stopped)
         # the optimistic overlay scores an epoch's passes on a base frozen
         # at its start: hand it the room first, then wake who waits for it
         ct = self.device_cache.resident()
@@ -652,27 +655,85 @@ class Server:
 
     def update_node_drain(self, node_id: str, drain) -> list[Evaluation]:
         """Node.UpdateDrain: stamp the force deadline and commit; the
-        NodeDrainer picks the node up on its next scan. Cancelling a
-        drain clears any pending migrate marks so wave accounting and
-        future drains start clean (drainer.go Remove)."""
-        import time as _t
+        NodeDrainer wakes on the commit. Cancelling a drain clears any
+        pending migrate marks so wave accounting and future drains start
+        clean (drainer.go Remove). Node evals are made only where the
+        node turns eligible again (node_endpoint.go UpdateDrain: "if the
+        node is transitioning to be eligible, create Node evaluations");
+        a drain that starts makes none — the drainer makes one a job as
+        it marks allocations, and an eval before the first mark finds
+        nothing to do."""
+        from ..obs.trace import global_tracer
 
-        if drain is not None and drain.deadline_s > 0 and not drain.force_deadline_unix:
-            drain.force_deadline_unix = _t.time() + drain.deadline_s
+        t_entry = time.perf_counter()
+        with global_tracer.background("node_drain") as sp:
+            live = [
+                a for a in self.store.allocs_by_node(node_id)
+                if not a.terminal_status()
+            ]
+            if (
+                drain is not None
+                and drain.deadline_s > 0
+                and not drain.force_deadline_unix
+            ):
+                drain.force_deadline_unix = time.time() + drain.deadline_s
 
-        resets = {}
-        if drain is None:
-            from ..structs.alloc import DesiredTransition as _DT
+            resets = {}
+            if drain is None:
+                from ..structs.alloc import DesiredTransition as _DT
 
-            for a in self.store.allocs_by_node(node_id):
-                if not a.terminal_status() and a.desired_transition.migrate:
-                    resets[a.id] = _DT(migrate=False)
+                resets = {
+                    a.id: _DT(migrate=False)
+                    for a in live if a.desired_transition.migrate
+                }
+            before = self.store.node_by_id(node_id)
+            self.raft_apply(
+                self._msg.NODE_DRAIN,
+                {"node_id": node_id, "drain": drain, "transitions": resets},
+            )
+            self.drainer.note_drain(time.perf_counter(), node_id, drain)
+            evals = []
+            if self._turned_eligible(before, node_id):
+                evals = self._create_node_evals(node_id, entered_at=t_entry)
+            if sp is not None:
+                sp.tags.update(allocs=len(live), node_evals=len(evals))
+        return evals
 
+    def update_node_eligibility(
+        self, node_id: str, eligibility: str
+    ) -> list[Evaluation]:
+        """Node.UpdateEligibility (node_endpoint.go): commit, and where
+        the node turns eligible make node evals, "because there may be a
+        System job registered that should be evaluated". A node that
+        drains cannot be made eligible."""
+        t_entry = time.perf_counter()
+        before = self.store.node_by_id(node_id)
+        if before is None:
+            raise KeyError(f"node {node_id} not found")
+        if before.drain is not None and eligibility == NODE_SCHED_ELIGIBLE:
+            raise ValueError(
+                "can not set node's scheduling eligibility to eligible "
+                "while it is draining"
+            )
         self.raft_apply(
-            self._msg.NODE_DRAIN,
-            {"node_id": node_id, "drain": drain, "transitions": resets},
+            self._msg.NODE_ELIGIBILITY,
+            {"node_id": node_id, "eligibility": eligibility},
         )
-        return self._create_node_evals(node_id)
+        self._publish(
+            "Node", "NodeEligibility", node_id, "default",
+            {"eligibility": eligibility},
+        )
+        if self._turned_eligible(before, node_id):
+            return self._create_node_evals(node_id, entered_at=t_entry)
+        return []
+
+    def _turned_eligible(self, before, node_id: str) -> bool:
+        after = self.store.node_by_id(node_id)
+        return (
+            before is not None and after is not None
+            and before.scheduling_eligibility != NODE_SCHED_ELIGIBLE
+            and after.scheduling_eligibility == NODE_SCHED_ELIGIBLE
+        )
 
     def stop_alloc(self, alloc_id: str) -> Optional[Evaluation]:
         """Alloc.Stop (nomad/alloc_endpoint.go): mark the allocation for
@@ -708,7 +769,11 @@ class Server:
         self.eval_broker.enqueue(ev)
         return ev
 
-    def _create_node_evals(self, node_id: str) -> list[Evaluation]:
+    def _create_node_evals(
+        self, node_id: str, entered_at: Optional[float] = None
+    ) -> list[Evaluation]:
+        """``entered_at``: the entry of the server call these evals
+        answer, for their ``register`` span."""
         jobs = {}
         for a in self.store.allocs_by_node(node_id):
             if not a.terminal_status() or a.client_status == "failed":
@@ -731,6 +796,8 @@ class Server:
         node = self.store.node_by_id(node_id)
         if node is not None and node.ready():
             for job in self.store.jobs():
+                if (job.namespace, job.id) in jobs:
+                    continue  # has its eval (createNodeEvals: jobIDs)
                 if job.type in ("system", "sysbatch") and not job.stopped():
                     evals.append(
                         Evaluation(
@@ -745,8 +812,9 @@ class Server:
                     )
         if evals:
             self.raft_apply(self._msg.EVAL_UPSERT, {"evals": evals})
+            global_metrics.incr("nomad.node.update_evals", len(evals))
             evals = self._fresh_evals(evals)
-            self.eval_broker.enqueue_all(evals)
+            self.eval_broker.enqueue_all(evals, entered_at=entered_at)
         return evals
 
     # -- API: client alloc updates ----------------------------------------
@@ -788,10 +856,11 @@ class Server:
                 self._msg.ALLOC_CLIENT_UPDATE, {"updates": updates}
             )
         # a client's health verdict frees max_parallel budget: the
-        # deployment watcher rolls the next eval from it
-        self.deployment_watcher.note_client_health(
-            time.perf_counter(), updates
-        )
+        # deployment watcher rolls the next eval from it, and the drainer
+        # marks a draining node's next wave
+        applied_at = time.perf_counter()
+        self.deployment_watcher.note_client_health(applied_at, updates)
+        self.drainer.note_client_update(applied_at, updates)
         for u in updates:
             self._publish(
                 "Allocation",

@@ -415,3 +415,174 @@ class TestDrainerChaos:
             assert a.node_id != victim.id
             node = server.store.node_by_id(a.node_id)
             assert node.status == "ready"
+
+
+# -- the wake, and Node.UpdateEligibility ------------------------------------
+
+
+@pytest.fixture
+def quiet_server():
+    """A server whose drainer would poll every 10 s: what happens sooner
+    happens on a wake."""
+    s = Server(ServerConfig(num_workers=1, heartbeat_ttl=60.0))
+    s.drainer.interval = 10.0
+    s.establish_leadership()
+    yield s
+    s.shutdown()
+
+
+def _running(server, job):
+    import copy
+
+    updates = []
+    for a in server.store.allocs_by_job(job.namespace, job.id):
+        if a.client_status == "pending" and not a.terminal_status():
+            u = copy.copy(a)
+            u.client_status = "running"
+            updates.append(u)
+    if updates:
+        server.update_allocs_from_client(updates)
+
+
+def test_a_drain_starts_on_the_commit_that_set_it(quiet_server):
+    """watch_nodes.go / watch_jobs.go are blocking queries: the first marks
+    follow the strategy's commit, not the next poll."""
+    server = quiet_server
+    n1, n2 = mock.node(), mock.node()
+    server.register_node(n1)
+    server.register_node(n2)
+    job = mock.job()
+    job.task_groups[0].count = 4
+    job.task_groups[0].migrate = MigrateStrategy(max_parallel=1)
+    server.register_job(job)
+    assert server.wait_for_evals(10)
+    _running(server, job)
+    victim = max(
+        (n1, n2), key=lambda n: len(live_allocs_on(server, n.id)))
+    held = len(live_allocs_on(server, victim.id))
+    assert held > 0
+    scans = global_metrics.snapshot()["counters"].get("nomad.drain.waves", 0)
+
+    t0 = time.perf_counter()
+    evals = server.update_node_drain(victim.id, DrainStrategy(deadline_s=3600))
+    assert evals == []  # a drain that starts makes no node evals
+    assert wait_until(
+        lambda: any(
+            a.desired_transition.migrate
+            for a in server.store.allocs_by_node(victim.id)
+        ),
+        timeout=2.0, interval=0.002,
+    )
+    assert time.perf_counter() - t0 < 0.05
+    # every later wave rides the clients' acknowledgement: the whole drain
+    # ends well inside the 10 s of one poll
+    deadline = time.time() + 8.0
+    while time.time() < deadline and (
+        server.store.node_by_id(victim.id).drain is not None
+    ):
+        _running(server, job)
+        time.sleep(0.01)
+    node = server.store.node_by_id(victim.id)
+    assert node.drain is None and node.scheduling_eligibility == "ineligible"
+    assert not live_allocs_on(server, victim.id)
+    assert time.perf_counter() - t0 < 8.0
+    waves = global_metrics.snapshot()["counters"]["nomad.drain.waves"] - scans
+    assert waves == held  # max_parallel 1: a wave an allocation
+    # the scans were wakes over the draining set, not walks of the fleet
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    woken = [
+        s for s in flight_recorder.background()
+        if s["name"] == "drain.scan" and s["tags"]["woken_by"] != "interval"
+    ]
+    assert woken and all(s["tags"]["walked"] <= 1 for s in woken)
+    assert {"node_drain", "client_update"} <= {
+        r for s in woken for r in s["tags"]["woken_by"].split(",")}
+
+
+def test_a_node_that_turns_eligible_gets_node_evals(quiet_server):
+    """node_endpoint.go UpdateEligibility: evals for the system jobs when
+    a node turns eligible; none when it turns ineligible or stays as it
+    is; a draining node cannot be made eligible."""
+    server = quiet_server
+    node = mock.node()
+    server.register_node(node)
+    sysjob = mock.system_job()
+    server.register_job(sysjob)
+    assert server.wait_for_evals(10)
+    made = lambda: global_metrics.snapshot()["counters"].get(  # noqa: E731
+        "nomad.node.update_evals", 0)
+    before = made()
+
+    assert server.update_node_eligibility(node.id, "ineligible") == []
+    assert server.store.node_by_id(node.id).scheduling_eligibility == (
+        "ineligible")
+    assert server.update_node_eligibility(node.id, "ineligible") == []
+    evals = server.update_node_eligibility(node.id, "eligible")
+    assert [e.job_id for e in evals] == [sysjob.id]
+    assert all(e.triggered_by == "node-update" and e.node_id == node.id
+               for e in evals)
+    assert made() == before + 1
+    assert server.update_node_eligibility(node.id, "eligible") == []
+
+    server.update_node_drain(node.id, DrainStrategy(deadline_s=3600))
+    with pytest.raises(ValueError):
+        server.update_node_eligibility(node.id, "eligible")
+    with pytest.raises(KeyError):
+        server.update_node_eligibility("no-such-node", "eligible")
+
+
+def test_the_http_handler_goes_through_update_node_eligibility(quiet_server):
+    from nomad_tpu.api.http import APIError, HTTPAgent
+
+    server = quiet_server
+    node = mock.node()
+    server.register_node(node)
+    server.register_job(mock.system_job())
+    assert server.wait_for_evals(10)
+    http = HTTPAgent(server, None, port=0)
+    calls = []
+    through = server.update_node_eligibility
+
+    def recording(node_id, eligibility):
+        calls.append((node_id, eligibility))
+        return through(node_id, eligibility)
+
+    server.update_node_eligibility = recording
+    out = http.handle_node_eligibility(
+        "POST", {"eligibility": "ineligible"}, {}, node.id)
+    assert out == {"eligibility": "ineligible", "eval_ids": []}
+    out = http.handle_node_eligibility(
+        "PUT", {"eligibility": "eligible"}, {}, node.id)
+    assert out["eligibility"] == "eligible" and len(out["eval_ids"]) == 1
+    assert calls == [(node.id, "ineligible"), (node.id, "eligible")]
+    server.update_node_drain(node.id, DrainStrategy(deadline_s=3600))
+    with pytest.raises(APIError) as e:
+        http.handle_node_eligibility(
+            "POST", {"eligibility": "eligible"}, {}, node.id)
+    assert e.value.status == 400
+
+
+def test_the_applier_refuses_a_node_that_started_draining_after_the_snapshot(
+        quiet_server):
+    """plan_apply.go evaluateNodePlan: a plan made before the drain was
+    set places nothing on the node at the index it commits at."""
+    from nomad_tpu.broker.plan_apply import evaluate_node_plan
+    from nomad_tpu.structs import Plan
+
+    server = quiet_server
+    node = mock.node()
+    server.register_node(node)
+    job = mock.job()
+    plan = Plan(eval_id="e", job=job)
+    alloc = mock.alloc(job, node_id=node.id)
+    plan.node_allocation[node.id] = [alloc]
+    ok, _why = evaluate_node_plan(server.store.snapshot(), plan, node.id)
+    assert ok
+    server.update_node_eligibility(node.id, "ineligible")
+    ok, why = evaluate_node_plan(server.store.snapshot(), plan, node.id)
+    assert not ok and why == "node is not eligible"
+    # an update of an allocation the node already holds stays
+    server.store.upsert_allocs(server.store.latest_index + 1, [alloc])
+    ok, _why = evaluate_node_plan(server.store.snapshot(), plan, node.id)
+    assert ok

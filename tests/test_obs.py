@@ -792,7 +792,7 @@ class TestRolloutRecord:
             assert tags.pop("ignore") in (2, 4)
             assert tags == {
                 "place": 0, "destructive": 2, "inplace": 0, "stop": 0,
-                "max_parallel": 2,
+                "migrate": 0, "max_parallel": 2,
             }
             assert _outside_parent(t) == []
 
