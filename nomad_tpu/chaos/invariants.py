@@ -509,15 +509,13 @@ def check_cluster(
             )
 
     # -- shard_consistency -------------------------------------------------
-    # Law 12: with a multi-chip mesh active, the device-resident capacity
-    # shards (per-shard incremental refresh, device/cache.py) re-gathered
-    # to host must equal the store-derived reference bitwise — including
-    # after mesh.shard_refresh_drop recovery. Skipped when no device view
-    # ever materialized (mesh off / single shard).
-    from ..utils.backend import get_mesh
-
+    # Law 12: the device-resident capacity (device/cache.py: one buffer
+    # on one device, per-shard incremental refresh under a mesh)
+    # re-gathered to host must equal the store-derived reference bitwise
+    # — including after mesh.shard_refresh_drop recovery. Skipped when no
+    # device view ever materialized.
     cache = getattr(server, "device_cache", None)
-    if get_mesh().active and cache is not None:
+    if cache is not None:
         mismatches = cache.verify_device_view()
         if mismatches is not None:
             report.checked["shard_consistency"] = True
@@ -528,8 +526,8 @@ def check_cluster(
     # rescoring, device/cache.py) re-gathered to host must equal their
     # generation mirror bitwise — including after cache.score_refresh_drop
     # recovery and killed commits. Checked whenever a score view ever
-    # materialized; unlike the capacity half it also exists with the mesh
-    # off (the degenerate path persists a whole-tensor buffer).
+    # materialized (with the mesh off the degenerate path persists a
+    # whole-tensor buffer).
     if cache is not None:
         score_mismatches = cache.verify_score_view()
         if score_mismatches is not None:

@@ -76,13 +76,18 @@ class DeviceStateCache:
         self.incremental_refreshes = 0
         self.hits = 0
         self.stale_builds = 0  # older-than-resident snapshots (transient)
-        # mesh sharding: device-resident capacity, refreshed per shard.
-        # Dirty-REGION tracking (region ids are stable across incremental
-        # refreshes; only a full reflatten may re-sort rows) maps journal
-        # changes to the node-axis shards that must re-upload; clean
-        # shards keep their existing device buffers.
-        self._dev_capacity = None  # committed sharded jax.Array | None
+        # device-resident capacity of the resident generation: capacity
+        # changes only when a node is written, so a pass reads the
+        # buffer of the pass before it. On one device a refresh that
+        # changed a capacity row marks the buffer stale and the next
+        # access uploads it whole. Under a mesh it is refreshed per
+        # shard: dirty-REGION tracking (region ids are stable across
+        # incremental refreshes; only a full reflatten may re-sort rows)
+        # maps journal changes to the node-axis shards that must
+        # re-upload; clean shards keep their existing device buffers.
+        self._dev_capacity = None  # committed jax.Array | None
         self._dev_layout_gen = 0
+        self._dev_capacity_stale = False
         self._dirty_regions: set[int] = set()
         self.shard_uploads = 0  # per-shard (partial) device refreshes
         self.full_uploads = 0  # whole-tensor device uploads
@@ -114,9 +119,9 @@ class DeviceStateCache:
             if sp is not None:
                 sp.tags["full"] = self.full_flattens > flattens
             out = replace(ct, used=ct.used.copy())
-            cfg = get_mesh()
-            if cfg.active:
-                out.device_capacity = self._device_capacity_locked(ct, cfg)
+            out.device_capacity = self._device_capacity_locked(
+                ct, get_mesh()
+            )
             if incremental_enabled():
                 # the incremental seam the kernels read (device/score.py
                 # used_device): present ⇒ the pass's ``used`` upload may
@@ -137,6 +142,7 @@ class DeviceStateCache:
         with self._lock:
             self._ct = None
             self._dev_capacity = None
+            self._dev_capacity_stale = False
             self._dirty_regions.clear()
             self._score = None
             self._score_staged = None
@@ -167,24 +173,24 @@ class DeviceStateCache:
         """Invariant law 12 (shard_consistency) probe: re-gather every
         device-resident capacity shard to host and compare *bitwise*
         against the resident generation's store-derived capacity.
-        Returns None when no device view is materialized (mesh off, or
-        never accessed); else a list of mismatch details (empty ==
-        consistent). Pending dirty regions are fine — they re-upload on
-        the next access — but a shard that claims to be clean must
-        match."""
+        Returns None when no device view is materialized (never
+        accessed, or a mesh that does not divide the bucket); else a
+        list of mismatch details (empty == consistent). Pending dirty
+        regions are fine — they re-upload on the next access — but a
+        shard that claims to be clean must match."""
         with self._lock:
             ct = self._ct
             arr = self._dev_capacity
             if ct is None or arr is None:
                 return None
-            if self._dirty_regions:
-                # flush pending per-shard refreshes so the comparison
-                # sees what the next eval would read
+            if self._dirty_regions or self._dev_capacity_stale:
+                # flush pending refreshes so the comparison sees what
+                # the next eval would read
                 from ..utils.backend import get_mesh
 
-                cfg = get_mesh()
-                if cfg.active:
-                    arr = self._device_capacity_locked(ct, cfg)
+                arr = self._device_capacity_locked(ct, get_mesh())
+                if arr is None:
+                    return None
             problems: list[str] = []
             ref = np.asarray(ct.capacity)
             for sh in arr.addressable_shards:
@@ -377,42 +383,49 @@ class DeviceStateCache:
                     )
             return problems
 
-    # -- device view (mesh sharding) ---------------------------------------
+    # -- device view -------------------------------------------------------
+    def _capacity_upload_locked(self, ct: ClusterTensors, cfg):
+        """Whole-tensor upload of the resident generation's capacity."""
+        from ..utils.backend import shard_put
+
+        self._dev_capacity = shard_put(ct.capacity, ("nodes",), cfg)
+        self._dev_layout_gen = ct.layout_gen
+        self._dev_capacity_stale = False
+        self._dirty_regions.clear()
+        self.full_uploads += 1
+        global_metrics.incr("nomad.device_cache.capacity_uploads")
+        return self._dev_capacity
+
     def _device_capacity_locked(self, ct: ClusterTensors, cfg):
-        """Sharded device-resident capacity for the resident generation.
-        Steady-state node updates re-upload ONLY the shards whose regions
-        went dirty; layout changes (full reflatten) or a chaos-dropped
-        shard refresh fall back to a whole-tensor upload. Returns None
-        when the mesh doesn't divide the bucket (callers shard on the
-        fly)."""
+        """Device-resident capacity for the resident generation: the
+        buffer of the last access unless a node write changed a row
+        since. On one device that is a whole-tensor upload; under a
+        mesh steady-state node updates re-upload ONLY the shards whose
+        regions went dirty. A first access, a layout change (full
+        reflatten) or a chaos-dropped shard refresh upload the whole
+        tensor. Returns None when the mesh doesn't divide the bucket
+        (callers shard on the fly)."""
         import jax
 
         from ..chaos.plane import chaos_site
-        from ..utils.backend import shard_put
 
         mp = cfg.n_node_shards
         pn = ct.padded_n
-        if mp <= 1 or pn % mp != 0 or ct.region_ids is None:
+        if mp > 1 and (pn % mp != 0 or ct.region_ids is None):
             return None
         if (
             self._dev_capacity is None
             or self._dev_layout_gen != ct.layout_gen
             or self._dev_capacity.shape != ct.capacity.shape
+            or (mp <= 1 and self._dev_capacity_stale)
         ):
-            self._dev_capacity = shard_put(ct.capacity, ("nodes",), cfg)
-            self._dev_layout_gen = ct.layout_gen
-            self._dirty_regions.clear()
-            self.full_uploads += 1
-            return self._dev_capacity
-        if not self._dirty_regions:
+            return self._capacity_upload_locked(ct, cfg)
+        if mp <= 1 or not self._dirty_regions:
             return self._dev_capacity
         if chaos_site("mesh.shard_refresh_drop") == "drop":
             # a dropped shard upload must never serve stale capacity:
             # recovery is a whole-tensor re-upload on this access
-            self._dev_capacity = shard_put(ct.capacity, ("nodes",), cfg)
-            self._dirty_regions.clear()
-            self.full_uploads += 1
-            return self._dev_capacity
+            return self._capacity_upload_locked(ct, cfg)
         seg = pn // mp
         rows = np.flatnonzero(
             np.isin(ct.region_ids, list(self._dirty_regions))
@@ -433,6 +446,7 @@ class DeviceStateCache:
         self._dev_capacity = jax.make_array_from_single_device_arrays(
             ct.capacity.shape, arr.sharding, bufs
         )
+        self._dev_capacity_stale = False
         self._dirty_regions.clear()
         self.shard_uploads += 1
         return self._dev_capacity
@@ -559,6 +573,7 @@ class DeviceStateCache:
                 getattr(node, "device_class", ""), len(device_class_vocab)
             )
             capacity[row] = node_comparable_capacity(node).to_vector()
+            self._dev_capacity_stale = True
             ready[row] = node.ready()
             used[row] = _node_used(snap, node.id, dims)
             if region_ids is not None:
@@ -579,7 +594,12 @@ class DeviceStateCache:
                 continue  # appended above
             node = snap.node_by_id(nid)
             nodes[row] = node
-            capacity[row] = node_comparable_capacity(node).to_vector()
+            vec = node_comparable_capacity(node).to_vector()
+            if (capacity[row] != vec).any():
+                # a drain or a change of eligibility writes the node and
+                # leaves its capacity: the device's copy still holds
+                self._dev_capacity_stale = True
+            capacity[row] = vec
             ready[row] = node.ready()
             used[row] = _node_used(snap, nid, dims)
             if region_ids is not None:

@@ -126,10 +126,12 @@ class ClusterTensors:
     # region. region_vocab maps "dc[/device_class]" → id.
     region_ids: np.ndarray | None = None  # i32[N]
     region_vocab: dict[str, int] = field(default_factory=dict)
-    # device-resident sharded capacity for this generation (filled by
-    # DeviceStateCache when a mesh is active; None = shard on the fly).
-    # Shared by reference across the per-call used-copy wrappers — the
-    # buffer is immutable on device and regenerated per cache refresh.
+    # device-resident capacity for this generation (filled by
+    # DeviceStateCache, sharded when a mesh is active; None = hand-built
+    # tensors, or a mesh that does not divide the bucket: upload on the
+    # fly). Shared by reference across the per-call used-copy wrappers —
+    # the buffer is immutable on device and uploaded again only after a
+    # node write changed a capacity row.
     device_capacity: object = None
     # incremental-rescoring seam (NOMAD_TPU_INCREMENTAL): the owning
     # DeviceStateCache, attached by ``tensors()`` only when the

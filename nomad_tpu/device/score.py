@@ -58,6 +58,7 @@ Scoring component semantics (each cites its reference):
 from __future__ import annotations
 
 import functools
+import inspect
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -67,7 +68,13 @@ import numpy as np
 
 from ..obs.trace import global_tracer as _tracer
 from ..structs.resources import BINPACK_MAX_SCORE
-from ..utils.backend import get_mesh, shard_put, traced_jit
+from ..utils.backend import (
+    get_mesh,
+    host_put,
+    shard_put,
+    traced_jit,
+    transfer_totals,
+)
 
 # Retrace budgets (nomad_tpu.analysis.retrace): the per-kernel trace
 # count a representative bench batch may reach. Every dynamic dimension
@@ -1115,13 +1122,17 @@ def _pad_group_axis(asks: list, pn: int) -> list:
 
 def _shared_batch(asks: list, pn: int) -> dict:
     """Host-side assembly of the kernel inputs common to all placement
-    paths (the value-block fields are added by the coupled paths).
+    paths (the value-block fields, the algorithm flag and the tie-break
+    jitter are added by ``PlacementKernel``).
 
-    Transfer-slimmed: eligibility/penalty masks ride bit-packed (u8,
-    8×), and per-lane arrays that are degenerate across
-    the whole batch (no job allocs yet, no penalties, no affinities, no
-    device asks — the common case for fresh registrations) collapse to
-    [G, 1] broadcasts instead of [G, N] uploads."""
+    Slim forms: eligibility/penalty masks ride bit-packed (u8, 8×), and
+    per-lane arrays that are degenerate across the whole batch (no job
+    allocs yet, no penalties, no affinities, no device asks — the common
+    case for fresh registrations) collapse to [G, 1] broadcasts instead
+    of [G, N] arrays. The forms key the compiled variants and keep the
+    host's assembly and the one packed buffer small; what a kernel call
+    pays for its operands is the number of hand-offs, not their bytes
+    (PERF.md section 6, PR 36), and ``_pack_operands`` makes that one."""
     g = len(asks)
     jc = np.stack([a.job_counts for a in asks])
     if not jc.any():
@@ -1187,6 +1198,7 @@ _BATCH_SPECS = {
     "block_weights": ("groups",),
     "block_kinds": ("groups",),
     "throughputs": ("groups", "nodes"),
+    "jitter": ("nodes",),
 }
 
 
@@ -1210,18 +1222,91 @@ def used_device(cluster, used0, cfg=None):
     return shard_put(used0, ("nodes",), cfg)
 
 
-def _device_batch(batch: dict, cfg=None) -> dict:
-    """Upload a host batch dict through the sharding seam: NamedSharding
-    placement when a mesh is active, plain jnp.asarray otherwise (the
-    degenerate path is byte-for-byte the pre-mesh upload)."""
-    if cfg is None:
-        cfg = get_mesh()
-    if not cfg.active:
-        return {k: jnp.asarray(v) for k, v in batch.items()}
+def _device_batch(batch: dict, cfg) -> dict:
+    """A host batch dict onto an active mesh, array by array, each with
+    its PartitionSpec: what the specs are for, and what one packed
+    buffer cannot carry. The per-call scalars that have no spec
+    (``algorithm_spread``, ``enforce_idx``) go up unplaced."""
     return {
-        k: shard_put(v, _BATCH_SPECS.get(k, ()), cfg)
+        k: shard_put(v, _BATCH_SPECS[k], cfg)
+        if k in _BATCH_SPECS
+        else jnp.asarray(v)
         for k, v in batch.items()
     }
+
+
+def _pack_operands(batch: dict):
+    """``(layout, words)``: the batch's arrays laid end to end in one
+    fresh ``uint32`` buffer, each from a word boundary, in the dict's
+    order. ``layout`` — ``(name, dtype, shape)`` per array — is a pure
+    function of the shapes and dtypes, which key the compiled variant
+    already; ``_unpack_operands`` is its inverse inside the compiled
+    program. One buffer is one host→device hand-off where the dict was
+    one per array, at 0.3 ms each whatever it carried."""
+    arrays = [np.asarray(v) for v in batch.values()]
+    layout = tuple(
+        (k, a.dtype.name, a.shape) for k, a in zip(batch, arrays)
+    )
+    if any(a.dtype.itemsize not in (1, 4) for a in arrays):
+        raise ValueError(f"operands of 1 or 4 bytes an element only: {layout}")
+    starts = np.cumsum([0] + [-(-a.nbytes // 4) * 4 for a in arrays])
+    words = np.zeros(int(starts[-1]) // 4, dtype=np.uint32)
+    raw = words.view(np.uint8)
+    for a, at in zip(arrays, starts):
+        # reshape(-1) copies where ``a`` is not contiguous
+        raw[at : at + a.nbytes] = a.reshape(-1).view(np.uint8)
+    return layout, words
+
+
+def _unpack_operands(words, layout) -> dict:
+    """The arrays ``_pack_operands`` laid into ``words``, by static
+    slices and bitcasts: same dtypes, shapes and bytes."""
+    out = {}
+    at = 0
+    for name, dtype, shape in layout:
+        dt = np.dtype(dtype)
+        size = int(np.prod(shape, dtype=np.int64))
+        n = -(-size * dt.itemsize // 4)
+        seg = words[at : at + n]
+        at += n
+        if dt.itemsize == 4:
+            arr = jax.lax.bitcast_convert_type(seg, dt)
+        else:
+            arr = jax.lax.bitcast_convert_type(seg, jnp.uint8)
+            arr = arr.reshape(-1)[:size]
+            if dt == np.bool_:
+                arr = arr != 0
+        out[name] = arr.reshape(shape)
+    return out
+
+
+@functools.cache
+def _packed_entry(kernel, static: tuple):
+    """The thin outer program of ``kernel`` for a packed call:
+    ``(capacity, used0, words, layout, <the statics>)``. It unpacks and
+    calls the kernel as it is (a nested ``traced_jit`` call inlines), so
+    the kernel keeps its signature, and the device program is named
+    ``<kernel>_packed``: whoever looks for the kernel's name finds it.
+    One entry per kernel object, made at its first packed call."""
+
+    def entry(capacity, used0, words, layout, **statics):
+        return kernel(
+            capacity, used0, **_unpack_operands(words, layout), **statics
+        )
+
+    entry.__name__ = entry.__qualname__ = f"{kernel.__name__}_packed"
+    entry.__module__ = kernel.__module__
+    entry.__signature__ = inspect.Signature(
+        [
+            inspect.Parameter(n, inspect.Parameter.POSITIONAL_OR_KEYWORD)
+            for n in ("capacity", "used0", "words", "layout", *static)
+        ]
+    )
+    return traced_jit(
+        entry,
+        retrace_budget=RETRACE_BUDGET,
+        static_argnames=("layout", *static),
+    )
 
 
 @dataclass
@@ -1272,27 +1357,46 @@ class PlacementKernel:
         mp = cfg.n_node_shards
         return mp if mp > 1 and pn % mp == 0 else 1
 
-    def _upload(self, cluster, used0, batch, jitter, cfg):
-        """One kernel call's host→device transfers, as the stage
-        ``place.upload``: capacity, usage, the batch, the tie-break
-        jitter and the algorithm flag."""
-        with _tracer.span("place.upload"):
-            return (
-                self._capacity_dev(cluster, cfg),
-                used_device(cluster, used0, cfg),
-                _device_batch(batch, cfg),
-                None
-                if jitter is None
-                else shard_put(jitter, ("nodes",), cfg),
-                jnp.asarray(self.algorithm_spread),
-            )
+    def _call(self, kernel, cluster, used0, batch, jitter, **statics):
+        """One kernel call with its operands handed to the device, as
+        the stage ``place.upload`` and the dispatch after it. Capacity
+        is the cache generation's resident buffer (an upload of its own
+        only for hand-built tensors), usage goes through its seam
+        ``used_device``, and everything else that is new each call —
+        the batch, the tie-break jitter, the algorithm flag — goes up
+        as one packed buffer that the kernel's outer program unpacks;
+        under an active mesh array by array, each with its
+        PartitionSpec, into the kernel itself. The span's tags
+        ``transfers`` and ``bytes`` say what it handed over."""
+        cfg = self.mesh_cfg()
+        batch = dict(batch, algorithm_spread=np.asarray(self.algorithm_spread))
+        if jitter is not None:
+            batch["jitter"] = jitter
+        with _tracer.span("place.upload") as sp:
+            before = transfer_totals()
+            capacity = self._capacity_dev(cluster, cfg)
+            used = used_device(cluster, used0, cfg)
+            if cfg.active:
+                operands = _device_batch(batch, cfg)
+            else:
+                layout, words = _pack_operands(batch)
+                words = host_put(words)
+            if sp is not None:
+                after = transfer_totals()
+                sp.tags["transfers"] = after[0] - before[0]
+                sp.tags["bytes"] = after[1] - before[1]
+        if cfg.active:
+            return kernel(capacity, used, **operands, **statics)
+        return _packed_entry(kernel, tuple(sorted(statics)))(
+            capacity, used, words, layout=layout, **statics
+        )
 
     @staticmethod
     def _capacity_dev(cluster, cfg):
-        """The DeviceStateCache's per-shard-refreshed capacity buffer
-        when one rode along on the tensors; else upload via the seam."""
+        """The DeviceStateCache's resident capacity buffer when one rode
+        along on the tensors; else upload via the seam."""
         dev = getattr(cluster, "device_capacity", None)
-        if dev is not None and cfg.active:
+        if dev is not None:
             return dev
         return shard_put(cluster.capacity, ("nodes",), cfg)
 
@@ -1494,19 +1598,9 @@ class PlacementKernel:
             real_n = len(asks)
             asks = _pad_group_axis(asks, pn)
             batch = _shared_batch(asks, pn)
-        cfg = self.mesh_cfg()
-        capacity, used, dev_batch, jitter_dev, spread = self._upload(
-            cluster, used0, batch, jitter, cfg
-        )
-        packed = place_closed_form_kernel(
-            capacity,
-            used,
-            **dev_batch,
-            algorithm_spread=spread,
-            max_j=max_j,
-            k=k,
-            jitter=jitter_dev,
-            n_shards=self._n_shards(pn),
+        packed = self._call(
+            place_closed_form_kernel, cluster, used0, batch, jitter,
+            max_j=max_j, k=k, n_shards=self._n_shards(pn),
         )
         with _tracer.span("place.pull"):
             fused = np.array(packed)
@@ -1548,18 +1642,9 @@ class PlacementKernel:
                 np.array([a.count for a in asks]) > 0, batch["counts"], 0
             ).astype(np.int32)
             batch.update(pad_value_blocks([a.blocks for a in asks], pn))
-        cfg = self.mesh_cfg()
-        capacity, used, dev_batch, jitter_dev, spread = self._upload(
-            cluster, used0, batch, jitter, cfg
-        )
-        choices, scores = place_value_scan_kernel(
-            capacity,
-            used,
-            **dev_batch,
-            algorithm_spread=spread,
-            max_j=max_j,
-            max_steps=max_steps,
-            jitter=jitter_dev,
+        choices, scores = self._call(
+            place_value_scan_kernel, cluster, used0, batch, jitter,
+            max_j=max_j, max_steps=max_steps,
         )
         return self._unpack_coupled(choices, scores, asks[:real_n], overflow)
 
@@ -1593,19 +1678,9 @@ class PlacementKernel:
                 np.array([a.count for a in asks]) > 0, batch["counts"], 0
             ).astype(np.int32)
             batch.update(pad_value_blocks([a.blocks for a in asks], pn))
-        cfg = self.mesh_cfg()
-        capacity, used, dev_batch, jitter_dev, spread = self._upload(
-            cluster, used0, batch, jitter, cfg
-        )
-        choices, scores = place_spread_chunked_kernel(
-            capacity,
-            used,
-            **dev_batch,
-            algorithm_spread=spread,
-            max_j=max_j,
-            chunk=CHUNK,
-            n_chunks=n_chunks,
-            jitter=jitter_dev,
+        choices, scores = self._call(
+            place_spread_chunked_kernel, cluster, used0, batch, jitter,
+            max_j=max_j, chunk=CHUNK, n_chunks=n_chunks,
             n_shards=self._n_shards(pn),
         )
         return self._unpack_coupled(choices, scores, asks[:real_n], overflow)
@@ -1675,20 +1750,10 @@ class PlacementKernel:
             batch["counts"] = np.where(
                 np.array([a.count for a in asks]) > 0, batch["counts"], 0
             ).astype(np.int32)
-        cfg = self.mesh_cfg()
-        capacity, used, dev_batch, jitter_dev, spread = self._upload(
-            cluster, used0, batch, jitter, cfg
-        )
-        choices, scores = place_spread_opv_kernel(
-            capacity,
-            used,
-            **dev_batch,
-            enforce_idx=jnp.asarray(enforce_idx),
-            algorithm_spread=spread,
-            max_j=max_j,
-            k_seg=k_seg,
-            n_chunks=n_chunks,
-            jitter=jitter_dev,
+            batch["enforce_idx"] = enforce_idx
+        choices, scores = self._call(
+            place_spread_opv_kernel, cluster, used0, batch, jitter,
+            max_j=max_j, k_seg=k_seg, n_chunks=n_chunks,
         )
         return self._unpack_coupled(choices, scores, asks[:real_n], overflow)
 

@@ -152,17 +152,26 @@ class KernelEntry:
 _kernel_registry: dict[str, KernelEntry] = {}
 
 
+def _is_static(a) -> bool:
+    """A plain Python value a static argument can be rebuilt from;
+    tuples of such values (an operand layout) included."""
+    if isinstance(a, tuple):
+        return all(_is_static(v) for v in a)
+    return a is None or isinstance(a, (bool, int, float, str))
+
+
 def _arg_spec(a):
     """Abstract spec of one kernel argument, built at trace time.
 
     Dynamic args are tracers -> ("aval", shape, dtype, weak_type);
-    static args are plain Python values -> ("static", value); anything
+    static args are plain Python values or tuples of them ->
+    ("static", value); anything
     the analyzer cannot reconstruct -> ("opaque", type name)."""
     aval = getattr(a, "aval", None)
     if aval is not None and hasattr(aval, "shape"):
         return ("aval", tuple(int(d) for d in aval.shape),
                 str(aval.dtype), bool(getattr(aval, "weak_type", False)))
-    if a is None or isinstance(a, (bool, int, float, str)):
+    if _is_static(a):
         return ("static", a)
     if hasattr(a, "shape") and hasattr(a, "dtype"):  # concrete array
         return ("aval", tuple(int(d) for d in a.shape),
@@ -306,6 +315,9 @@ def reset_trace_counts() -> None:
             _trace_counts[k] = 0
 
 
+_reference_tls = threading.local()  # .active: inside a _reference_call
+
+
 def traced_jit(fn=None, *, trace_name=None, retrace_budget=None, **jit_kwargs):
     """Drop-in ``jax.jit`` replacement that counts traces per callable and
     (optionally) declares a retrace budget for the analysis checker::
@@ -377,11 +389,19 @@ def traced_jit(fn=None, *, trace_name=None, retrace_budget=None, **jit_kwargs):
 
         args = tuple(_host(a) for a in args)
         kwargs = {k: _host(v) for k, v in kwargs.items()}
-        if cpu is not None:
-            with jax.default_device(cpu):
+        # a kernel this body calls runs its own body here too: its
+        # breaker may be closed, and its dispatch would leave for the
+        # watchdog's thread, where the CPU pin below does not hold
+        outer = getattr(_reference_tls, "active", False)
+        _reference_tls.active = True
+        try:
+            if cpu is not None:
+                with jax.default_device(cpu):
+                    out = fn(*args, **kwargs)
+            else:
                 out = fn(*args, **kwargs)
-        else:
-            out = fn(*args, **kwargs)
+        finally:
+            _reference_tls.active = outer
         global_metrics.incr("nomad.resilience.fallback_calls")
         global_metrics.measure(
             f"nomad.kernel.{short}.fallback", time.perf_counter() - t0
@@ -404,6 +424,8 @@ def traced_jit(fn=None, *, trace_name=None, retrace_budget=None, **jit_kwargs):
             for leaf in jax.tree_util.tree_leaves((args, kwargs))
         ):
             return jitted(*args, **kwargs)
+        if getattr(_reference_tls, "active", False):
+            return fn(*args, **kwargs)  # inside an outer reference call
         br = breaker_for(name)
         if not br.allow():
             return _reference_call(args, kwargs)
@@ -613,6 +635,38 @@ def shard_drops() -> dict[str, int]:
         return dict(_shard_drops)
 
 
+_transfer_tls = threading.local()  # .count, .bytes: this thread's hand-offs
+
+
+def _note_transfer(x) -> None:
+    _transfer_tls.count = getattr(_transfer_tls, "count", 0) + 1
+    _transfer_tls.bytes = getattr(_transfer_tls, "bytes", 0) + int(
+        getattr(x, "nbytes", 0)
+    )
+
+
+def transfer_totals() -> tuple[int, int]:
+    """``(hand-offs, bytes)`` this thread has issued through the seam
+    (``shard_put``, ``host_put``) so far: a span that wants to say how
+    many it held reads the pair before and after."""
+    return (
+        getattr(_transfer_tls, "count", 0),
+        getattr(_transfer_tls, "bytes", 0),
+    )
+
+
+def host_put(x):
+    """One host buffer to the default device in one hand-off: the packed
+    operands of a kernel call where no mesh is active (a packed buffer
+    carries no PartitionSpec; under a mesh the operands go through
+    ``shard_put`` one by one). The buffer must be the caller's to give
+    away: on the CPU backend the device array may alias it."""
+    import jax
+
+    _note_transfer(x)
+    return jax.device_put(x)
+
+
 def shard_put(x, axes, cfg: "MeshConfig | None" = None):
     """Place ``x`` on the mesh with PartitionSpec(*axes); the sanctioned
     device_put seam. ``axes`` entries are "groups"/"nodes"/None, one per
@@ -625,6 +679,7 @@ def shard_put(x, axes, cfg: "MeshConfig | None" = None):
 
     if cfg is None:
         cfg = get_mesh()
+    _note_transfer(x)
     if not cfg.active:
         return jnp.asarray(x)
     shape = getattr(x, "shape", None)
