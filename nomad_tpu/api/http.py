@@ -1695,8 +1695,11 @@ class HTTPAgent:
 
     def handle_agent_trace(self, method, body, query, eval_id=None):
         """/v1/agent/trace[/{eval_id}] — flight-recorder dump: recent
-        completed eval traces (summaries), last-N error events, and the
-        per-kernel jit profile; with an eval id, the full span tree."""
+        completed eval traces (summaries), the newest background spans
+        (work that belongs to no eval; ``?background=<name>`` keeps one
+        name: ``drain`` is a whole node drain with where its time went),
+        last-N error events, and the per-kernel jit profile; with an
+        eval id, the full span tree."""
         self._enforce(query, "agent_read")
         from ..obs.recorder import flight_recorder
 
@@ -1723,8 +1726,15 @@ class HTTPAgent:
 
             count_swallowed("http", e)
             fingerprints = {}
+        n = int(query.get("n", 50))
+        named = query.get("background")
+        background = [
+            s for s in reversed(flight_recorder.background())
+            if not named or s["name"] == named
+        ]
         return {
-            "traces": flight_recorder.list(int(query.get("n", 50))),
+            "traces": flight_recorder.list(n),
+            "background": background[: max(0, n)],
             "errors": flight_recorder.errors(),
             "kernels": kernel_profile(),
             "kernel_fingerprints": fingerprints,

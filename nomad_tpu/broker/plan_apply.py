@@ -562,7 +562,6 @@ class PlanApplier:
                 note_committed(fresh)
                 # commit-train accounting: one FSM apply, one plan landed
                 metrics.incr("nomad.plan.commits")
-                metrics.incr("nomad.plan.committed_plans")
                 _count_committed((result,))
                 result.alloc_index = index
                 if evals and self.on_evals_created is not None:
@@ -610,9 +609,6 @@ class PlanApplier:
                 self._check_lane_rejections(mplan, results)
             evaluate_s = time.perf_counter() - t_evaluate
             metrics.measure("nomad.plan.evaluate", evaluate_s)
-            # merged-only sample so the bench can report the batched
-            # verify latency separately from single-plan evaluates
-            metrics.measure("nomad.plan.verify_batch", evaluate_s)
             commit_members = [
                 (mp.eval_id, res)
                 for mp, res in zip(mplan.plans, results)
@@ -656,9 +652,6 @@ class PlanApplier:
                             self.store.latest_index + 1, evals
                         )
                 metrics.incr("nomad.plan.commits")
-                metrics.incr(
-                    "nomad.plan.committed_plans", len(commit_members)
-                )
                 _count_committed(committed)
                 metrics.incr("nomad.plan.merged_commits")
                 metrics.incr(

@@ -802,8 +802,11 @@ def cmd_operator_metrics(args) -> int:
 
 def cmd_trace(args) -> int:
     """`nomad-tpu trace [eval_id]` — flight-recorder view. Without an
-    id: recent completed traces + last error events. With one: the full
-    span tree rendered as an indented duration breakdown."""
+    id: recent completed traces, the node drains that ended lately (each
+    from its command's commit to the node empty, split among who it
+    waited for: the scheduler, the clients, the drainer) + last error
+    events. With one: the full span tree rendered as an indented duration
+    breakdown."""
     c = _client(args)
     if args.eval_id:
         try:
@@ -817,7 +820,9 @@ def cmd_trace(args) -> int:
 
             print(render_trace(tr))
         return 0
-    out = c._request("GET", "/v1/agent/trace")
+    out = c._request(
+        "GET", "/v1/agent/trace", params={"background": "drain"}
+    )
     if args.json:
         print(json.dumps(out, indent=2))
         return 0
@@ -830,6 +835,21 @@ def cmd_trace(args) -> int:
             f"{t['duration_ms']:>9.2f}ms  {t['spans']:>3} spans  "
             + ",".join(f"{k}={v}" for k, v in sorted(t["tags"].items()))
         )
+    drains = [s for s in out.get("background", []) if s["name"] == "drain"]
+    if drains:
+        print(f"\n{len(drains)} recent node drain(s):")
+        for s in drains:
+            tags = s["tags"]
+            print(
+                f"  {tags['node_id']}  {s['duration_ms']:>9.2f}ms  "
+                f"scheduler={tags['sched_ms']:.2f}ms "
+                f"clients={tags['client_ms']:.2f}ms "
+                f"drainer={tags['drainer_ms']:.2f}ms  "
+                + ",".join(
+                    f"{k}={tags[k]}" for k in
+                    ("allocs", "waves", "evals", "migrated", "deadlined")
+                )
+            )
     errors = out.get("errors", [])
     if errors:
         print(f"\n{len(errors)} recent error event(s):")

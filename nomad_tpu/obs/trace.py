@@ -24,7 +24,10 @@ cannot carry a trace end to end. The model here:
   nothing is counted back from the moment of the call.
 - ``background(name)`` times work that belongs to no eval (the
   deployment watcher's tick, the clients' alloc sync) as a lone span in
-  the recorder's background ring, beside the traces and not among them.
+  the recorder's background ring, beside the traces and not among them;
+  ``add_background`` records one retroactively, as ``add_span`` does: an
+  operation that spans many evals and many commits (a node's drain),
+  handed over whole where it ends.
 - ``phase(name)`` opens a top-level phase of a scheduling pass: a span
   that carries the pass tags (``pass_id``, ``path``, ``evals``) the
   worker put on the trace's root, so every phase of every member of one
@@ -372,6 +375,24 @@ class Tracer:
             if self.recorder is not None:
                 self.recorder.record_background(sp.to_dict())
 
+    def add_background(
+        self,
+        name: str,
+        duration_s: float,
+        *,
+        start: float,
+        tags: Optional[dict] = None,
+    ) -> None:
+        """Record an already-measured interval that belongs to no eval
+        into the background ring: ``start`` is the ``perf_counter`` stamp
+        at which it began."""
+        if not self._enabled:
+            return
+        sp = self._span(f"background:{name}", name, None, tags, start)
+        sp.duration_ms = duration_s * 1000.0
+        if self.recorder is not None:
+            self.recorder.record_background(sp.to_dict())
+
     def _open(self, name, parent, tags, t0) -> Optional[Span]:
         if not self._enabled:
             return None
@@ -419,6 +440,14 @@ class Tracer:
         sp.duration_ms = duration_s * 1000.0
         tr.spans.append(sp)
         return sp
+
+    def newest(self, trace_id: str, name: str) -> Optional[Span]:
+        """The last span called ``name`` written into an open trace."""
+        tr = self._active.get(trace_id)
+        for sp in reversed(tr.spans if tr is not None else ()):
+            if sp.name == name:
+                return sp
+        return None
 
     def record_kernel(
         self,

@@ -28,6 +28,12 @@ that stopped allocations on a draining node, which may have emptied it)
 and then looks at the draining nodes only, whose ids it keeps. With no
 wake for ``interval`` it walks every node as before: the fallback that
 finds a drain nobody told it about.
+
+A drain is one operation to the operator who ordered it, and many evals
+and commits to the program. The drainer keeps a record of each drain it
+was told of (``_DrainRecord``) and hands it to the tracer where the drain
+ends: the background span ``drain``, from the commit of the strategy to
+the commit that cleared it, its time split among who it waited for.
 """
 
 from __future__ import annotations
@@ -46,6 +52,39 @@ from ..utils.metrics import global_metrics as metrics
 from .fsm import MsgType
 
 log = logging.getLogger("nomad_tpu.drainer")
+
+
+class _DrainRecord:
+    """One drain under way and where its time has gone so far. At any
+    moment the drain waits for one of three, and the drainer, which looks
+    at the node after every commit that can change that, keeps the time:
+
+    ``sched``    an allocation of the node is marked to migrate and not
+                 yet stopped: its eval queues, runs, commits;
+    ``client``   nothing is marked and unstopped, allocations remain and
+                 no group's budget lets one be marked: a replacement has
+                 to turn healthy on its client first;
+    ``drainer``  the rest: from the commit that gave the drainer its turn
+                 (the strategy's, the one that freed a budget, the last
+                 stop) over its wake and its look to the commit of its
+                 own answer (the transition message, the strategy cleared).
+
+    A state lasts from ``turn`` to ``turn``; the three sum to the time
+    from ``start`` to the last turn."""
+
+    __slots__ = ("start", "at", "state", "spent", "allocs", "waves",
+                 "evals", "migrated")
+
+    def __init__(self, start: float):
+        self.start = self.at = start  # ``perf_counter`` stamps
+        self.state = "drainer"
+        self.spent = {"sched": 0.0, "client": 0.0, "drainer": 0.0}
+        self.allocs: Optional[int] = None  # held at the first look
+        self.waves = self.evals = self.migrated = 0
+
+    def turn(self, at: float, state: str) -> None:
+        self.spent[self.state] += at - self.at
+        self.at, self.state = at, state
 
 
 class NodeDrainer:
@@ -68,6 +107,13 @@ class NodeDrainer:
         # update of a job no eval has answered yet ((namespace, job id))
         self._drain_at: dict[str, float] = {}
         self._freed_at: dict[tuple[str, str], float] = {}
+        # node id -> the record of its drain, from ``note_drain`` to
+        # ``_complete``; a drain found by a walk or inherited by a new
+        # leader has none and writes no span
+        self._drains: dict[str, _DrainRecord] = {}
+        # stamp of the oldest commit noted since the last scan began:
+        # where a state the next look finds changed, it changed there
+        self._noted_at: Optional[float] = None
 
     def start(self) -> None:
         self._stop.clear()
@@ -100,9 +146,11 @@ class NodeDrainer:
                 log.exception("drainer scan failed")
 
     # -- the commits that give it work --------------------------------------
-    def _wake_for(self, reason: str) -> None:
+    def _wake_for(self, reason: str, at: float) -> None:
         with self._lock:
             self._woken_by.add(reason)
+            if self._noted_at is None:
+                self._noted_at = at
         self._wake.set()
 
     def note_drain(self, applied_at: float, node_id: str, drain) -> None:
@@ -111,12 +159,15 @@ class NodeDrainer:
             if drain is not None:
                 self._draining.add(node_id)
                 self._drain_at[node_id] = applied_at
+                if node_id not in self._drains:  # not a new deadline
+                    self._drains[node_id] = _DrainRecord(applied_at)
             else:
                 self._draining.discard(node_id)
                 self._drain_at.pop(node_id, None)
+                self._drains.pop(node_id, None)  # cancelled: no span
         if drain is not None:
             metrics.incr("nomad.drain.started")
-            self._wake_for("node_drain")
+            self._wake_for("node_drain", applied_at)
 
     def note_client_update(self, applied_at: float, updates) -> None:
         """The clients' alloc sync committed at ``applied_at``: a
@@ -130,7 +181,7 @@ class NodeDrainer:
                     self._freed_at.setdefault(
                         (u.namespace, u.job_id), applied_at
                     )
-        self._wake_for("client_update")
+        self._wake_for("client_update", applied_at)
 
     def note_health(self, applied_at: float, namespace: str,
                     job_id: str) -> None:
@@ -140,7 +191,7 @@ class NodeDrainer:
             if not self._draining:
                 return
             self._freed_at.setdefault((namespace, job_id), applied_at)
-        self._wake_for("health")
+        self._wake_for("health", applied_at)
 
     def note_stops(self, node_ids) -> None:
         """A plan's stops landed on these nodes: a draining one among
@@ -148,7 +199,7 @@ class NodeDrainer:
         with self._lock:
             hit = not self._draining.isdisjoint(node_ids)
         if hit:
-            self._wake_for("plan_stops")
+            self._wake_for("plan_stops", time.perf_counter())
 
     # -- one pass ----------------------------------------------------------
     def scan(self, full: bool = True) -> None:
@@ -160,6 +211,7 @@ class NodeDrainer:
             with self._lock:
                 woken_by = ",".join(sorted(self._woken_by)) or "interval"
                 self._woken_by.clear()
+                noted_at, self._noted_at = self._noted_at, None
                 ids = sorted(self._draining)
             if full:
                 nodes = store.nodes()
@@ -176,13 +228,15 @@ class NodeDrainer:
                 ]
                 if len(draining) < len(ids):  # cancelled, or gone
                     with self._lock:
-                        self._draining -= set(ids) - {n.id for n in draining}
+                        for gone in set(ids) - {n.id for n in draining}:
+                            self._draining.discard(gone)
+                            self._drains.pop(gone, None)
             seen = {
                 "draining": len(draining), "walked": walked, "marked": 0,
                 "evals": 0, "completed": 0, "woken_by": woken_by,
             }
             for node in draining:
-                self._drain_node(node, seen)
+                self._drain_node(node, seen, noted_at)
             if sp is not None:
                 sp.tags.update(seen)
         if not draining:
@@ -201,15 +255,27 @@ class NodeDrainer:
             return True
         return a.client_status == "running"
 
-    def _drain_node(self, node, seen: dict) -> None:
+    def _drain_node(self, node, seen: dict,
+                    noted_at: Optional[float] = None) -> None:
         store = self.server.store
         drain = node.drain
         now = time.time()
         deadlined = 0 < drain.force_deadline_unix <= now or drain.deadline_s < 0
+        rec = self._drains.get(node.id)
+        t_look = time.perf_counter()
+
+        def found(state: str) -> None:
+            # what this look finds the drain waiting for; where that is
+            # news, it has been so since the commit that woke the drainer
+            if rec is not None and state != rec.state:
+                turned = t_look if noted_at is None else noted_at
+                rec.turn(min(max(turned, rec.at), t_look), state)
 
         allocs = [
             a for a in store.allocs_by_node(node.id) if not a.terminal_status()
         ]
+        if rec is not None and rec.allocs is None:
+            rec.allocs = len(allocs)
         system, normal = [], []
         for a in allocs:
             job = store.job_by_id(a.namespace, a.job_id)
@@ -226,7 +292,8 @@ class NodeDrainer:
                 remaining += system
 
         if not remaining:
-            self._complete(node, deadlined)
+            found("drainer")
+            self._complete(node, deadlined, rec)
             seen["completed"] += 1
             return
 
@@ -289,6 +356,10 @@ class NodeDrainer:
                     jobs_touched[(ns, job_id)] = job
                     num_to_mark -= 1
 
+        if any(a.desired_transition.migrate for a in allocs):
+            found("sched")
+        else:
+            found("drainer" if transitions else "client")
         if not transitions:
             return
         evals = [
@@ -308,6 +379,11 @@ class NodeDrainer:
             MsgType.ALLOC_DESIRED_TRANSITION,
             {"transitions": transitions, "evals": evals},
         )
+        if rec is not None:
+            rec.turn(time.perf_counter(), "sched")
+            rec.waves += 1
+            rec.migrated += len(transitions)
+            rec.evals += len(evals)
         metrics.incr("nomad.drain.waves")
         seen["marked"] += len(transitions)
         if evals:
@@ -336,9 +412,11 @@ class NodeDrainer:
                     }
         return tags
 
-    def _complete(self, node, deadlined: bool) -> None:
+    def _complete(self, node, deadlined: bool,
+                  rec: Optional[_DrainRecord] = None) -> None:
         """Drain finished: clear the strategy, stay ineligible
-        (drainer.go handleDoneNodeDrains → Node.UpdateDrain with nil)."""
+        (drainer.go handleDoneNodeDrains → Node.UpdateDrain with nil),
+        and hand the tracer the operation whole."""
         from ..structs import NODE_SCHED_INELIGIBLE
 
         self.server.raft_apply(
@@ -346,7 +424,23 @@ class NodeDrainer:
             {"node_id": node.id, "drain": None,
              "eligibility": NODE_SCHED_INELIGIBLE},
         )
-        self.note_drain(time.perf_counter(), node.id, None)
+        cleared_at = time.perf_counter()
+        with self._lock:
+            # a drain cancelled while this look was under way is gone
+            ended = self._drains.pop(node.id, None)
+        self.note_drain(cleared_at, node.id, None)
+        if rec is not None and ended is rec:
+            rec.turn(cleared_at, "ended")
+            tracer.add_background(
+                "drain", cleared_at - rec.start, start=rec.start,
+                tags={
+                    "node_id": node.id, "allocs": rec.allocs,
+                    "waves": rec.waves, "evals": rec.evals,
+                    "migrated": rec.migrated, "deadlined": deadlined,
+                    **{f"{k}_ms": round(v * 1000.0, 4)
+                       for k, v in rec.spent.items()},
+                },
+            )
         metrics.incr("nomad.drain.completed")
         self.server._publish(
             "Node",

@@ -42,6 +42,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs.trace import global_tracer as tracer
+from ..utils.metrics import global_metrics
 
 SCORING_WAIT_S = 30.0  # longest a pass waits for another's read-then-write
 
@@ -97,13 +99,18 @@ class SharedOverlay:
         add_delta freezes the base. Pair with pass_finished(). Waits
         for a pass that is between its read and its write (bounded: a
         pass stuck in a kernel must not wedge the others; the applier
-        stays the authority)."""
+        stays the authority): the phase ``overlay.wait`` of the calling
+        pass, between its ``prepare`` and its ``invoke_scheduler``."""
         if self._scoring_owner != threading.get_ident():
-            if self._scoring.acquire(timeout=SCORING_WAIT_S):
+            with tracer.phase(
+                "overlay.wait", tags={"waited": self._scoring.locked()}
+            ) as sp:
+                got = self._scoring.acquire(timeout=SCORING_WAIT_S)
+                if sp is not None:
+                    sp.tags["timed_out"] = not got
+            if got:
                 self._scoring_owner = threading.get_ident()
             else:
-                from ..utils.metrics import global_metrics
-
                 global_metrics.incr("nomad.overlay.scoring_wait_timeouts")
         with self._lock:
             self._passes += 1
@@ -140,8 +147,6 @@ class SharedOverlay:
                 and writer is not None
                 and writer != self.owner
             ):
-                from ..utils.metrics import global_metrics
-
                 global_metrics.incr("nomad.overlay.cross_lane_writes")
                 return
             if self._base is None:

@@ -696,7 +696,9 @@ class Server:
             if self._turned_eligible(before, node_id):
                 evals = self._create_node_evals(node_id, entered_at=t_entry)
             if sp is not None:
-                sp.tags.update(allocs=len(live), node_evals=len(evals))
+                sp.tags.update(
+                    node_id=node_id, allocs=len(live), node_evals=len(evals)
+                )
         return evals
 
     def update_node_eligibility(

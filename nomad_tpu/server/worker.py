@@ -83,11 +83,16 @@ class _PassTrace:
     once, there; ``phase`` then copies the phase itself, same start and
     duration, into the other members' trees — an operator reads one
     eval's tree. A phase several members share is tagged ``shared``, and
-    a copy also ``leader_eval``."""
+    a copy also ``leader_eval``. A member the pass sets aside for a solo
+    pass of its own (``set_aside``) waits from then on for the commit
+    thread to reach it, behind the pass's commit and the members set
+    aside before it: ``solo_wait`` in its trace."""
 
     def __init__(self, worker_id: int, seq: int, path: str, evals: list):
         self.leader = evals[0].id
         self.members = [ev.id for ev in evals]
+        # eval id -> (perf_counter stamp, reason) of the members set aside
+        self.aside: dict[str, tuple[float, str]] = {}
         self.tags = {
             "pass_id": f"{worker_id}-{seq}",
             "path": path,
@@ -118,10 +123,41 @@ class _PassTrace:
             else:  # the leader's trace is gone, or tracing is off
                 copy = {**self.tags, **(tags or {})}
                 dt = time.perf_counter() - t0
-            copy = {**copy, "leader_eval": self.leader}
-            for eid in self.members:
-                if eid != self.leader:
-                    tracer.add_span(eid, name, dt, start=t0, tags=copy)
+            self._copy(name, t0, dt, copy)
+
+    def _copy(self, name: str, t0: float, dt: float, tags: dict) -> None:
+        tags = {**tags, "leader_eval": self.leader}
+        for eid in self.members:
+            if eid != self.leader:
+                tracer.add_span(eid, name, dt, start=t0, tags=tags)
+
+    def share(self, name: str) -> None:
+        """Copy the phase ``name`` that the code below the pass has just
+        written into the leader's trace (the overlay's own wait) into the
+        other members', as ``phase`` would have."""
+        sp = tracer.newest(self.leader, name)
+        if sp is not None and len(self.members) > 1:
+            sp.tags["shared"] = True
+            self._copy(name, sp.t0, sp.duration_ms / 1000.0, sp.tags)
+
+    def set_aside(self, singles: list, ev, token, reason: str) -> None:
+        """The member leaves this pass for the solo path. ``reason`` is
+        one word a site: ``not_batchable`` (no service or batch eval),
+        ``nothing_to_batch`` (its prepare returned no asks: stops, or
+        nothing to place), ``prepare_error``, ``kernel_fallback``,
+        ``conflict_fallback``, ``handoff_fallback`` (lane mode),
+        ``commit_fallback``."""
+        self.aside[ev.id] = (time.perf_counter(), reason)
+        singles.append((ev, token))
+
+    def solo_wait(self, eval_id: str, ahead: int) -> None:
+        """Set aside -> now, the entry of the member's own solo pass,
+        ``ahead`` members of the same commit having run before it."""
+        t0, reason = self.aside[eval_id]
+        tracer.add_span(
+            eval_id, "solo_wait", time.perf_counter() - t0, start=t0,
+            tags={**self.tags, "reason": reason, "ahead": ahead},
+        )
 
 
 class _EvalBuffer:
@@ -403,6 +439,7 @@ class Worker:
                         "priority": ev.priority,
                         "worker": self.id,
                         "batch_size": len(batch),
+                        **({"node_id": ev.node_id} if ev.node_id else {}),
                         **((stay.trace_tags if stay else None) or {}),
                     },
                 )
@@ -565,7 +602,7 @@ class Worker:
         singles: list[tuple[Evaluation, str]] = []
         for ev, token in batch:
             if ev.type not in ("service", "batch"):
-                singles.append((ev, token))
+                ps.set_aside(singles, ev, token, "not_batchable")
                 continue
             sched = new_scheduler(
                 ev.type,
@@ -581,11 +618,10 @@ class Worker:
             except Exception as e:
                 log.exception("worker %d: batch prepare %s", self.id, ev.id)
                 count_swallowed("worker", e)
-                asks = None
-                singles.append((ev, token))
+                ps.set_aside(singles, ev, token, "prepare_error")
                 continue
             if asks is None:
-                singles.append((ev, token))
+                ps.set_aside(singles, ev, token, "nothing_to_batch")
             else:
                 assert sched._batch_ctx[0] is ct
                 lane_groups.extend([len(prepared)] * len(asks))
@@ -618,6 +654,7 @@ class Worker:
             # this overlay is the worker's own; peers' in-flight state
             # is irrelevant by construction (disjoint lanes + claims).
             used_override = overlay.begin_pass(ct)
+            ps.share("overlay.wait")
             try:
                 kernel = prepared[0][2].kernel
                 # all scheds in a batch share one scheduler config, so
@@ -688,7 +725,8 @@ class Worker:
                 log.exception("worker %d: combined kernel pass", self.id)
                 count_swallowed("worker", e)
                 metrics.incr("nomad.worker.batch_kernel_errors")
-                singles.extend((ev, token) for ev, token, _, _ in prepared)
+                for ev, token, _, _ in prepared:
+                    ps.set_aside(singles, ev, token, "kernel_fallback")
                 prepared = []
                 results = None
             finally:
@@ -870,7 +908,7 @@ class Worker:
                 if not span_ok:
                     metrics.incr("nomad.worker.batch_conflict_fallbacks")
                     metrics.incr("nomad.worker.batch_repair_fallbacks")
-                    singles.append((ev, token))
+                    ps.set_aside(singles, ev, token, "conflict_fallback")
                     continue
                 sched.planner.buffer = buf
                 try:
@@ -925,7 +963,7 @@ class Worker:
                             continue
                         server.lane_claims.release(claim, committed=False)
                     metrics.incr("nomad.worker.lane_handoff_fallbacks")
-                    singles.append((ev, token))
+                    ps.set_aside(singles, ev, token, "handoff_fallback")
                 members = kept
 
             # 2. followup evals must exist BEFORE the plans that reference
@@ -1016,7 +1054,7 @@ class Worker:
                     else:
                         metrics.incr("nomad.worker.batch_conflict_fallbacks")
                         metrics.incr("nomad.worker.batch_commit_fallbacks")
-                        singles.append((ev, token))
+                        ps.set_aside(singles, ev, token, "commit_fallback")
 
             # 6. land every member's finalize-time status (and blocked
             # eval creates) in one raft apply, then ack — status must be
@@ -1031,8 +1069,9 @@ class Worker:
                 metrics.incr("nomad.worker.evals_processed")
                 tracer.finish(ev.id, status="acked")
 
-            for ev, token in singles:
+            for ahead, (ev, token) in enumerate(singles):
                 metrics.incr("nomad.worker.batch_single_fallbacks")
+                ps.solo_wait(ev.id, ahead)
                 self._run_one(ev, token)
         except Exception as e:
             # the commit thread must never die with evals unacked —

@@ -32,6 +32,12 @@ NEW_METRICS = (
     "reconcile_ms_p50", "plan_stops_ms_p50", "client_update_ms_p50",
     "plan_stops_committed",
 )
+# the drain as one operation, and the waits of its evals (PR 37)
+DRAIN_RECORD_METRICS = (
+    "drain_ms_p50", "drain_sched_ms_p50", "drain_client_wait_ms_p50",
+    "drain_drainer_ms_p50", "drain_evals_wait_ms_p50",
+    "drain_evals_busy_ms_p50",
+)
 
 
 def test_reference_imports_nothing_of_the_program():
@@ -67,6 +73,17 @@ def test_the_cell_rehearses_correct_through_run_main():
     # every span and counter this deployment brings is read
     for name in NEW_METRICS:
         assert metrics[name]["value"] > 0, name
+    # a value each, 0 where the median drained node held nothing (the toy
+    # fleet's does); the three clocks of the median drain are no more
+    # than the longest drain
+    for name in DRAIN_RECORD_METRICS:
+        assert metrics[name]["value"] >= 0.0, name
+    assert metrics["drain_ms_p50"]["value"] > 0.0
+    for name in ("solo_wait_ms_p50", "overlay_wait_total_ms",
+                 "request_wait_ms_p50"):
+        assert metrics[name]["value"] > 0.0, name
+    assert metrics["request_wait_ms_p50"]["value"] >= (
+        metrics["broker_wait_p50_ms"]["value"])
     # the drainer woke on the commit: far under its 250 ms poll
     assert metrics["drain_wave_lag_ms_p50"]["value"] < 50.0
     for alarm in ("drain_force_stops", "attr_columns_rebuilt",

@@ -16,10 +16,14 @@ for p in (ROOT, os.path.join(ROOT, "benchmark", "tests")):
 
 import check_traces  # noqa: E402
 from benchmark.readers import (  # noqa: E402
+    drain_evals,
+    eval_wait,
     latency_untraced,
     pass_stage_sum,
     pass_wall,
+    span_sum,
 )
+from nomad_tpu.obs.recorder import flight_recorder  # noqa: E402
 from nomad_tpu.obs.trace import global_tracer  # noqa: E402
 
 PHASES = ["wait_for_index", "snapshot", "prepare", "invoke_scheduler",
@@ -231,7 +235,209 @@ class TestLatencyUntraced:
         assert latency_untraced.read(ctx, 0.5) is None
 
 
+def set_aside_pass():
+    """Pass b-7, a wave of two evals with stops, both set aside: the
+    leader's dequeue [0.198, 0.200), the pass's snapshot [0.200, 0.201),
+    prepares, the overlay's wait [0.2025, 0.2030) and join_commit
+    [0.203, 0.204) (the member holds copies), then on the commit thread
+    the leader's solo pass s-8 [0.2045, 0.2105) after a solo_wait from
+    0.2015 and the member's s-9 [0.211, 0.216) after one from 0.202; the
+    member's own overlay.wait [0.212, 0.2125)."""
+    p = {"pass_id": "b-7", "path": "batched", "evals": 2}
+    lead = trace("e-w1", [
+        span(30, None, "eval", 0.200, 11.0),
+        span(31, 30, "dequeue", 0.198, 2.0, queue_wait_ms=2.0),
+        span(32, 30, "snapshot", 0.200, 1.0, shared=True, **p),
+        span(33, 30, "prepare", 0.201, 0.5, **p),
+        span(34, 30, "solo_wait", 0.2015, 3.0, reason="nothing_to_batch",
+             ahead=0, **p),
+        span(35, 30, "overlay.wait", 0.2025, 0.5, shared=True, waited=True,
+             timed_out=False, **p),
+        span(36, 30, "join_commit", 0.203, 1.0, shared=True, **p),
+        span(37, 30, "prepare", 0.2045, 2.0, pass_id="s-8", path="solo",
+             evals=1),
+        span(38, 30, "submit_plan", 0.2065, 4.0, pass_id="s-8", path="solo",
+             evals=1),
+    ], triggered_by="node-drain", node_id="n-1", leader=True, **p)
+    member = trace("e-w2", [
+        span(40, None, "eval", 0.200, 16.5),
+        span(41, 40, "dequeue", 0.199, 1.0, queue_wait_ms=1.0),
+        span(42, 40, "snapshot", 0.200, 1.0, shared=True,
+             leader_eval="e-w1", **p),
+        span(43, 40, "prepare", 0.2015, 0.5, **p),
+        span(44, 40, "solo_wait", 0.202, 9.0, reason="nothing_to_batch",
+             ahead=1, **p),
+        span(45, 40, "overlay.wait", 0.2025, 0.5, shared=True, waited=True,
+             timed_out=False, leader_eval="e-w1", **p),
+        span(46, 40, "join_commit", 0.203, 1.0, shared=True,
+             leader_eval="e-w1", **p),
+        span(47, 40, "prepare", 0.211, 1.0, pass_id="s-9", path="solo",
+             evals=1),
+        span(48, 40, "overlay.wait", 0.212, 0.5, pass_id="s-9", path="solo",
+             evals=1, waited=False, timed_out=False),
+        span(49, 40, "submit_plan", 0.2125, 3.5, pass_id="s-9", path="solo",
+             evals=1),
+    ], triggered_by="node-drain", node_id="n-1", leader_eval="e-w1", **p)
+    return lead, member
+
+
+class TestSpanSum:
+    def test_every_span_of_the_name_counts_copies_too(self):
+        ctx = {"traces": list(set_aside_pass())}
+        # the leader's, the member's copy of it, the member's own
+        assert span_sum.read(ctx, "overlay.wait") == pytest.approx(1.5)
+        assert span_sum.read(ctx, "solo_wait") == pytest.approx(12.0)
+
+    def test_a_program_without_the_span_gives_nothing(self):
+        ctx = {"traces": [solo_pass(), *batched_pass()]}
+        assert span_sum.read(ctx, "overlay.wait") is None
+        assert span_sum.read({"traces": []}, "overlay.wait") is None
+
+
+class TestEvalWait:
+    KINDS = ["job-register", "node-drain"]
+
+    def test_the_union_of_an_evals_four_waits(self):
+        lead, member = set_aside_pass()
+        # dequeue 2 + solo_wait 3 (the overlay's wait and join_commit lie
+        # inside it, and count once)
+        assert eval_wait.read(
+            {"traces": [lead]}, 0.5, self.KINDS) == pytest.approx(5.0)
+        # dequeue 1 + solo_wait 9 (the copies inside it) + its own wait 0.5
+        assert eval_wait.read(
+            {"traces": [member]}, 0.5, self.KINDS) == pytest.approx(10.5)
+        assert eval_wait.read(
+            {"traces": [lead, member]}, 1.0, self.KINDS
+        ) == pytest.approx(10.5)
+
+    def test_a_members_copy_counts_for_the_member(self):
+        lead, member = set_aside_pass()
+        member["spans"] = [
+            s for s in member["spans"] if s["name"] != "solo_wait"]
+        # dequeue 1, the copies [0.2025, 0.2030) and [0.203, 0.204), its
+        # own wait 0.5
+        assert eval_wait.read(
+            {"traces": [lead, member]}, 1.0, self.KINDS
+        ) == pytest.approx(5.0)
+        assert eval_wait.read(
+            {"traces": [member]}, 0.5, self.KINDS) == pytest.approx(3.0)
+
+    def test_other_kinds_of_eval_are_left_out(self):
+        lead, member = set_aside_pass()
+        member["tags"]["triggered_by"] = "job-deregister"
+        ctx = {"traces": [lead, member]}
+        assert eval_wait.read(ctx, 1.0, self.KINDS) == pytest.approx(5.0)
+        assert eval_wait.read(ctx, 1.0, ["job-deregister"]) == (
+            pytest.approx(10.5))
+        assert eval_wait.read(ctx, 0.5, ["node-update"]) is None
+
+    def test_a_program_without_the_new_waits_gives_nothing(self):
+        old = [solo_pass(), *batched_pass()]
+        for t in old:
+            t["tags"]["triggered_by"] = "job-register"
+            t["spans"].append(span(99, t["spans"][0]["span_id"], "dequeue",
+                                   -0.001, 1.0, queue_wait_ms=1.0))
+        assert eval_wait.read({"traces": old}, 0.5, self.KINDS) is None
+
+
+class TestDrainEvals:
+    """``drain`` spans come from the recorder's background ring, on the
+    tracer's clock: the traces above are moved onto it."""
+
+    T = 9_000.0  # perf_counter stamp of the hand-built traces' T0
+
+    @pytest.fixture(autouse=True)
+    def _ring(self):
+        flight_recorder.clear()
+        yield
+        flight_recorder.clear()
+
+    def _shift(self, traces):
+        by = global_tracer.unix_at(self.T) - T0
+        for t in traces:
+            for s in t["spans"]:
+                s["start_unix"] += by
+        return traces
+
+    def _drain(self, node_id, start, dur_s):
+        global_tracer.add_background(
+            "drain", dur_s, start=self.T + start,
+            tags={"node_id": node_id, "sched_ms": 1.0})
+
+    def _ctx(self, traces):
+        return {"traces": self._shift(list(traces)),
+                "t_open": self.T, "t_close": self.T + 1.0}
+
+    def test_work_first_then_what_only_waits_cover(self):
+        self._drain("n-1", 0.197, 0.020)  # [0.197, 0.217)
+        ctx = self._ctx(set_aside_pass())
+        # busy: [0.200, 0.202) snapshot and prepares, [0.2045, 0.2105),
+        # [0.211, 0.212), [0.2125, 0.216)
+        assert drain_evals.read(ctx, 0.5, "busy") == pytest.approx(
+            12.5, abs=1e-3)
+        # everything covered is [0.198, 0.216) = 18; less busy
+        assert drain_evals.read(ctx, 0.5, "wait") == pytest.approx(
+            5.5, abs=1e-3)
+
+    def test_spans_are_clipped_to_the_drain(self):
+        self._drain("n-1", 0.197, 0.017)  # ends at 0.214, inside s-9
+        ctx = self._ctx(set_aside_pass())
+        assert drain_evals.read(ctx, 0.5, "busy") == pytest.approx(
+            10.5, abs=1e-3)
+        assert drain_evals.read(ctx, 0.5, "wait") == pytest.approx(
+            5.5, abs=1e-3)
+
+    def test_an_eval_of_another_node_or_from_outside_is_left_out(self):
+        self._drain("n-1", 0.197, 0.020)
+        lead, member = set_aside_pass()
+        member["tags"]["node_id"] = "n-2"
+        early = solo_pass()  # the same node, but it began before the drain
+        early["tags"]["node_id"] = "n-1"
+        ctx = self._ctx([lead, member, early])
+        # the leader alone: busy [0.200, 0.2015) and [0.2045, 0.2105)
+        assert drain_evals.read(ctx, 0.5, "busy") == pytest.approx(
+            7.5, abs=1e-3)
+        # covered [0.198, 0.2105) = 12.5
+        assert drain_evals.read(ctx, 0.5, "wait") == pytest.approx(
+            5.0, abs=1e-3)
+
+    def test_a_drain_that_straddles_the_windows_edge_is_left_out(self):
+        self._drain("n-1", 0.197, 0.020)
+        self._drain("n-1", 0.990, 0.020)  # ends after the close
+        self._drain("n-1", -0.010, 0.020)  # began before the opening
+        ctx = self._ctx(set_aside_pass())
+        assert drain_evals.read(ctx, 0.0, "busy") == pytest.approx(
+            12.5, abs=1e-3)
+        assert drain_evals.read(ctx, 1.0, "busy") == pytest.approx(
+            12.5, abs=1e-3)
+
+    def test_a_program_without_the_span_or_the_tag_gives_nothing(self):
+        ctx = self._ctx(set_aside_pass())
+        assert drain_evals.read(ctx, 0.5, "busy") is None  # no drain span
+        self._drain("n-1", 0.197, 0.020)
+        old = self._ctx([solo_pass(), *batched_pass()])  # no node_id
+        assert drain_evals.read(old, 0.5, "busy") is None
+        assert drain_evals.read(old, 0.5, "wait") is None
+
+
 class TestCheckTraces:
+    def test_a_set_aside_pass_passes_every_check(self):
+        """``solo_wait`` is a child of the root and may overlap the copies
+        beside it; the gap table shows the root's gaps around it covered."""
+        lead, member = set_aside_pass()
+        report = check_traces.check([lead, member], {"metrics": {}})
+        assert report["ok"], report
+        assert report["distinct_pass_ids"] == 3
+        gaps = check_traces.gaps([lead])
+        assert "eval: prepare -> join_commit" not in gaps
+        assert gaps["eval: join_commit -> prepare"] == pytest.approx(
+            0.0005, abs=1e-6)
+        lead["spans"] = [
+            s for s in lead["spans"]
+            if s["name"] not in ("solo_wait", "overlay.wait")]
+        assert check_traces.gaps([lead])[
+            "eval: prepare -> join_commit"] == pytest.approx(0.0015, abs=1e-6)
+
     def test_sound_traces_pass_every_check(self):
         traces = [solo_pass()] + batched_pass()
         result = {"metrics": {"passes_solo": {"value": 1.0},

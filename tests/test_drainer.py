@@ -586,3 +586,177 @@ def test_the_applier_refuses_a_node_that_started_draining_after_the_snapshot(
     server.store.upsert_allocs(server.store.latest_index + 1, [alloc])
     ok, _why = evaluate_node_plan(server.store.snapshot(), plan, node.id)
     assert ok
+
+
+# -- the drain as one record --------------------------------------------------
+
+
+def _two_of_one_job_on(server, max_parallel=1):
+    """(the node that holds both allocations of a job of two, the job), a
+    second node registered beside it once they are placed and running."""
+    n1 = mock.node()
+    server.register_node(n1)
+    job = mock.job()
+    job.task_groups[0].count = 2
+    job.task_groups[0].migrate = MigrateStrategy(max_parallel=max_parallel)
+    server.register_job(job)
+    assert server.wait_for_evals(10)
+    _running(server, job)
+    assert len(live_allocs_on(server, n1.id)) == 2
+    server.register_node(mock.node())
+    return n1, job
+
+
+def _drain_spans():
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    return [s for s in flight_recorder.background() if s["name"] == "drain"]
+
+
+def test_a_drain_is_one_span_whose_three_clocks_sum_to_it(quiet_server):
+    """Command's commit -> strategy cleared, handed over where it ends,
+    its time split among who the drain waited for."""
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    server = quiet_server
+    flight_recorder.clear()
+    traces = []
+    flight_recorder.add_listener(traces.append)
+    try:
+        victim, job = _two_of_one_job_on(server)
+        t0 = time.perf_counter()
+        server.update_node_drain(victim.id, DrainStrategy(deadline_s=3600))
+        deadline = time.time() + 8.0
+        while time.time() < deadline and (
+            server.store.node_by_id(victim.id).drain is not None
+        ):
+            _running(server, job)  # the fake client
+            time.sleep(0.005)
+        t1 = time.perf_counter()
+        assert server.store.node_by_id(victim.id).drain is None
+        assert server.wait_for_evals(10)
+        time.sleep(0.1)  # the last trace reaches the recorder after its ack
+    finally:
+        flight_recorder.remove_listener(traces.append)
+
+    (span,) = _drain_spans()
+    tags = span["tags"]
+    assert tags["node_id"] == victim.id
+    assert tags["allocs"] == 2 and tags["migrated"] == 2
+    assert tags["waves"] == 2 and tags["evals"] == 2  # max_parallel 1
+    assert tags["deadlined"] is False
+    clocks = [tags["sched_ms"], tags["client_ms"], tags["drainer_ms"]]
+    assert all(c >= 0.0 for c in clocks)
+    assert sum(clocks) == pytest.approx(span["duration_ms"], rel=0.01)
+    # both waves waited for the scheduler and the drainer answered four
+    # commits; the second wave waited for the client's word on the first
+    # replacement unless that came before the drainer's next look
+    assert tags["sched_ms"] > 0.0 and tags["drainer_ms"] > 0.0
+    # the command's commit -> the commit that cleared the strategy: inside
+    # what the caller saw, and it starts where ``node_drain`` applied
+    assert span["duration_ms"] <= (t1 - t0) * 1000.0
+    (call,) = [s for s in flight_recorder.background()
+               if s["name"] == "node_drain"]
+    assert call["tags"]["node_id"] == victim.id
+    assert call["start_unix"] <= span["start_unix"] <= (
+        call["start_unix"] + call["duration_ms"] / 1000.0)
+    # the drain's parts share its identifier: the root of each of its evals
+    mine = [t for t in traces if t["tags"].get("node_id") == victim.id]
+    assert len(mine) == 2
+    assert all(t["tags"]["triggered_by"] == "node-drain" for t in mine)
+    assert server.drainer._drains == {}
+
+
+def test_a_cancelled_drain_writes_no_span(quiet_server):
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    server = quiet_server
+    flight_recorder.clear()
+    victim, job = _two_of_one_job_on(server)
+    server.update_node_drain(victim.id, DrainStrategy(deadline_s=3600))
+    # no client answers: the first wave's replacement never turns healthy
+    assert wait_until(
+        lambda: any(
+            a.desired_transition.migrate
+            for a in server.store.allocs_by_node(victim.id)
+        ),
+        timeout=2.0, interval=0.002,
+    )
+    assert victim.id in server.drainer._drains
+    server.update_node_drain(victim.id, None)
+    assert server.wait_for_evals(10)
+    server.drainer.scan()
+    assert server.store.node_by_id(victim.id).drain is None
+    assert _drain_spans() == []
+    assert server.drainer._drains == {}
+
+
+def test_a_drain_a_walk_finds_is_drained_and_writes_no_span(quiet_server):
+    """A new leader inherits drains whose start it never saw: it ends
+    them, and hands over no record of an interval it cannot know."""
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    server = quiet_server
+    flight_recorder.clear()
+    n1 = mock.node()
+    server.register_node(n1)
+    server.update_node_drain(n1.id, DrainStrategy(deadline_s=3600))
+    with server.drainer._lock:  # as a drainer started after the commit
+        server.drainer._drains.clear()
+    server.drainer.scan()
+    assert wait_until(lambda: server.store.node_by_id(n1.id).drain is None)
+    assert _drain_spans() == []
+
+
+@pytest.mark.parametrize("states, want", [
+    # (stamp, state the look found) in order -> seconds by state
+    ([(1.0, "sched"), (4.0, "drainer"), (4.5, "ended")],
+     {"drainer": 1.5, "sched": 3.0, "client": 0.0}),
+    ([(1.0, "sched"), (2.0, "client"), (5.0, "drainer"), (5.25, "sched"),
+      (6.0, "drainer"), (6.5, "ended")],
+     {"drainer": 1.75, "sched": 1.75, "client": 3.0}),
+    ([(0.5, "ended")], {"drainer": 0.5, "sched": 0.0, "client": 0.0}),
+])
+def test_a_drain_records_clocks_sum_to_its_length(states, want):
+    from nomad_tpu.server.drainer import _DrainRecord
+
+    rec = _DrainRecord(0.0)
+    for at, state in states:
+        rec.turn(at, state)
+    assert rec.spent == pytest.approx(want)
+    assert sum(rec.spent.values()) == pytest.approx(states[-1][0])
+
+
+def test_the_cli_prints_a_drain_with_its_three_clocks(quiet_server, capsys):
+    """"Why is my drain slow" is an operator's question: ``nomad-tpu
+    trace`` answers it from ``/v1/agent/trace``'s background list."""
+    from nomad_tpu.api.http import HTTPAgent
+    from nomad_tpu.cli.main import main as cli_main
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    server = quiet_server
+    flight_recorder.clear()
+    victim, job = _two_of_one_job_on(server)
+    server.update_node_drain(victim.id, DrainStrategy(deadline_s=3600))
+    deadline = time.time() + 8.0
+    while time.time() < deadline and not _drain_spans():
+        _running(server, job)
+        time.sleep(0.005)
+    (span,) = _drain_spans()
+    http = HTTPAgent(server, None, port=0)
+    http.start()
+    try:
+        assert cli_main(["-address", http.address, "trace"]) == 0
+    finally:
+        http.stop()
+    out = capsys.readouterr().out
+    assert "1 recent node drain(s):" in out
+    (line,) = [ln for ln in out.splitlines() if victim.id in ln
+               and "scheduler=" in ln]
+    tags = span["tags"]
+    for part in (f"scheduler={tags['sched_ms']:.2f}ms",
+                 f"clients={tags['client_ms']:.2f}ms",
+                 f"drainer={tags['drainer_ms']:.2f}ms",
+                 "allocs=2", "waves=2", "evals=2", "migrated=2",
+                 "deadlined=False"):
+        assert part in line, (part, line)

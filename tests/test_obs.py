@@ -360,6 +360,21 @@ class TestEndToEndTrace:
             idx = c._request("GET", "/v1/agent/trace")
             assert ev.id in [t["eval_id"] for t in idx["traces"]]
             assert "errors" in idx and "kernels" in idx
+            # the background ring beside the traces, newest first, and
+            # one name of it on request
+            with global_tracer.background("tick"):
+                pass
+            global_tracer.add_background(
+                "drain", 0.25, start=time.perf_counter() - 0.25,
+                tags={"node_id": "n-1"})
+            idx = c._request("GET", "/v1/agent/trace")
+            assert idx["background"][0]["name"] == "drain"
+            assert idx["background"][0]["duration_ms"] == 250.0
+            assert "tick" in {s["name"] for s in idx["background"]}
+            only = c._request(
+                "GET", "/v1/agent/trace", params={"background": "drain"})
+            assert [s["tags"] for s in only["background"]] == [
+                {"node_id": "n-1"}]
 
             tr = c._request("GET", f"/v1/agent/trace/{ev.id}")
             assert {s["name"] for s in tr["spans"]} >= LIFECYCLE
@@ -496,7 +511,7 @@ def pass_traces():
         assert server.wait_for_evals(timeout=30)
         for w in server.workers:
             w.pause()
-        time.sleep(0.05)  # the worker's dequeue poll may hold one more turn
+        time.sleep(0.25)  # the worker's 0.2 s dequeue poll holds one more turn
         lead = server.register_job(_job("pass-a"))
         member = server.register_job(_job("pass-b"))
         for w in server.workers:
@@ -504,7 +519,7 @@ def pass_traces():
         assert server.wait_for_evals(timeout=30)
         for w in server.workers:
             w.pause()
-        time.sleep(0.05)
+        time.sleep(0.25)
         first = server.register_job(_job("pass-c"))
         gated = server.deregister_job("default", "pass-c")
         for w in server.workers:
@@ -537,8 +552,8 @@ class TestPassRecord:
         assert lead - BATCHED_ONLY == solo
         assert member == lead
         assert solo == {"register", "dequeue", "wait_for_index", "snapshot",
-                        "prepare", "invoke_scheduler", "build_plan",
-                        "submit_plan"}
+                        "prepare", "overlay.wait", "invoke_scheduler",
+                        "build_plan", "submit_plan"}
 
     def test_every_member_carries_the_pass_id(self, pass_traces):
         lead, member = pass_traces["lead"], pass_traces["member"]
@@ -583,10 +598,11 @@ class TestPassRecord:
         lead = pass_traces["lead"]
         copies = {s["name"] for s in _top_level(member)
                   if s["tags"].get("leader_eval") == lead["eval_id"]}
-        assert {"wait_for_index", "snapshot", "invoke_scheduler",
-                "join_commit", "submit_plan"} == copies
+        assert {"wait_for_index", "snapshot", "overlay.wait",
+                "invoke_scheduler", "join_commit", "submit_plan"} == copies
         assert not any("leader_eval" in s["tags"] for s in lead["spans"][1:])
-        for name in ("snapshot", "invoke_scheduler", "submit_plan"):
+        for name in ("snapshot", "overlay.wait", "invoke_scheduler",
+                     "submit_plan"):
             theirs, ours = span_by_name(member, name), span_by_name(lead, name)
             # a copy is the leader's interval, to the digit
             assert theirs["start_unix"] == ours["start_unix"]
@@ -673,6 +689,170 @@ class TestPassRecord:
             for s in _top_level(pass_traces[name])
             if "pass_id" in s["tags"]
         })
+
+
+@pytest.fixture(scope="module")
+def set_aside_traces():
+    """Three live jobs deregistered while the worker is paused: one
+    dequeue of three evals with stops, each set aside by the batched pass
+    for a solo pass of its own. {job id: trace}; "alone" is a
+    deregistration dequeued alone."""
+    global_tracer.set_enabled(True)
+    global_tracer.reset()
+    got = {}
+
+    def keep(trace):
+        got[trace["eval_id"]] = trace
+
+    flight_recorder.add_listener(keep)
+    server = Server(ServerConfig(num_workers=1))
+    server.establish_leadership()
+    try:
+        for _ in range(4):
+            server.register_node(mock.node())
+        for name in ("aside-a", "aside-b", "aside-c", "aside-alone"):
+            server.register_job(_job(name))
+        assert server.wait_for_evals(timeout=30)
+        alone = server.deregister_job("default", "aside-alone")
+        assert server.wait_for_evals(timeout=30)
+        for w in server.workers:
+            w.pause()
+        time.sleep(0.25)  # the worker's dequeue poll holds one more turn
+        evals = [server.deregister_job("default", name)
+                 for name in ("aside-a", "aside-b", "aside-c")]
+        for w in server.workers:
+            w.resume()
+        assert server.wait_for_evals(timeout=30)
+        names = {"alone": alone.id, **{
+            name: ev.id for name, ev in zip("abc", evals)}}
+        deadline = time.time() + 5.0
+        while time.time() < deadline and not set(names.values()) <= set(got):
+            time.sleep(0.02)
+    finally:
+        server.shutdown()
+        flight_recorder.remove_listener(keep)
+    return {name: got[eid] for name, eid in names.items()}
+
+
+class TestSoloWait:
+    """A member a batched pass sets aside waits for the commit thread to
+    reach it: ``solo_wait``, from the set-aside to its own solo pass."""
+
+    @pytest.mark.parametrize("name, ahead", [("a", 0), ("b", 1), ("c", 2)])
+    def test_each_member_set_aside_holds_one_solo_wait(
+        self, set_aside_traces, name, ahead
+    ):
+        t = set_aside_traces[name]
+        assert t["tags"]["batch_size"] == 3
+        waits = [s for s in t["spans"] if s["name"] == "solo_wait"]
+        assert len(waits) == 1
+        wait = waits[0]
+        assert wait["parent_id"] == t["spans"][0]["span_id"]
+        assert wait["tags"]["reason"] == "nothing_to_batch"
+        assert wait["tags"]["ahead"] == ahead
+        # the pass it left, not the solo pass it went on to
+        batched = span_by_name(t, "join_commit")["tags"]
+        assert batched["path"] == wait["tags"]["path"] == "batched"
+        assert wait["tags"]["pass_id"] == batched["pass_id"]
+        assert wait["tags"]["evals"] == 3
+        assert t["tags"]["path"] == "solo"
+        assert t["tags"]["pass_id"] != wait["tags"]["pass_id"]
+        # set aside inside the batched pass, after the member's prepare;
+        # its end is the start of the solo pass's first phase
+        own = [s for s in _top_level(t)
+               if s["tags"].get("pass_id") == t["tags"]["pass_id"]]
+        first = min(own, key=lambda s: s["start_unix"])
+        assert first["name"] == "wait_for_index"
+        assert _end(wait) == pytest.approx(first["start_unix"], abs=1e-3)
+        assert _end(wait) <= first["start_unix"] + 2e-6
+        prepared = [s for s in _top_level(t) if s["name"] == "prepare"][0]
+        assert _end(prepared) <= wait["start_unix"] + 2e-6
+
+    def test_each_waits_for_the_solo_passes_ahead_of_it(
+        self, set_aside_traces
+    ):
+        a, b, c = (
+            span_by_name(set_aside_traces[n], "solo_wait") for n in "abc")
+        assert a["duration_ms"] < b["duration_ms"] < c["duration_ms"]
+        # b's wait ends no sooner than a's whole solo pass
+        assert _end(b) >= _end(set_aside_traces["a"]["spans"][0]) - 1e-3
+
+    def test_an_eval_dequeued_alone_writes_none(self, set_aside_traces):
+        t = set_aside_traces["alone"]
+        assert t["tags"]["batch_size"] == 1
+        assert "solo_wait" not in {s["name"] for s in t["spans"]}
+
+    @pytest.mark.parametrize("name", ["a", "b", "c", "alone"])
+    def test_the_set_aside_traces_nest(self, set_aside_traces, name):
+        assert _outside_parent(set_aside_traces[name]) == []
+
+
+class _Tensors:
+    layout_gen = 0
+
+
+def _pass_under(overlay, eval_id, hold_s, entered=None, go=None):
+    """One pass's read-then-write of ``overlay`` in ``eval_id``'s trace."""
+    global_tracer.begin(eval_id, tags={
+        "pass_id": f"0-{eval_id}", "path": "solo", "evals": 1})
+    with global_tracer.activate(eval_id):
+        if go is not None:
+            go.wait(5.0)
+        overlay.begin_pass(_Tensors())
+        if entered is not None:
+            entered.set()
+        time.sleep(hold_s)
+        overlay.pass_finished()
+    return global_tracer.finish(eval_id)
+
+
+class TestOverlayWait:
+    """``overlay.wait``: the acquire in ``SharedOverlay.begin_pass``."""
+
+    def test_a_lone_pass_waits_for_nobody(self):
+        from nomad_tpu.server.overlay import SharedOverlay
+
+        t = _pass_under(SharedOverlay(), "lone", 0.0)
+        wait = span_by_name(t, "overlay.wait")
+        assert wait["tags"] == {
+            "pass_id": "0-lone", "path": "solo", "evals": 1,
+            "waited": False, "timed_out": False,
+        }
+        assert wait["duration_ms"] < 1.0
+        assert wait["parent_id"] == t["spans"][0]["span_id"]
+
+    def test_a_second_pass_waits_for_the_firsts_hold(self):
+        from nomad_tpu.server.overlay import SharedOverlay
+
+        overlay, hold_s = SharedOverlay(), 0.05
+        entered, out = threading.Event(), {}
+
+        def second():
+            out["second"] = _pass_under(overlay, "second", 0.0, go=entered)
+
+        th = threading.Thread(target=second)
+        th.start()
+        out["first"] = _pass_under(overlay, "first", hold_s, entered=entered)
+        th.join(5.0)
+        first = span_by_name(out["first"], "overlay.wait")
+        assert first["tags"]["waited"] is False
+        wait = span_by_name(out["second"], "overlay.wait")
+        assert wait["tags"]["waited"] is True
+        assert wait["tags"]["timed_out"] is False
+        assert wait["tags"]["pass_id"] == "0-second"
+        # it began once the first held the lock and lasted to its release:
+        # the first's hold, less the moment the second took to get going
+        assert wait["start_unix"] >= _end(first) - 2e-6
+        assert _end(wait) >= _end(first) + hold_s - 1e-3
+        assert wait["duration_ms"] >= hold_s * 1000.0 - 10.0
+
+    def test_a_pass_outside_any_trace_writes_nothing(self):
+        from nomad_tpu.server.overlay import SharedOverlay
+
+        overlay = SharedOverlay()
+        assert overlay.begin_pass(_Tensors()) is None
+        overlay.pass_finished()
+        assert global_tracer.active_count() == 0
 
 
 @pytest.fixture(scope="module")
