@@ -445,6 +445,16 @@ class GroupAsk:
     gang_weight_rack: float = 0.0
     gang_weight_pod: float = 0.0
     gang_weight_ici: float = 0.0
+    # A lane of a batched pass whose placement must be the best of the
+    # whole fleet on a state the store has held, as its eval alone would
+    # find it (the scheduler sets it on the asks of a plan that stops
+    # what it replaces: a migration, a lost allocation). Never confined
+    # to a stripe; and where its first choice went to a lane ahead of
+    # it, not moved to a runner-up of the shared snapshot — a pass
+    # commits at one index, so no snapshot explains that choice — but
+    # placed again on what every other lane left and committed after
+    # them (``repair_batch_conflicts``; PERF.md section 6, PR 38).
+    exact: bool = False
 
     @property
     def has_spreads(self) -> bool:

@@ -1327,6 +1327,10 @@ class PlacementResult:
     # when the pass ran with explain=True; purely observational — never
     # consulted by repair or the schedulers' placement decisions
     explanation: Optional[object] = None
+    # set by repair_batch_conflicts on an ``exact`` lane it placed after
+    # every other lane of the pass, on the usage that holds them all: the
+    # lane's plan commits after theirs, at an index of its own
+    deferred: bool = False
 
 
 class PlacementKernel:
@@ -1806,7 +1810,8 @@ def _decorrelate_lanes(cluster, asks: list, salt: int = 0, used0=None) -> list:
     constraints concentrate eligibility) keep the full node set — repair
     resolves whatever conflicts remain. What a stripe costs in placement
     quality is in PERF.md §2 and §7 (jobs of a pass with several
-    registrations, against the best on offer)."""
+    registrations, against the best on offer); a lane marked ``exact``
+    (``GroupAsk.exact``) does not pay it and keeps the full node set."""
     from dataclasses import replace
 
     n_lanes = len(asks)
@@ -1837,7 +1842,7 @@ def _decorrelate_lanes(cluster, asks: list, salt: int = 0, used0=None) -> list:
     )  # [pn, D]
     out = []
     for i, a in enumerate(asks):
-        if a.count <= 0:
+        if a.count <= 0 or a.exact:
             out.append(a)
             continue
         # Widest stripe count that still leaves this lane comfortable
@@ -2022,8 +2027,15 @@ def repair_batch_conflicts(
     runs out an exact NumPy re-score places them directly — only the
     *conflicted placement* is re-placed, never the whole eval. Kernel
     failures (row −1, e.g. a lane whose stripe ran dry) get the same
-    re-score. The plan applier's per-node AllocsFit re-check
-    (plan_apply.go:638-689) remains the authority.
+    re-score. A lane marked ``exact`` (``GroupAsk.exact``) is never moved
+    to a runner-up scored on the shared snapshot: when a placement of its
+    own no longer fits, its eval waits for every other lane of the pass
+    and is then placed by the exact re-score on the usage that holds them
+    all — what its own pass would find after their commit — and marked
+    ``deferred`` (``PlacementResult.deferred``) for the caller to commit
+    it after them, deferred evals one after the other in lane order. The
+    plan applier's per-node AllocsFit re-check (plan_apply.go:638-689)
+    remains the authority.
 
     Mutates each PlacementResult in place. Returns per-lane ``ok`` —
     False only when a placement is unplaceable under the batch overlay
@@ -2047,26 +2059,39 @@ def repair_batch_conflicts(
         else np.asarray(used_override)
     )
     used = used0.copy()
-    ok_lanes: list[bool] = []
+    ok_lanes: list[bool] = [True] * len(asks)
     # group id -> [(placed_on_node, ask), ...] commit journal for rollback
     group_commits: dict = {}
     failed_groups: set = set()
-    for lane_idx, (a, res) in enumerate(zip(asks, results)):
-        group = lane_groups[lane_idx] if lane_groups is not None else lane_idx
-        if group in failed_groups:
-            # a sibling lane of this eval already hit contention: the
-            # whole eval re-runs individually, so don't reserve anything
-            ok_lanes.append(False)
-            continue
+    # groups of ``exact`` lanes that lost a node to a lane ahead of them:
+    # placed again, whole, once every other lane has been
+    deferred_groups: set = set()
+
+    def release(group, placed_on_node, ask) -> None:
+        """Take back what this lane and the processed lanes of its eval
+        reserved: the eval's placements are not (yet) the pass's."""
+        for r, m in placed_on_node.items():
+            used[r] -= m * ask
+        for sib_placed, sib_ask in group_commits.pop(group, ()):
+            for r, m in sib_placed.items():
+                used[r] -= m * sib_ask
+
+    def walk(lane_idx: int, group) -> None:
+        a, res = asks[lane_idx], results[lane_idx]
         ok = True
         # within-lane placements per node (distinct_hosts, slot caps,
         # anti-affinity collisions all key off it)
         placed_on_node: dict[int, int] = {}
         blocks = a.blocks
         counts = blocks.counts0.copy() if blocks is not None else None
+        rows = res.node_rows.tolist()
         overflow = list(
             zip(res.overflow_rows.tolist(), res.overflow_scores.tolist())
         )
+        if res.deferred:
+            # what the kernel chose, candidates included, was scored
+            # without the other lanes: every placement is re-scored
+            rows, overflow = [-1] * len(rows), []
         of_idx = 0
         dead = False  # lane-intrinsic infeasibility: stop re-scoring
 
@@ -2120,7 +2145,7 @@ def repair_batch_conflicts(
             )
             return "contention" if row >= 0 else "intrinsic"
 
-        for i, row in enumerate(res.node_rows.tolist()):
+        for i, row in enumerate(rows):
             if row >= 0 and acceptable(row):
                 commit(row)
                 continue
@@ -2128,6 +2153,13 @@ def repair_batch_conflicts(
                 res.node_rows[i] = -1
                 res.scores[i] = -np.inf
                 continue
+            if a.exact and row >= 0:
+                # its best node went to a lane ahead of it: the eval
+                # waits for the other lanes and is placed on what they
+                # leave (below), not on a runner-up of this snapshot
+                release(group, placed_on_node, a.ask)
+                deferred_groups.add(group)
+                return
             # conflicted or unplaced: advance through overflow candidates
             repl = -1
             while of_idx < len(overflow):
@@ -2149,11 +2181,7 @@ def repair_batch_conflicts(
                 # reservations would cascade later lanes into serial
                 # fallbacks a fresh-state rerun would avoid). Release this
                 # lane AND every processed sibling lane of the same eval.
-                for r, m in placed_on_node.items():
-                    used[r] -= m * a.ask
-                for sib_placed, sib_ask in group_commits.get(group, ()):
-                    for r, m in sib_placed.items():
-                        used[r] -= m * sib_ask
+                release(group, placed_on_node, a.ask)
                 failed_groups.add(group)
                 ok = False
                 break
@@ -2169,5 +2197,24 @@ def repair_batch_conflicts(
             group_commits.setdefault(group, []).append(
                 (placed_on_node, a.ask)
             )
-        ok_lanes.append(ok)
+        ok_lanes[lane_idx] = ok
+
+    def visit(lane_idx: int, group) -> None:
+        if group in failed_groups:
+            # a sibling lane of this eval already hit contention: the
+            # whole eval re-runs individually, so don't reserve anything
+            ok_lanes[lane_idx] = False
+        else:
+            walk(lane_idx, group)
+
+    groups = lane_groups if lane_groups is not None else range(len(asks))
+    for lane_idx, group in enumerate(groups):
+        if group not in deferred_groups:
+            visit(lane_idx, group)
+    # the deferred evals, every lane of each, on the usage that now holds
+    # every other lane's placements and the deferred ones before them
+    for lane_idx, group in enumerate(groups):
+        if group in deferred_groups:
+            results[lane_idx].deferred = True
+            visit(lane_idx, group)
     return ok_lanes

@@ -190,7 +190,9 @@ class GenericScheduler:
         with tracer.phase("prepare"):
             placements = self._start_attempt()
             if placements and self.job is not None:
-                ct, tg_order = self._build_group_asks(placements)
+                ct = self.cache.tensors(self.snapshot)
+                self._free_plan_stops(ct)
+                tg_order = self._build_group_asks(ct, placements)
                 asks = [t[3] for t in tg_order]
                 if self.node_filter is not None and asks:
                     mask = self.node_filter(ct)
@@ -271,7 +273,9 @@ class GenericScheduler:
                 self.overlay.pass_finished()
 
     # -- batched multi-eval pass (SURVEY.md §7 step 5) --------------------
-    def prepare_batch_attempt(self, evaluation: Evaluation, ct=None):
+    def prepare_batch_attempt(
+        self, evaluation: Evaluation, ct, *, with_stops: bool = True
+    ):
         """Phase A of a batched multi-eval device pass: run the host side
         (reconcile + flatten) and return this eval's group asks for the
         caller to merge into one kernel call across evals — the batch
@@ -282,12 +286,22 @@ class GenericScheduler:
         for the whole batch: every eval's masks must be built against the
         same row order as the capacity/used arrays of the combined kernel
         call (a mid-batch cache-generation advance would otherwise hand
-        later evals a differently-ordered transient build).
+        later evals a differently-ordered transient build). Its ``used``
+        is every lane's and the base the overlay freezes: it is read
+        here, never written.
 
         Returns the list of GroupAsks, or None when the eval needs the
-        individual path: no placement work at all, or a plan whose
-        evictions couple placements to freed capacity (the in-plan used
-        overlay is eval-local and can't share one batched ``used0``).
+        individual path: no placement work at all, or a plan whose stops
+        free room its own placements may take (the solo pass takes them
+        off ``used``, which is eval-local and can't share one batched
+        ``used0``). A plan whose stops all sit on nodes closed to
+        placement — a migration off a draining node, the replacement of
+        an allocation lost with its node — stays: no lane reads ``used``
+        on a row it may not place on, so the stop is left on the shared
+        ``used`` and the other lanes see that row still full, as from a
+        snapshot taken before the stop commits. ``with_stops`` False
+        sends every plan with a stop the individual way (lane mode: the
+        stop's node may be another worker's).
         """
         self.eval = evaluation
         self.batch = self.batch or evaluation.type == "batch"
@@ -299,11 +313,31 @@ class GenericScheduler:
         placements = self._start_attempt()
         if not placements or self.job is None:
             return None
-        if self.plan.node_update or self.plan.node_preemptions:
+        if self.plan.node_preemptions:
             return None  # evictions free capacity only for this eval's plan
-        ct, tg_order = self._build_group_asks(placements, ct=ct)
+        if self.plan.node_update and not (
+            with_stops and self._stops_out_of_reach(ct)
+        ):
+            return None
+        tg_order = self._build_group_asks(ct, placements)
         self._batch_ctx = (ct, tg_order)
-        return [t[3] for t in tg_order]
+        asks = [t[3] for t in tg_order]
+        if self.plan.node_update:
+            # what replaces a stopped allocation is the best node of the
+            # fleet on a state the store has held, as the eval's own pass
+            # would find it (``GroupAsk.exact``)
+            for ga in asks:
+                ga.exact = True
+        return asks
+
+    def _stops_out_of_reach(self, ct) -> bool:
+        """Whether no ask of this eval may place on a node row its plan's
+        stops free. Decided on ``ct.ready``, where every ask's eligibility
+        starts (flatten ``_eligibility_for_group``), before anything is
+        flattened: a node closed there is closed to every ask. A node the
+        tensors do not hold frees nothing a lane can read."""
+        rows = [ct.node_row.get(node_id) for node_id in self.plan.node_update]
+        return not ct.ready[[r for r in rows if r is not None]].any()
 
     def complete_batch_attempt(self, results) -> bool:
         """Phase B: consume this eval's slice of the combined kernel
@@ -516,33 +550,36 @@ class GenericScheduler:
         return True, False
 
     # -- placement via the device kernel ---------------------------------
-    def _build_group_asks(self, placements, ct=None):
-        """Flatten this eval's placements into dense group asks against
-        the resident tensors (replaces computePlacements' per-alloc
-        stack.Select walk). Returns (ct, tg_order). ``ct`` lets a batch
-        caller supply one shared tensors object for all evals."""
-        snap = self.snapshot
-        if ct is None:
-            ct = self.cache.tensors(snap)
-        nodes_sorted = ct.nodes
-        # overlay this plan's own stops (evicted allocs free capacity)
+    def _free_plan_stops(self, ct) -> None:
+        """Take this plan's own stops off the solo pass's own ``ct.used``
+        (stopped and evicted allocations free capacity for the plan's
+        placements)."""
         self._plan_freed = None
-        if self.plan.node_update:
-            with tracer.span("plan_stops") as sp:
-                n_stops = 0
-                freed = np.zeros_like(ct.used)
-                for node_id, stops in self.plan.node_update.items():
-                    row = ct.node_row.get(node_id)
-                    if row is None:
-                        continue
-                    for a in stops:
-                        freed[row] += a.comparable_resources().to_vector()
-                    n_stops += len(stops)
-                ct.used -= freed
-                # what the shared overlay's view of usage still lacks
-                self._plan_freed = freed
-                if sp is not None:
-                    sp.tags["stops"] = n_stops
+        if not self.plan.node_update:
+            return
+        with tracer.span("plan_stops") as sp:
+            n_stops = 0
+            freed = np.zeros_like(ct.used)
+            for node_id, stops in self.plan.node_update.items():
+                row = ct.node_row.get(node_id)
+                if row is None:
+                    continue
+                for a in stops:
+                    freed[row] += a.comparable_resources().to_vector()
+                n_stops += len(stops)
+            ct.used -= freed
+            # what the shared overlay's view of usage still lacks
+            self._plan_freed = freed
+            if sp is not None:
+                sp.tags["stops"] = n_stops
+
+    def _build_group_asks(self, ct, placements) -> list:
+        """Flatten this eval's placements into dense group asks against
+        the tensors ``ct`` (replaces computePlacements' per-alloc
+        stack.Select walk): ``tg_order``, a (name, placements, group, ask)
+        a task group."""
+        snap = self.snapshot
+        nodes_sorted = ct.nodes
 
         # group placements by task group
         by_tg: dict[str, list] = {}
@@ -568,7 +605,7 @@ class GenericScheduler:
                 plan=self.plan,
             )
             tg_order.append((tg_name, prs, tg, ga))
-        return ct, tg_order
+        return tg_order
 
     def _finish_placements(self, ct, tg_order, results) -> None:
         """Consume kernel results: build allocations, run the preemption

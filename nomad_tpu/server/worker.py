@@ -143,8 +143,10 @@ class _PassTrace:
     def set_aside(self, singles: list, ev, token, reason: str) -> None:
         """The member leaves this pass for the solo path. ``reason`` is
         one word a site: ``not_batchable`` (no service or batch eval),
-        ``nothing_to_batch`` (its prepare returned no asks: stops, or
-        nothing to place), ``prepare_error``, ``kernel_fallback``,
+        ``nothing_to_batch`` (its prepare returned no asks: nothing to
+        place, or stops that free room within its own asks' reach — a
+        destructive update —, or any stop in lane mode),
+        ``prepare_error``, ``kernel_fallback``,
         ``conflict_fallback``, ``handoff_fallback`` (lane mode),
         ``commit_fallback``."""
         self.aside[ev.id] = (time.perf_counter(), reason)
@@ -613,8 +615,25 @@ class Worker:
             )
             try:
                 # each member's own prepare, in its own trace
-                with tracer.activate(ev.id), tracer.phase("prepare"):
-                    asks = sched.prepare_batch_attempt(ev, ct=ct)
+                with tracer.activate(ev.id), tracer.phase("prepare") as sp:
+                    # lane mode: a stop's node may be a peer's, and a
+                    # merged plan answers for every node it touches
+                    asks = sched.prepare_batch_attempt(
+                        ev, ct=ct, with_stops=not lane_mode
+                    )
+                    if asks is not None:
+                        # members that stay with stops in their plan (a
+                        # drain's migration: room freed only where its
+                        # own lanes may not place), counted by 0 for the
+                        # others: a window of batched passes that held
+                        # none reads 0, not nothing
+                        stops = sum(map(len, sched.plan.node_update.values()))
+                        metrics.incr(
+                            "nomad.worker.evals_batched_with_stops",
+                            float(stops > 0),
+                        )
+                        if stops and sp is not None:
+                            sp.tags["stops_batched"] = stops
             except Exception as e:
                 log.exception("worker %d: batch prepare %s", self.id, ev.id)
                 count_swallowed("worker", e)
@@ -894,6 +913,11 @@ class Worker:
         server = self.server
         buf = _EvalBuffer(server)
         members: list[tuple] = []  # (ev, token, sched, member plan)
+        # members the repair placed after every other lane, on the usage
+        # that holds them all (``PlacementResult.deferred``): each commits
+        # after the pass's merged plan, at an index of its own, in lane
+        # order — every placement then sits on a state the store has held
+        late: list[tuple] = []
         done: list[tuple] = []  # acked after the status flush below
         claims: list = []  # confirmed cross-lane claims riding this commit
         try:
@@ -928,6 +952,8 @@ class Worker:
                     # no-op eval: finalized already (status buffered)
                     done.append((ev, token))
                     metrics.incr("nomad.worker.batch_evals_completed")
+                elif any(r.deferred for r in span):
+                    late.append((ev, token, sched, member))
                 else:
                     members.append((ev, token, sched, member))
 
@@ -970,91 +996,14 @@ class Worker:
             # them commit; one raft apply covers the whole batch's creates
             buf.flush()
 
-            # 3. submit: ONE merged entry for the whole pass
-            mresults: list = [None] * len(members)
-            if members:
-                for _ev, token, _sched, member in members:
-                    member.eval_token = token
-                    member.normalize()
-                # past this point the applier may land the claimed
-                # placements even if this thread dies — the finally
-                # below must settle (not just drop) the claimed nodes
-                for claim in claims:
-                    claim.submitted = True
-                with ps.phase(
-                    "submit_plan", timer="nomad.worker.submit_plan"
-                ) as sp:
-                    # the applier thread records the queue wait and the
-                    # merged apply under this span, once for the pass
-                    futures = server.plan_queue.enqueue_merged(
-                        MergedPlan(
-                            plans=[m[3] for m in members],
-                            owner_worker=(
-                                self.id if self._lane_mode() else -1
-                            ),
-                            claims=list(claims),
-                        ),
-                        trace_ctx=tracer.current_ctx(),
-                    )
-                    # a kill here crashes the thread AFTER the merged plan
-                    # is in flight: the applier still commits it, nobody
-                    # acks, and redelivered members must converge to
-                    # no-ops (never lose or double-commit a member)
-                    chaos_site("worker.commit")
-                    for i, (ev, token, _sched, _member) in enumerate(members):
-                        try:
-                            mresults[i] = futures[i].result(timeout=30)
-                        except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
-                            self._nack_member(ev, token, e, "merged submit")
-                    if sp is not None:
-                        sp.tags["rejected_nodes"] = sum(
-                            len(r.rejected_nodes)
-                            for r in mresults if r is not None
-                        )
-
-                # 4. one shared refresh barrier for every partially
-                # committed member (each previously waited on its own)
-                refresh = max(
-                    (r.refresh_index for r in mresults if r is not None),
-                    default=0,
+            # 3-5. submit the pass as ONE merged entry, then each deferred
+            # member as an entry of its own
+            self._submit_merged(members, claims, ps, singles, done)
+            for member in late:
+                metrics.incr("nomad.worker.batch_deferred_commits")
+                self._submit_merged(
+                    [member], [], ps, singles, done, alone=True
                 )
-                if refresh:
-                    with ps.phase(
-                        "refresh_snapshot", tags={"refresh_index": refresh}
-                    ):
-                        server.store.wait_for_index(refresh, timeout=5.0)
-
-                # 5. complete: full commits finalize (status buffered);
-                # stale members retry individually on fresh state (the
-                # trace stays open; _run_one below appends the retry)
-                for i, (ev, token, sched, _member) in enumerate(members):
-                    if mresults[i] is None:
-                        continue  # nacked above
-                    if mresults[i].token_stale:
-                        # the applier dropped this member: the broker
-                        # redelivered the eval mid-pass and another
-                        # worker owns it now — no ack/nack (our token is
-                        # dead) and no singles retry (that would race
-                        # the new owner into a double commit)
-                        metrics.incr("nomad.worker.stale_token_drops")
-                        self._bump("processed")
-                        tracer.finish(ev.id, status="stale_token")
-                        continue
-                    try:
-                        with tracer.activate(ev.id):
-                            completed = sched.complete_merged_attempt(
-                                mresults[i]
-                            )
-                    except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
-                        self._nack_member(ev, token, e, "batch complete")
-                        continue
-                    if completed:
-                        done.append((ev, token))
-                        metrics.incr("nomad.worker.batch_evals_completed")
-                    else:
-                        metrics.incr("nomad.worker.batch_conflict_fallbacks")
-                        metrics.incr("nomad.worker.batch_commit_fallbacks")
-                        ps.set_aside(singles, ev, token, "commit_fallback")
 
             # 6. land every member's finalize-time status (and blocked
             # eval creates) in one raft apply, then ack — status must be
@@ -1098,6 +1047,100 @@ class Worker:
                 server.lane_claims.release(
                     claim, committed=claim.submitted
                 )
+
+    def _submit_merged(
+        self, members, claims, ps, singles, done, *, alone: bool = False
+    ) -> None:
+        """Steps 3 to 5 of the coalesced commit for one group of member
+        plans: one merged plan-queue entry, the shared refresh barrier,
+        each member resolved from its own result. The phases are the
+        pass's, shared by its members; those of a deferred member that
+        commits ``alone`` go to its own trace."""
+        if not members:
+            return
+        server = self.server
+        phase = ps.phase
+        if alone:
+            ((lone, _token, _sched, _member),) = members
+
+            @contextmanager
+            def phase(name, **kw):
+                with tracer.activate(lone.id), tracer.phase(name, **kw) as sp:
+                    yield sp
+
+        # 3. submit: ONE merged entry for the group
+        mresults: list = [None] * len(members)
+        for _ev, token, _sched, member in members:
+            member.eval_token = token
+            member.normalize()
+        # past this point the applier may land the claimed placements
+        # even if this thread dies — the caller's finally must settle
+        # (not just drop) the claimed nodes
+        for claim in claims:
+            claim.submitted = True
+        with phase("submit_plan", timer="nomad.worker.submit_plan") as sp:
+            # the applier thread records the queue wait and the merged
+            # apply under this span, once for the group
+            futures = server.plan_queue.enqueue_merged(
+                MergedPlan(
+                    plans=[m[3] for m in members],
+                    owner_worker=self.id if self._lane_mode() else -1,
+                    claims=list(claims),
+                ),
+                trace_ctx=tracer.current_ctx(),
+            )
+            # a kill here crashes the thread AFTER the merged plan is in
+            # flight: the applier still commits it, nobody acks, and
+            # redelivered members must converge to no-ops (never lose or
+            # double-commit a member)
+            chaos_site("worker.commit")
+            for i, (ev, token, _sched, _member) in enumerate(members):
+                try:
+                    mresults[i] = futures[i].result(timeout=30)
+                except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
+                    self._nack_member(ev, token, e, "merged submit")
+            if sp is not None:
+                sp.tags["rejected_nodes"] = sum(
+                    len(r.rejected_nodes) for r in mresults if r is not None
+                )
+
+        # 4. one shared refresh barrier for every partially committed
+        # member (each previously waited on its own)
+        refresh = max(
+            (r.refresh_index for r in mresults if r is not None), default=0
+        )
+        if refresh:
+            with phase("refresh_snapshot", tags={"refresh_index": refresh}):
+                server.store.wait_for_index(refresh, timeout=5.0)
+
+        # 5. complete: full commits finalize (status buffered); stale
+        # members retry individually on fresh state (the trace stays
+        # open; _run_one appends the retry)
+        for i, (ev, token, sched, _member) in enumerate(members):
+            if mresults[i] is None:
+                continue  # nacked above
+            if mresults[i].token_stale:
+                # the applier dropped this member: the broker redelivered
+                # the eval mid-pass and another worker owns it now — no
+                # ack/nack (our token is dead) and no singles retry (that
+                # would race the new owner into a double commit)
+                metrics.incr("nomad.worker.stale_token_drops")
+                self._bump("processed")
+                tracer.finish(ev.id, status="stale_token")
+                continue
+            try:
+                with tracer.activate(ev.id):
+                    completed = sched.complete_merged_attempt(mresults[i])
+            except Exception as e:  # nta: allow=NTA003 — _nack_member logs+counts
+                self._nack_member(ev, token, e, "batch complete")
+                continue
+            if completed:
+                done.append((ev, token))
+                metrics.incr("nomad.worker.batch_evals_completed")
+            else:
+                metrics.incr("nomad.worker.batch_conflict_fallbacks")
+                metrics.incr("nomad.worker.batch_commit_fallbacks")
+                ps.set_aside(singles, ev, token, "commit_fallback")
 
     def process_eval(self, ev: Evaluation, planner=None) -> None:
         # solo evals score against the shared overlay too (an overlay-

@@ -225,6 +225,22 @@ def test_batch_decorrelation_and_repair_large_lanes():
     assert np.all(ct.used + total <= ct.capacity + 1e-3)
 
 
+def test_an_exact_lane_is_not_confined_to_a_stripe():
+    """``_decorrelate_lanes`` leaves a lane marked ``exact`` the whole
+    node set; its siblings keep their stripes."""
+    from nomad_tpu.device.score import _decorrelate_lanes
+
+    ct = make_cluster(512, seed=25, load_max=0.3)
+    lanes = [make_ask(ct, count=1, seed=40 + s) for s in range(4)]
+    lanes[2].exact = True
+    work = _decorrelate_lanes(ct, lanes, salt=3)
+    assert work[2] is lanes[2]
+    for i in (0, 1, 3):
+        assert work[i] is not lanes[i]
+        assert work[i].eligible.sum() < lanes[i].eligible.sum()
+        assert not (work[i].eligible & ~lanes[i].eligible).any()
+
+
 def test_repair_rescore_places_conflicts_without_abort():
     """Two identical lanes, no decorrelation, tiny overflow: the second
     lane's conflicts must be re-placed by the exact host re-score instead

@@ -412,6 +412,65 @@ def test_repair_batch_conflicts_moves_overcommit():
     assert np.all(ct.used + total <= ct.capacity + 1e-5)
 
 
+@pytest.mark.parametrize("exact", [
+    (False, False, False),  # runners-up of the shared snapshot, as above
+    (False, True, False),   # an exact lane waits for the others
+    (False, False, True),
+    (True, True, True),     # ahead of the others it keeps its best
+])
+def test_an_exact_lane_that_loses_its_node_is_placed_after_the_others(exact):
+    """A lane marked ``exact`` (the replacement of what its plan stops) is
+    never moved to an overflow candidate of the shared snapshot: it is
+    placed once every other lane has been, by the exact re-score on the
+    usage that holds them, and marked ``deferred``."""
+    from nomad_tpu.device.score import _rescore_pick
+
+    ct = make_cluster(3, seed=10, load_max=0.0)
+    ct.capacity[:3, 0] = (1000, 1000, 2100)
+    ct.capacity[:3, 1] = (1024, 1024, 2100)
+    lanes = [make_ask(ct, count=1, seed=s, cpu=900, mem=900)
+             for s in (1, 2, 3)]
+    for lane, e in zip(lanes, exact):
+        lane.exact = e
+    results = PlacementKernel("binpack").place(ct, lanes)
+    first = [int(r.node_rows[0]) for r in results]
+    scores = [float(r.scores[0]) for r in results]
+    assert first[0] == first[1] == first[2]
+    assert first[0] in (0, 1)  # a node that takes one of the three
+    ok = repair_batch_conflicts(ct, lanes, results, lane_groups=[0, 1, 2])
+    assert ok == [True, True, True]
+    rows = [int(r.node_rows[0]) for r in results]
+    assert rows[0] == first[0] and sorted(rows) == [0, 1, 2]
+    assert [r.deferred for r in results] == [False, exact[1], exact[2]]
+    used = ct.used.copy()
+    used[rows[0]] += lanes[0].ask
+    zero = np.zeros(ct.padded_n, dtype=np.float32)
+    for k in (1, 2):
+        if not exact[k]:
+            # an overflow candidate, with the score it had on the snapshot
+            assert float(results[k].scores[0]) <= scores[k]
+            used[rows[k]] += lanes[k].ask
+    for k in (1, 2):
+        if exact[k]:
+            # the best of what every lane before it in this order left
+            row, score = _rescore_pick(
+                ct.capacity, used, lanes[k], zero, None, False)
+            assert (rows[k], float(results[k].scores[0])) == (row, score)
+            used[row] += lanes[k].ask
+
+
+def test_an_exact_lane_beside_one_that_leaves_room_keeps_its_node():
+    ct = make_cluster(2, seed=10, load_max=0.0)
+    ct.capacity[:2, 0] = 2000
+    ct.capacity[:2, 1] = 2048
+    lanes = [make_ask(ct, count=1, seed=s, cpu=900, mem=900) for s in (1, 2)]
+    lanes[1].exact = True
+    results = PlacementKernel("binpack").place(ct, lanes)
+    first = [int(r.node_rows[0]) for r in results]
+    assert repair_batch_conflicts(ct, lanes, results) == [True, True]
+    assert [int(r.node_rows[0]) for r in results] == first
+
+
 def test_repair_reports_unrepairable_lane():
     ct = make_cluster(1, seed=11, load_max=0.0)
     ct.capacity[0, 0] = 1000
