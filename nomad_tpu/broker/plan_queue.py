@@ -193,8 +193,12 @@ class PlanApplyLoop:
         where they happened, under the pass's submit_plan span."""
         wait_s = time.perf_counter() - pending.enqueued_at
         mplan = pending.mplan
+        ctx = pending.trace_ctx
         try:
-            results, timings = self.applier.apply_merged(mplan)
+            # the store's write (``plan_apply.store_write``) opens under
+            # the pass's submit_plan; it moves under the commit span below
+            with tracer.attach(ctx):
+                results, timings = self.applier.apply_merged(mplan)
         except Exception as e:  # noqa: BLE001 — propagate to waiters
             log.exception("merged plan apply failed (%d members)",
                           len(mplan.plans))
@@ -202,7 +206,6 @@ class PlanApplyLoop:
                 if not f.done():
                     f.set_exception(e)
             return
-        ctx = pending.trace_ctx
         if ctx is not None:
             eid = ctx.trace_id
             tracer.add_span(
@@ -221,9 +224,15 @@ class PlanApplyLoop:
             )
             if sp is not None:
                 for stage in ("evaluate", "commit"):
-                    tracer.add_span(
+                    stage_sp = tracer.add_span(
                         eid, f"plan_apply.{stage}", timings[f"{stage}_s"],
                         start=timings[f"{stage}_start"], parent=sp,
                     )
+                write = tracer.newest(eid, "plan_apply.store_write")
+                if (
+                    write is not None and stage_sp is not None
+                    and write.parent_id == ctx.span_id
+                ):
+                    write.parent_id = stage_sp.span_id
         for res, fut in zip(results, pending.futures):
             fut.set_result(res)

@@ -909,6 +909,26 @@ def _device_slot_caps(
     return slot_caps, dev_aff, has_dev_aff
 
 
+def proposed_job_counts(ct: ClusterTensors, snap, job: Job, plan=None):
+    """The job's allocations per node row as the plan proposes them: those
+    it stops are gone (rank.go JobAntiAffinityIterator reads
+    ctx.ProposedAllocs, which leaves out plan.NodeUpdate)."""
+    stopped_ids = (
+        {a.id for stops in plan.node_update.values() for a in stops}
+        if plan is not None and plan.node_update
+        else ()
+    )
+    job_counts = np.zeros(ct.padded_n, dtype=np.int32)
+    if snap is not None:
+        for a in snap.allocs_by_job(job.namespace, job.id):
+            if a.terminal_status() or a.id in stopped_ids:
+                continue
+            row = ct.node_row.get(a.node_id)
+            if row is not None:
+                job_counts[row] += 1
+    return job_counts
+
+
 def flatten_group_ask(
     ct: ClusterTensors,
     snap,
@@ -944,22 +964,7 @@ def flatten_group_ask(
         ct, nodes_sorted, job, tg, snap
     )
 
-    # the job's allocations per node as the plan proposes them: those it
-    # stops are gone (rank.go JobAntiAffinityIterator reads
-    # ctx.ProposedAllocs, which leaves out plan.NodeUpdate)
-    stopped_ids = (
-        {a.id for stops in plan.node_update.values() for a in stops}
-        if plan is not None and plan.node_update
-        else ()
-    )
-    job_counts = np.zeros(ct.padded_n, dtype=np.int32)
-    if snap is not None:
-        for a in snap.allocs_by_job(job.namespace, job.id):
-            if a.terminal_status() or a.id in stopped_ids:
-                continue
-            row = ct.node_row.get(a.node_id)
-            if row is not None:
-                job_counts[row] += 1
+    job_counts = proposed_job_counts(ct, snap, job, plan)
 
     penalty = np.zeros(ct.padded_n, dtype=bool)
     for nid in penalty_node_ids or ():

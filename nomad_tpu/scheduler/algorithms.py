@@ -219,14 +219,16 @@ def score_group(
     if not explain:
         return np.asarray(finals)[0], np.asarray(fits)[0]
     from ..obs.explain import explain_group
+    from ..obs.trace import global_tracer as tracer
 
-    ex = explain_group(
-        ct,
-        ga,
-        np.asarray(ct.used),
-        algorithm="spread" if algorithm_spread else "binpack",
-        algorithm_spread=algorithm_spread,
-        throughputs=throughputs[0] if throughputs is not None else None,
-        desired_total=float(max(desired_total, 1)),
-    )
+    with tracer.span("explain", tags={"step": "groups"}):
+        ex = explain_group(
+            ct,
+            ga,
+            np.asarray(ct.used),
+            algorithm="spread" if algorithm_spread else "binpack",
+            algorithm_spread=algorithm_spread,
+            throughputs=throughputs[0] if throughputs is not None else None,
+            desired_total=float(max(desired_total, 1)),
+        )
     return np.asarray(finals)[0], np.asarray(fits)[0], ex

@@ -39,7 +39,7 @@ from ..structs.evaluation import (
     TRIGGER_JOB_REGISTER,
     TRIGGER_QUEUED_ALLOCS,
 )
-from .reconcile import reconcile
+from .reconcile import reconcile, updated_in_place
 from .scheduler import Planner, register_scheduler
 
 MAX_SERVICE_SCHEDULE_ATTEMPTS = 5  # generic_sched.go:15-18
@@ -90,6 +90,29 @@ def tainted_nodes(snapshot, allocs) -> dict:
             if node.status != "initializing":
                 out[a.node_id] = node
     return out
+
+
+def free_plan_stops(ct, plan):
+    """Take ``plan``'s own stops off the pass's own ``ct.used`` (stopped
+    and evicted allocations free capacity for the plan's placements);
+    returns what was taken off, ``[padded_n, D]``, or None for a plan
+    without stops."""
+    if not plan.node_update:
+        return None
+    with tracer.span("plan_stops") as sp:
+        n_stops = 0
+        freed = np.zeros_like(ct.used)
+        for node_id, stops in plan.node_update.items():
+            row = ct.node_row.get(node_id)
+            if row is None:
+                continue
+            for a in stops:
+                freed[row] += a.comparable_resources().to_vector()
+            n_stops += len(stops)
+        ct.used -= freed
+        if sp is not None:
+            sp.tags["stops"] = n_stops
+    return freed
 
 
 @register_scheduler("service")
@@ -470,10 +493,7 @@ class GenericScheduler:
             )
         # in-place updates: same node, new job version
         for upd in results.inplace_update:
-            a = upd.alloc.copy_for_update()
-            a.job = upd.new_job
-            a.job_version = upd.new_job.version
-            self.plan.append_alloc(a)
+            self.plan.append_alloc(updated_in_place(upd.alloc, upd.new_job))
         # destructive updates: stop old + place new
         destructive_places = []
         for old, pr in results.destructive_update:
@@ -551,27 +571,8 @@ class GenericScheduler:
 
     # -- placement via the device kernel ---------------------------------
     def _free_plan_stops(self, ct) -> None:
-        """Take this plan's own stops off the solo pass's own ``ct.used``
-        (stopped and evicted allocations free capacity for the plan's
-        placements)."""
-        self._plan_freed = None
-        if not self.plan.node_update:
-            return
-        with tracer.span("plan_stops") as sp:
-            n_stops = 0
-            freed = np.zeros_like(ct.used)
-            for node_id, stops in self.plan.node_update.items():
-                row = ct.node_row.get(node_id)
-                if row is None:
-                    continue
-                for a in stops:
-                    freed[row] += a.comparable_resources().to_vector()
-                n_stops += len(stops)
-            ct.used -= freed
-            # what the shared overlay's view of usage still lacks
-            self._plan_freed = freed
-            if sp is not None:
-                sp.tags["stops"] = n_stops
+        # what the shared overlay's view of usage still lacks
+        self._plan_freed = free_plan_stops(ct, self.plan)
 
     def _build_group_asks(self, ct, placements) -> list:
         """Flatten this eval's placements into dense group asks against

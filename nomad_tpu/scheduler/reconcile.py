@@ -115,6 +115,15 @@ def tasks_updated(old_job: Job, new_job: Job, group_name: str) -> bool:
     return False
 
 
+def updated_in_place(alloc: Allocation, job: Job) -> Allocation:
+    """The allocation moved onto ``job``'s version where it stands: same
+    id, same node, no restart (scheduler/util.go inplaceUpdate)."""
+    a = alloc.copy_for_update()
+    a.job = job
+    a.job_version = job.version
+    return a
+
+
 class AllocNameIndex:
     """Bitmap-style tracker of claimed alloc name indices per group
     (reconcile_util.go allocNameIndex): freed indices are reused so names

@@ -1183,10 +1183,10 @@ class Worker:
             overlay=overlay,
             **kw,
         )
-        if ev.type in ("service", "batch"):
-            # the generic scheduler writes the pass's phases itself, the
-            # ones the batched pass writes (prepare, invoke_scheduler,
-            # build_plan, submit_plan)
+        if ev.type in ("service", "batch", "system", "sysbatch"):
+            # the generic and the system scheduler write the pass's phases
+            # themselves, the ones the batched pass writes (prepare,
+            # invoke_scheduler, build_plan, submit_plan)
             sched.process(ev)
             return
         with tracer.phase(

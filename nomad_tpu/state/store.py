@@ -23,8 +23,10 @@ from __future__ import annotations
 
 import threading
 import time as _time
+from contextlib import contextmanager
 from typing import Callable, Iterable, Optional
 
+from ..obs.trace import global_tracer as tracer
 from ..structs import (
     ALLOC_CLIENT_LOST,
     ALLOC_DESIRED_STOP,
@@ -328,6 +330,25 @@ class StateSnapshot:
         return self._t.indexes.get(table, 0)
 
 
+@contextmanager
+def _plan_write_span(results):
+    """``plan_apply.store_write``: the store's write of committed plan
+    results, inside the applier's ``plan_apply.commit`` (tags ``allocs``,
+    the placements and in-place updates; ``stops``, the stops and
+    evictions)."""
+    with tracer.span("plan_apply.store_write") as sp:
+        if sp is not None:
+            sp.tags["allocs"] = sum(
+                sum(map(len, r.node_allocation.values())) for r in results
+            )
+            sp.tags["stops"] = sum(
+                sum(map(len, r.node_update.values()))
+                + sum(map(len, r.node_preemptions.values()))
+                for r in results
+            )
+        yield sp
+
+
 class StateStore(StateSnapshot):
     """The live, writable store. Reads see the latest committed state."""
 
@@ -593,6 +614,8 @@ class StateStore(StateSnapshot):
         table = self._own("allocs")
         by_node = self._own("allocs_by_node")
         by_job = self._own("allocs_by_job")
+        node_adds: dict = {}  # index key -> ids this write adds
+        job_adds: dict = {}
         for a in allocs:
             # Denormalize: plans ship with alloc.job stripped
             # (Plan.normalize); re-attach the stored job at the alloc's
@@ -625,15 +648,24 @@ class StateStore(StateSnapshot):
                     a.client_status = existing.client_status
                 if existing.node_id and existing.node_id != a.node_id:
                     self._idx_del(by_node, existing.node_id, a.id)
+                    node_adds.get(existing.node_id, set()).discard(a.id)
                     self.journal.note(index, "node_allocs", existing.node_id)
             else:
                 a.create_index = index
             a.modify_index = index
             table[a.id] = a
             if a.node_id:
-                self._idx_add(by_node, a.node_id, a.id)
+                node_adds.setdefault(a.node_id, set()).add(a.id)
                 self.journal.note(index, "node_allocs", a.node_id)
-            self._idx_add(by_job, (a.namespace, a.job_id), a.id)
+            job_adds.setdefault((a.namespace, a.job_id), set()).add(a.id)
+        # each index entry is rebuilt once a write, not once an allocation:
+        # a frozenset union copies the set, and a system job's holds one
+        # id a node for every version it has run
+        for adds, d in ((node_adds, by_node), (job_adds, by_job)):
+            for key, ids in adds.items():
+                cur = d.get(key, frozenset())
+                if not cur.issuperset(ids):
+                    d[key] = cur | ids
 
     def delete_allocs(self, index: int, alloc_ids: Iterable[str]) -> None:
         with self._lock:
@@ -718,7 +750,7 @@ class StateStore(StateSnapshot):
     def upsert_plan_results(self, index: int, result: PlanResult, eval_id: str = ""):
         """Apply a committed plan atomically: stops/evictions, preempted
         allocs, then placements (state_store.go UpsertPlanResults)."""
-        with self._lock:
+        with self._lock, _plan_write_span((result,)):
             self._apply_plan_result_locked(index, result)
             self._bump(index, "allocs", "deployments")
 
@@ -729,7 +761,7 @@ class StateStore(StateSnapshot):
         store transaction: every member's stops/preemptions/placements
         land under a single lock acquisition and a single index bump, so
         a batch of B plans costs one listener fan-out instead of B."""
-        with self._lock:
+        with self._lock, _plan_write_span(results):
             for result in results:
                 self._apply_plan_result_locked(index, result)
             self._bump(index, "allocs", "deployments")

@@ -139,6 +139,21 @@ class Evaluation:
             failed_tg_allocs=dict(failed_tg_allocs),
         )
 
+    def next_rolling_eval(self, wait_s: float, now: float) -> "Evaluation":
+        """structs.Evaluation.NextRollingEval: where a rolling update
+        reached its limit, the eval that goes on after ``wait_s``."""
+        return Evaluation(
+            namespace=self.namespace,
+            priority=self.priority,
+            type=self.type,
+            triggered_by=TRIGGER_ROLLING_UPDATE,
+            job_id=self.job_id,
+            job_modify_index=self.job_modify_index,
+            status=EVAL_STATUS_PENDING,
+            wait_until_unix=now + wait_s,
+            previous_eval=self.id,
+        )
+
     def create_failed_follow_up_eval(self, wait_s: float, now: float) -> "Evaluation":
         return Evaluation(
             namespace=self.namespace,
