@@ -10,8 +10,17 @@ the scheduler scored with bandwidth/port-count aggregates only (the
 guess-then-verify split, SURVEY.md §7 "hard parts").
 
 The reference parallelizes per-node verification over an EvaluatePool of
-NumCPU/2 goroutines (plan_apply_pool.go:18-40); here the same check is a
-vectorized host pass (and the touched-node count per plan is small).
+NumCPU/2 goroutines (plan_apply_pool.go:18-40). Here a plan's placing
+nodes are checked in one array compare against the store's per-node
+live-usage index (``StateSnapshot.node_usage``): the index row, less the
+stored live copy of each stop, eviction and in-place update, plus each
+placement, against the node's capacity — exact integers, so the walk's
+answer by construction. A node the index cannot judge (gone, terminal or
+closed; holding or getting ports, device instances or device asks) goes to
+the exact walk, ``evaluate_node_plan`` (``_evaluate_node_members`` for a
+merged plan), which re-runs AllocsFit over every allocation the node
+holds. Counters ``nomad.plan.nodes_indexed`` / ``nodes_walked`` and the
+``plan_apply.evaluate`` span's tags ``indexed`` / ``walked`` say which.
 """
 
 from __future__ import annotations
@@ -32,6 +41,7 @@ from ..structs import (
     Plan,
     PlanResult,
     allocs_fit,
+    needs_exact_fit,
 )
 from ..structs.resources import node_comparable_capacity
 from ..utils.metrics import count_swallowed, global_metrics as metrics
@@ -176,20 +186,93 @@ def _csi_claims_ok(snapshot, allocs, claimed: dict) -> bool:
     return True
 
 
+def _indexed_fits(snapshot, changes) -> dict[str, bool]:
+    """Fit from the store's per-node live-usage index, in one array
+    compare: for each node of ``changes`` (node id → (removed, placed)),
+    the ``node_usage`` row, less the stored live copy of each removed or
+    replaced allocation the node holds, plus each live placement, against
+    ``node_comparable_capacity`` on the three dimensions ``superset``
+    compares. The sums are exact integers, so a node fits here exactly
+    when ``evaluate_node_plan`` admits it. A node that is gone, terminal or
+    closed, or that holds or gets an allocation ``needs_exact_fit``, is
+    left out of the result, for the exact walk."""
+    memo: dict = {}
+    ids: list[str] = []
+    rows: list[tuple] = []
+    for node_id, (removed, placed) in changes.items():
+        node = snapshot.node_by_id(node_id)
+        if node is None or node.terminal_status() or not _open(node):
+            continue
+        usage = snapshot.node_usage(node_id)
+        if usage[4] or any(needs_exact_fit(a, memo) for a in placed):
+            continue
+        cpu, mem, disk = usage[0], usage[1], usage[2]
+        held = snapshot.node_alloc_ids(node_id)
+        gone: set = set()  # stored copies stopped, evicted or replaced here
+        for group in (*removed, placed):
+            for a in group:
+                if a.id in held and a.id not in gone:
+                    gone.add(a.id)
+                    stored = snapshot.alloc_by_id(a.id)
+                    if stored is not None and not stored.terminal_status():
+                        r = stored.comparable_resources()
+                        cpu -= r.cpu
+                        mem -= r.memory_mb
+                        disk -= r.disk_mb
+        for a in placed:
+            if not a.terminal_status():
+                r = a.comparable_resources()
+                cpu += r.cpu
+                mem += r.memory_mb
+                disk += r.disk_mb
+        # node_comparable_capacity on the dimensions superset compares
+        cap, reserved = node.node_resources, node.reserved
+        ids.append(node_id)
+        rows.append((
+            cap.cpu - reserved.cpu,
+            cap.memory_mb - reserved.memory_mb,
+            cap.disk_mb - reserved.disk_mb,
+            cpu, mem, disk,
+        ))
+    if not ids:
+        return {}
+    table = np.array(rows)
+    fits = (table[:, :3] >= table[:, 3:]).all(axis=1)
+    return dict(zip(ids, fits.tolist()))
+
+
 def evaluate_plan(snapshot, plan: Plan) -> PlanResult:
     """Per-node verify + partial commit (plan_apply.go:400-596): nodes that
     fail verification are dropped from the result; when anything is
     dropped, refresh_index tells the worker to retry on fresher state."""
+    return _evaluate_plan(snapshot, plan)[0]
+
+
+def _evaluate_plan(snapshot, plan: Plan) -> tuple[PlanResult, int, int]:
+    """``evaluate_plan``, with the counts of placing nodes the index and
+    the exact walk judged."""
     result = PlanResult(alloc_index=0)
     rejected = []
     touched = set(plan.node_allocation) | set(plan.node_update) | set(
         plan.node_preemptions
     )
+    fits = _indexed_fits(snapshot, {
+        node_id: (
+            (
+                plan.node_update.get(node_id, ()),
+                plan.node_preemptions.get(node_id, ()),
+            ),
+            placed,
+        )
+        for node_id, placed in plan.node_allocation.items()
+    })
     claimed: dict[str, tuple[int, int]] = {}  # vid → (readers, writers)
     for node_id in sorted(touched):
         has_new = node_id in plan.node_allocation
         if has_new:
-            ok, reason = evaluate_node_plan(snapshot, plan, node_id)
+            ok = fits.get(node_id)
+            if ok is None:
+                ok, _reason = evaluate_node_plan(snapshot, plan, node_id)
             if ok and not _csi_claims_ok(
                 snapshot, plan.node_allocation[node_id], claimed
             ):
@@ -216,7 +299,7 @@ def evaluate_plan(snapshot, plan: Plan) -> PlanResult:
         )
     result.deployment = plan.deployment
     result.deployment_updates = list(plan.deployment_updates)
-    return result
+    return result, len(fits), len(plan.node_allocation) - len(fits)
 
 
 def _merged_touched_nodes(plans) -> dict[str, list[int]]:
@@ -235,9 +318,10 @@ def _merged_touched_nodes(plans) -> dict[str, list[int]]:
 def _fast_path_slack(snapshot, node_id, member_plans):
     """Vectorized-verify candidacy for one node: when every touching
     member only ADDS networkless, deviceless, claim-free allocations, the
-    whole union check reduces to ``free - sum(asks) >= 0`` per dimension.
-    Returns that slack vector, or None to route the node to the exact
-    per-member walk (which reproduces evaluate_node_plan bit for bit)."""
+    whole union check reduces to ``free - sum(asks) >= 0`` per dimension,
+    ``free`` read from the store's ``node_usage`` row. Returns that slack,
+    or None to route the node to the exact per-member walk (which
+    reproduces evaluate_node_plan bit for bit)."""
     node = snapshot.node_by_id(node_id)
     if node is None or node.terminal_status():
         return None
@@ -248,8 +332,7 @@ def _fast_path_slack(snapshot, node_id, member_plans):
         if node_id in mp.node_update or node_id in mp.node_preemptions:
             return None
         new_allocs.extend(mp.node_allocation.get(node_id, ()))
-    existing = snapshot.allocs_by_node(node_id)
-    existing_ids = {a.id for a in existing}
+    existing_ids = snapshot.node_alloc_ids(node_id)
     for a in new_allocs:
         if (
             a.id in existing_ids  # in-place update: replacement math
@@ -258,15 +341,22 @@ def _fast_path_slack(snapshot, node_id, member_plans):
             or a.job is not None  # un-normalized: CSI/device asks possible
         ):
             return None
-    free = node_comparable_capacity(node).to_vector()
-    for a in existing:
-        if a.terminal_status():
-            continue
-        if a.allocated_networks or a.allocated_devices or a.job is not None:
-            return None
-        free = free - a.comparable_resources().to_vector()
+    usage = snapshot.node_usage(node_id)
+    if usage[4]:
+        return None  # a live allocation there holds ports or devices
+    cap = node_comparable_capacity(node)
+    free = [
+        cap.cpu - usage[0],
+        cap.memory_mb - usage[1],
+        cap.disk_mb - usage[2],
+        cap.bandwidth_mbits - usage[3],
+    ]
     for a in new_allocs:
-        free = free - a.comparable_resources().to_vector()
+        r = a.comparable_resources()
+        free[0] -= r.cpu
+        free[1] -= r.memory_mb
+        free[2] -= r.disk_mb
+        free[3] -= r.bandwidth_mbits
     return free
 
 
@@ -340,6 +430,12 @@ def evaluate_merged_plan(snapshot, plans) -> list[PlanResult]:
     CSI / eviction math) drops to the exact member-order walk, where only
     the members that no longer fit are rejected. Each rejected member
     gets its own ``refresh_index``; siblings commit untouched."""
+    return _evaluate_merged_plan(snapshot, plans)[0]
+
+
+def _evaluate_merged_plan(snapshot, plans) -> tuple[list[PlanResult], int, int]:
+    """``evaluate_merged_plan``, with the counts of placing nodes the index
+    admitted and the exact walk judged."""
     results = [PlanResult(alloc_index=0) for _ in plans]
     touched = _merged_touched_nodes(plans)
     slow_nodes: list[str] = []
@@ -354,10 +450,12 @@ def evaluate_merged_plan(snapshot, plans) -> list[PlanResult]:
         else:
             fast_ids.append(node_id)
             fast_rows.append(slack)
+    indexed = 0
     if fast_ids:
-        fits = (np.stack(fast_rows) >= 0).all(axis=1)
+        fits = (np.array(fast_rows) >= 0).all(axis=1)
         for node_id, node_fits in zip(fast_ids, fits):
             if node_fits:
+                indexed += 1
                 for i in touched[node_id]:
                     allocs = plans[i].node_allocation.get(node_id)
                     if allocs:
@@ -382,7 +480,11 @@ def evaluate_merged_plan(snapshot, plans) -> list[PlanResult]:
         res.deployment_updates = list(mp.deployment_updates)
         if res.rejected_nodes:
             res.refresh_index = refresh
-    return results
+    walked = sum(
+        1 for node_id in slow_nodes
+        if any(node_id in plans[i].node_allocation for i in touched[node_id])
+    )
+    return results, indexed, walked
 
 
 def preemption_evals(store, result: PlanResult) -> list:
@@ -414,6 +516,14 @@ def preemption_evals(store, result: PlanResult) -> list:
     if evals:
         metrics.incr("nomad.plan.preemption_evals", len(evals))
     return evals
+
+
+def _count_checked(indexed: int, walked: int) -> None:
+    """How many placing nodes the index and the exact walk judged; both
+    counters exist from the first plan, so a walk that never runs reads
+    0."""
+    metrics.incr("nomad.plan.nodes_indexed", indexed)
+    metrics.incr("nomad.plan.nodes_walked", walked)
 
 
 def _count_committed(results) -> None:
@@ -522,9 +632,13 @@ class PlanApplier:
                 )
             with tracer.span(
                 "plan_apply.evaluate", timer="nomad.plan.evaluate"
-            ):
+            ) as ev_sp:
                 chaos_site("plan_apply.verify")
-                result = evaluate_plan(self.store, plan)
+                result, indexed, walked = _evaluate_plan(self.store, plan)
+                _count_checked(indexed, walked)
+                if ev_sp is not None:
+                    ev_sp.tags["indexed"] = indexed
+                    ev_sp.tags["walked"] = walked
             if sp is not None:
                 sp.tags["rejected_nodes"] = len(result.rejected_nodes)
             if not result.is_no_op() or result.deployment is not None:
@@ -579,7 +693,8 @@ class PlanApplier:
         lock: one union verify pass, one FSM/Raft entry, one store index
         bump — per-member attribution preserved in the returned results.
         Returns (results, phase timings: seconds and ``perf_counter``
-        start stamps); the apply loop records them as spans of the pass."""
+        start stamps, and the evaluate stage's tags); the apply loop
+        records them as spans of the pass."""
         t_apply = time.perf_counter()
         with self._lock:
             lane_mode = self.lanes is not None and mplan.owner_worker >= 0
@@ -595,7 +710,7 @@ class PlanApplier:
             stale = [self._token_stale(p) for p in mplan.plans]
             if any(stale):
                 live_idx = [i for i, s in enumerate(stale) if not s]
-                live = evaluate_merged_plan(
+                live, indexed, walked = _evaluate_merged_plan(
                     self.store, [mplan.plans[i] for i in live_idx]
                 )
                 results = [
@@ -604,7 +719,10 @@ class PlanApplier:
                 for i, res in zip(live_idx, live):
                     results[i] = res
             else:
-                results = evaluate_merged_plan(self.store, mplan.plans)
+                results, indexed, walked = _evaluate_merged_plan(
+                    self.store, mplan.plans
+                )
+            _count_checked(indexed, walked)
             if lane_mode:
                 self._check_lane_rejections(mplan, results)
             evaluate_s = time.perf_counter() - t_evaluate
@@ -675,6 +793,7 @@ class PlanApplier:
                 "apply_start": t_apply,
                 "evaluate_s": evaluate_s,
                 "evaluate_start": t_evaluate,
+                "evaluate_tags": {"indexed": indexed, "walked": walked},
                 "commit_s": commit_s,
                 "commit_start": t_commit,
             }

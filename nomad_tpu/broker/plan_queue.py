@@ -227,6 +227,7 @@ class PlanApplyLoop:
                     stage_sp = tracer.add_span(
                         eid, f"plan_apply.{stage}", timings[f"{stage}_s"],
                         start=timings[f"{stage}_start"], parent=sp,
+                        tags=timings.get(f"{stage}_tags"),
                     )
                 write = tracer.newest(eid, "plan_apply.store_write")
                 if (

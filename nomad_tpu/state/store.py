@@ -24,6 +24,7 @@ from __future__ import annotations
 import threading
 import time as _time
 from contextlib import contextmanager
+from operator import add
 from typing import Callable, Iterable, Optional
 
 from ..obs.trace import global_tracer as tracer
@@ -35,9 +36,33 @@ from ..structs import (
     Job,
     Node,
     PlanResult,
+    needs_exact_fit,
 )
 
 JOB_TRACKED_VERSIONS = 6  # structsJobTrackedVersions
+
+NO_USAGE = (0, 0, 0, 0, 0)  # a node_usage row: no live allocation
+
+
+def _count_usage(rows: dict, allocs, sign: int) -> dict:
+    """Add (``sign`` 1) or take away (-1) the live allocations' shares of
+    their nodes' ``node_usage`` rows, in ``rows`` (node id → list of
+    five); returns ``rows``."""
+    memo: dict = {}
+    for a in allocs:
+        if not a.node_id or a.terminal_status():
+            continue
+        r = a.comparable_resources()
+        row = rows.get(a.node_id)
+        if row is None:
+            row = rows[a.node_id] = [0, 0, 0, 0, 0]
+        row[0] += sign * r.cpu
+        row[1] += sign * r.memory_mb
+        row[2] += sign * r.disk_mb
+        row[3] += sign * r.bandwidth_mbits
+        if needs_exact_fit(a, memo):
+            row[4] += sign
+    return rows
 
 
 class SchedulerConfiguration:
@@ -87,6 +112,7 @@ class _Tables:
         "evals",
         "allocs",
         "allocs_by_node",
+        "node_usage",
         "allocs_by_job",
         "evals_by_job",
         "deployments",
@@ -108,6 +134,10 @@ class _Tables:
         self.evals: dict[str, Evaluation] = {}
         self.allocs: dict[str, Allocation] = {}
         self.allocs_by_node: dict[str, frozenset[str]] = {}
+        # node id → (cpu, memory_mb, disk_mb, bandwidth_mbits, walk): the
+        # comparable resources summed over the node's live allocations, and
+        # how many of them only the applier's exact walk can judge
+        self.node_usage: dict[str, tuple] = {}
         self.allocs_by_job: dict[tuple[str, str], frozenset[str]] = {}
         self.evals_by_job: dict[tuple[str, str], frozenset[str]] = {}
         self.deployments: dict[str, object] = {}
@@ -129,6 +159,7 @@ class _Tables:
         "evals",
         "allocs",
         "allocs_by_node",
+        "node_usage",
         "allocs_by_job",
         "evals_by_job",
         "deployments",
@@ -161,8 +192,12 @@ class ChangeJournal:
         self._lock = threading.Lock()
 
     def note(self, index: int, table: str, key) -> None:
+        self.note_all(index, table, (key,))
+
+    def note_all(self, index: int, table: str, keys) -> None:
+        """One record a key, under one acquisition of the lock."""
         with self._lock:
-            self._entries.append((index, table, key))
+            self._entries.extend((index, table, key) for key in keys)
             if len(self._entries) > self._cap:
                 drop = len(self._entries) // 2
                 self._floor = self._entries[drop - 1][0]
@@ -252,6 +287,17 @@ class StateSnapshot:
     def allocs_by_node(self, node_id: str) -> list[Allocation]:
         ids = self._t.allocs_by_node.get(node_id, frozenset())
         return [self._t.allocs[i] for i in ids if i in self._t.allocs]
+
+    def node_alloc_ids(self, node_id: str) -> frozenset:
+        """The ids ``allocs_by_node`` reads, terminal ones included."""
+        return self._t.allocs_by_node.get(node_id, frozenset())
+
+    def node_usage(self, node_id: str) -> tuple:
+        """(cpu, memory_mb, disk_mb, bandwidth_mbits, walk) of the node's
+        non-terminal allocations: their ``comparable_resources()`` summed,
+        and how many of them ``needs_exact_fit``. The plan applier's fit
+        check reads it in place of walking every allocation the node held."""
+        return self._t.node_usage.get(node_id, NO_USAGE)
 
     def allocs_by_node_terminal(self, node_id: str, terminal: bool) -> list[Allocation]:
         return [
@@ -443,6 +489,20 @@ class StateStore(StateSnapshot):
         else:
             d.pop(key, None)
 
+    def _apply_usage(self, gone, came) -> None:
+        """Move one write's allocations in the ``node_usage`` rows: the
+        stored copies it replaced or removed (``gone``) out, the copies it
+        stored (``came``) in, once a node. A stored allocation is never
+        changed in place (the snapshots share it), so its share is what
+        was counted when it came."""
+        deltas = _count_usage(_count_usage({}, gone, -1), came, 1)
+        changed = [(n, d) for n, d in deltas.items() if any(d)]
+        if not changed:
+            return
+        usage = self._own("node_usage")
+        for node_id, d in changed:
+            usage[node_id] = tuple(map(add, usage.get(node_id, NO_USAGE), d))
+
     # -- nodes ------------------------------------------------------------
     def upsert_node(self, index: int, node: Node) -> None:
         with self._lock:
@@ -616,6 +676,9 @@ class StateStore(StateSnapshot):
         by_job = self._own("allocs_by_job")
         node_adds: dict = {}  # index key -> ids this write adds
         job_adds: dict = {}
+        moved_from: set = set()  # nodes an allocation left
+        gone: list = []  # stored copies this write replaces
+        came: list = []
         for a in allocs:
             # Denormalize: plans ship with alloc.job stripped
             # (Plan.normalize); re-attach the stored job at the alloc's
@@ -649,14 +712,16 @@ class StateStore(StateSnapshot):
                 if existing.node_id and existing.node_id != a.node_id:
                     self._idx_del(by_node, existing.node_id, a.id)
                     node_adds.get(existing.node_id, set()).discard(a.id)
-                    self.journal.note(index, "node_allocs", existing.node_id)
+                    moved_from.add(existing.node_id)
             else:
                 a.create_index = index
             a.modify_index = index
             table[a.id] = a
+            if existing is not None:
+                gone.append(existing)
+            came.append(a)
             if a.node_id:
                 node_adds.setdefault(a.node_id, set()).add(a.id)
-                self.journal.note(index, "node_allocs", a.node_id)
             job_adds.setdefault((a.namespace, a.job_id), set()).add(a.id)
         # each index entry is rebuilt once a write, not once an allocation:
         # a frozenset union copies the set, and a system job's holds one
@@ -666,19 +731,26 @@ class StateStore(StateSnapshot):
                 cur = d.get(key, frozenset())
                 if not cur.issuperset(ids):
                     d[key] = cur | ids
+        self._apply_usage(gone, came)
+        self.journal.note_all(
+            index, "node_allocs", moved_from | node_adds.keys()
+        )
 
     def delete_allocs(self, index: int, alloc_ids: Iterable[str]) -> None:
         with self._lock:
             table = self._own("allocs")
             by_node = self._own("allocs_by_node")
             by_job = self._own("allocs_by_job")
+            gone = []
             for aid in alloc_ids:
                 a = table.pop(aid, None)
                 if a is not None:
+                    gone.append(a)
                     if a.node_id:
                         self._idx_del(by_node, a.node_id, aid)
                         self.journal.note(index, "node_allocs", a.node_id)
                     self._idx_del(by_job, (a.namespace, a.job_id), aid)
+            self._apply_usage(gone, ())
             self._bump(index, "allocs")
 
     def delete_deployment(self, index: int, deployment_id: str) -> None:
@@ -700,6 +772,8 @@ class StateStore(StateSnapshot):
 
         with self._lock:
             table = self._own("allocs")
+            gone: list = []
+            came: list = []
             for upd in updates:
                 existing = table.get(upd.id)
                 if existing is None:
@@ -718,8 +792,14 @@ class StateStore(StateSnapshot):
                     a.deployment_status = upd.deployment_status
                 a.modify_index = index
                 table[a.id] = a
+                if a.terminal_status() != existing.terminal_status():
+                    # a complete or failed frees the allocation's share;
+                    # its node and resources are the server's, unchanged
+                    gone.append(existing)
+                    came.append(a)
                 if a.node_id:
                     self.journal.note(index, "node_allocs", a.node_id)
+            self._apply_usage(gone, came)
             self._bump(index, "allocs")
 
     # -- deployments -------------------------------------------------------

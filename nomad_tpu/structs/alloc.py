@@ -240,3 +240,25 @@ class Allocation:
         import copy
 
         return copy.copy(self)
+
+
+def needs_exact_fit(alloc, asks_memo: dict) -> bool:
+    """Does fitting ``alloc`` take more than its comparable resources:
+    ports (the applier's NetworkIndex re-check), device instances, or a
+    job that asks for devices (AllocsFit's device accounting)? Only the
+    applier's exact walk judges a node holding or getting such an
+    allocation. ``asks_memo`` caches the group's answer per (job, group)
+    for the allocations of one call."""
+    if alloc.allocated_networks or alloc.allocated_devices:
+        return True
+    job = alloc.job
+    if job is None:
+        return False
+    key = (id(job), alloc.task_group)
+    asks = asks_memo.get(key)
+    if asks is None:
+        tg = job.lookup_task_group(alloc.task_group)
+        asks = asks_memo[key] = tg is not None and any(
+            t.resources.devices for t in tg.tasks
+        )
+    return asks

@@ -137,6 +137,15 @@ def test_the_store_write_lies_in_the_commit_with_its_counts(
     assert write["tags"]["stops"] == stops
 
 
+@pytest.mark.parametrize("kind,indexed", [
+    ("register", N), ("destructive", N), ("inplace", N), ("stop", 0),
+])
+def test_the_verify_counts_the_nodes_the_usage_index_judged(
+        system_traces, kind, indexed):
+    tags = _one(system_traces[kind], "plan_apply.evaluate")["tags"]
+    assert (tags["indexed"], tags["walked"]) == (indexed, 0)
+
+
 def test_a_pass_without_placements_neither_scores_nor_walks(system_traces):
     for kind in ("inplace", "stop"):
         names = {s["name"] for s in system_traces[kind]["spans"]}

@@ -292,9 +292,17 @@ def test_a_stopped_job_stops_everything_and_places_nothing(cluster):
 
 def test_an_update_of_a_live_system_job_places_every_node_served_path():
     """The probe: before, the update's eval was acked with no plan and 0 of
-    N nodes held the new version."""
+    N nodes held the new version. The applier checks every node of it
+    from the store's usage index: the exact walk judges none."""
     from nomad_tpu.server import Server, ServerConfig
+    from nomad_tpu.utils.metrics import global_metrics
 
+    def counts():
+        c = global_metrics.snapshot()["counters"]
+        return [c.get(f"nomad.plan.nodes_{k}", 0)
+                for k in ("indexed", "walked")]
+
+    before = counts()
     server = Server(ServerConfig(num_workers=1, num_batch_workers=1))
     server.establish_leadership()
     try:
@@ -315,5 +323,7 @@ def test_an_update_of_a_live_system_job_places_every_node_served_path():
         version = server.store.job_by_id("default", job.id).version
         assert version == 1
         assert sorted(a.job_version for a in live) == [version] * n
+        indexed, walked = (b - a for a, b in zip(before, counts()))
+        assert walked == 0 and indexed >= 2 * n
     finally:
         server.shutdown()
