@@ -34,6 +34,7 @@ import numpy as np
 from ..chaos.plane import active_plane, chaos_site, note_committed
 from ..obs.trace import global_tracer as tracer
 from ..structs import (
+    ALLOC_CLIENT_LOST,
     NODE_SCHED_ELIGIBLE,
     Allocation,
     MergedPlan,
@@ -527,12 +528,17 @@ def _count_checked(indexed: int, walked: int) -> None:
 
 
 def _count_committed(results) -> None:
-    """What the committed results stop and which rollouts they open."""
-    stops = sum(
-        len(allocs) for res in results for allocs in res.node_update.values()
-    )
-    if stops:
-        metrics.incr("nomad.plan.stops_committed", stops)
+    """What the committed results stop, how many of those stops mark an
+    allocation lost with its node, and which rollouts they open."""
+    stopped = [
+        a for res in results for allocs in res.node_update.values()
+        for a in allocs
+    ]
+    if stopped:
+        metrics.incr("nomad.plan.stops_committed", len(stopped))
+    lost = sum(1 for a in stopped if a.client_status == ALLOC_CLIENT_LOST)
+    if lost:
+        metrics.incr("nomad.plan.allocs_lost", lost)
     created = sum(1 for res in results if res.deployment is not None)
     if created:
         metrics.incr("nomad.deployment.created", created)

@@ -334,7 +334,9 @@ class ValueBlocks:
 
     value_ids: np.ndarray  # i32[B, N]  (−1 = node has no value)
     counts0: np.ndarray  # f32[B, V] initial combined-use counts
-    desired: np.ndarray  # f32[B, V] target-mode desired; −1 = untargeted
+    # f32[B, V] target-mode desired, −1 = untargeted; in an even block
+    # EVEN_HELD_AT_ZERO (0) at a value the combined-use map holds at 0
+    desired: np.ndarray
     caps: np.ndarray  # f32[B, V] distinct_property allowed-count; +inf else
     weights: np.ndarray  # f32[B] target-mode relative weight (w / Σw)
     kinds: np.ndarray  # i32[B] BLOCK_TARGET_SPREAD/EVEN_SPREAD/DISTINCT_CAP
@@ -352,6 +354,15 @@ class ValueBlocks:
         from .score import BLOCK_DISTINCT_CAP
 
         return bool((self.kinds != BLOCK_DISTINCT_CAP).any())
+
+    @property
+    def held_at_zero(self) -> np.ndarray:
+        """bool[B, V]: the values an even block's combined-use map holds
+        at a count of 0, which the kernels read from ``desired``."""
+        from .score import BLOCK_EVEN_SPREAD, EVEN_HELD_AT_ZERO
+
+        return (self.kinds == BLOCK_EVEN_SPREAD)[:, None] & (
+            self.desired == EVEN_HELD_AT_ZERO)
 
 
 def pad_value_blocks(blocks: list, pn: int) -> dict:
@@ -653,19 +664,20 @@ def _combined_counts_vector(pset, vocab):
     """Flatten a PropertySet's combined-use map onto value ids. Values
     used by allocations but carried by no current node (e.g. only on a
     removed node) get *phantom* slots appended past the node vocab so
-    even-spread min/max still sees them."""
+    even-spread min/max still sees them. Returns ``(counts, ids,
+    cleared)``: ``cleared`` holds the ids of the values the map holds at
+    a count of 0 (every allocation there stopped by the plan)."""
     combined = pset.combined_use()
     extra = {v: n for v, n in combined.items() if v not in vocab}
     nv = len(vocab) + len(extra)
     counts = np.zeros(max(nv, 1), dtype=np.float32)
     ids = dict(vocab)
     for v, n in combined.items():
-        if v in ids:
-            counts[ids[v]] = n
-        else:
+        if v not in ids:
             ids[v] = len(ids)
-            counts[ids[v]] = n
-    return counts, ids
+        counts[ids[v]] = n
+    cleared = [ids[v] for v, n in combined.items() if n == 0]
+    return counts, ids, cleared
 
 
 def _value_blocks(
@@ -690,6 +702,7 @@ def _value_blocks(
         BLOCK_DISTINCT_CAP,
         BLOCK_EVEN_SPREAD,
         BLOCK_TARGET_SPREAD,
+        EVEN_HELD_AT_ZERO,
     )
 
     spreads = job.spreads_for_group(tg)
@@ -726,7 +739,7 @@ def _value_blocks(
     for sp in spreads:
         node_vals, vocab = ct.attr_column(sp.attribute)
         pset = build_pset(sp.attribute, tg.name)
-        counts, ids = _combined_counts_vector(pset, vocab)
+        counts, ids, cleared = _combined_counts_vector(pset, vocab)
         nv = counts.shape[0]
         desired = np.full(nv, -1.0, dtype=np.float32)
         if sp.targets:
@@ -756,6 +769,10 @@ def _value_blocks(
                         desired[vid] = implicit
             kinds_l.append(BLOCK_TARGET_SPREAD)
         else:
+            # a value the map holds at 0 takes part in the even boost's
+            # min (``EVEN_HELD_AT_ZERO``): the rack of a lost node's
+            # allocations, every one stopped by this plan
+            desired[cleared] = EVEN_HELD_AT_ZERO
             kinds_l.append(BLOCK_EVEN_SPREAD)
         cols.append(node_vals)
         counts_l.append(counts)
@@ -778,7 +795,7 @@ def _value_blocks(
             ] = int(ct.num_nodes)
             continue
         pset = build_pset(c.l_target, scope, allowed)
-        counts, ids = _combined_counts_vector(pset, vocab)
+        counts, _ids, _cleared = _combined_counts_vector(pset, vocab)
         nv = counts.shape[0]
         # nodes missing the property are infeasible (UsedCount error path)
         missing = (node_vals < 0) & eligible

@@ -90,6 +90,10 @@ BLOCK_TARGET_SPREAD = 0
 BLOCK_EVEN_SPREAD = 1
 BLOCK_DISTINCT_CAP = 2
 BLOCK_INACTIVE = -1
+# ``desired`` of an even-spread block at a value the job's combined-use map
+# holds at a count of 0 (every allocation there stopped by the plan: the
+# rack of a lost node); -1 at every other value
+EVEN_HELD_AT_ZERO = 0.0
 
 # extra greedy candidates emitted beyond ``count`` per lane, consumed by
 # repair_batch_conflicts when optimistic batch lanes collide on a node
@@ -422,10 +426,16 @@ def _block_tables(c, desired, caps, weights, kinds):
     spread.go:145-152).
 
     Even mode (spread.go:178-228 evenSpreadScoreBoost): boosts derive
-    from the min/max of *positive* counts. (The reference computes min
-    over a Go map that may contain cleared-to-zero entries, making the
-    min==0 branch order-dependent; we define min over positive counts,
-    which matches the deterministic reading.)
+    from the min/max over the values of the job's combined-use map: those
+    with a positive count, and those the map holds at 0
+    (``EVEN_HELD_AT_ZERO`` in ``desired``: every allocation there stopped
+    by the plan). A value the job never used is not in the map. A min of 0
+    takes the source's own branches: -1 off the min, +1 at it. (Go's loop
+    lets a later value overwrite a zero min, so the source's result there
+    follows the map's iteration order; the branches written for a zero min
+    are the reading taken.) With no positive count the boost is 0, as for
+    an empty map: a plan that stops every allocation of the job starts its
+    spread afresh.
 
     Distinct caps (feasible.go:604): allow[v] = c[v] < cap[v].
     """
@@ -436,15 +446,18 @@ def _block_tables(c, desired, caps, weights, kinds):
         -1.0,
     )
     # even
-    pos = c > 0
-    any_pos = jnp.any(pos, axis=1, keepdims=True)  # [B, 1]
-    minc = jnp.min(jnp.where(pos, c, jnp.inf), axis=1, keepdims=True)
-    maxc = jnp.max(jnp.where(pos, c, -jnp.inf), axis=1, keepdims=True)
-    at_min = c == minc
+    held = (c > 0) | (desired == EVEN_HELD_AT_ZERO)
+    any_pos = jnp.any(c > 0, axis=1, keepdims=True)  # [B, 1]
+    minc = jnp.min(jnp.where(held, c, jnp.inf), axis=1, keepdims=True)
+    maxc = jnp.max(jnp.where(held, c, -jnp.inf), axis=1, keepdims=True)
+    safe_min = jnp.maximum(minc, 1e-9)
     e_boost = jnp.where(
-        at_min,
-        jnp.where(minc == maxc, -1.0, (maxc - minc) / jnp.maximum(minc, 1e-9)),
-        (minc - c) / jnp.maximum(minc, 1e-9),
+        c == minc,
+        jnp.where(
+            minc == maxc, -1.0,
+            jnp.where(minc > 0, (maxc - minc) / safe_min, 1.0),
+        ),
+        jnp.where(minc > 0, (minc - c) / safe_min, -1.0),
     )
     e_boost = jnp.where(any_pos, e_boost, 0.0)
 
@@ -1537,10 +1550,14 @@ class PlacementKernel:
     def _needs_exact_scan(a) -> bool:
         """Cap (distinct_property) blocks can overshoot a per-value
         budget within one chunk, and small groups compile to short exact
-        scans anyway — both stay on the stepwise path."""
+        scans anyway — both stay on the stepwise path. So does an even
+        block whose map holds a value at 0: its boost is flat off that
+        min, and the one-per-value chunks no longer follow greedy."""
         if a.count <= EXACT_SCAN_MAX_COUNT:
             return True
-        return bool((a.blocks.kinds == BLOCK_DISTINCT_CAP).any())
+        b = a.blocks
+        return bool((b.kinds == BLOCK_DISTINCT_CAP).any()) or bool(
+            b.held_at_zero.any())
 
     @staticmethod
     def _j_bucket(n: int) -> int:
@@ -1924,6 +1941,24 @@ def _decorrelate_lanes(cluster, asks: list, salt: int = 0, used0=None) -> list:
     return out
 
 
+def even_boost(c, held_at_zero):
+    """NumPy mirror of _block_tables' even branch over count rows ``c``
+    ``[..., V]``: min and max over the values the combined-use map holds
+    (a positive count, or ``held_at_zero``); 0 where no count is
+    positive."""
+    c = np.asarray(c)
+    held = (c > 0) | held_at_zero
+    minc = np.where(held, c, np.inf).min(axis=-1, keepdims=True)
+    maxc = np.where(held, c, -np.inf).max(axis=-1, keepdims=True)
+    pos_min = np.isfinite(minc) & (minc > 0)
+    safe_min = np.where(pos_min, minc, 1.0)
+    at_min = np.where(
+        minc == maxc, -1.0, np.where(pos_min, (maxc - minc) / safe_min, 1.0))
+    off_min = np.where(pos_min, (minc - c) / safe_min, -1.0)
+    boost = np.where(c == minc, at_min, off_min)
+    return np.where((c > 0).any(axis=-1, keepdims=True), boost, 0.0)
+
+
 def _host_block_tables(c, blocks):
     """NumPy mirror of _block_tables for one lane's [B, V] count state."""
     boost = np.zeros_like(c)
@@ -1938,16 +1973,7 @@ def _host_block_tables(c, blocks):
                 -1.0,
             )
         elif kind == BLOCK_EVEN_SPREAD:
-            pos = c[b] > 0
-            if pos.any():
-                minc = float(c[b][pos].min())
-                maxc = float(c[b][pos].max())
-                at_min = c[b] == minc
-                boost[b] = np.where(
-                    at_min,
-                    -1.0 if minc == maxc else (maxc - minc) / max(minc, 1e-9),
-                    (minc - c[b]) / max(minc, 1e-9),
-                )
+            boost[b] = even_boost(c[b], blocks.held_at_zero[b])
         elif kind == BLOCK_DISTINCT_CAP:
             allow[b] = c[b] < blocks.caps[b]
     return boost, allow

@@ -529,6 +529,7 @@ def _instance_block_boost(blocks, rows):
         BLOCK_DISTINCT_CAP,
         BLOCK_EVEN_SPREAD,
         BLOCK_TARGET_SPREAD,
+        even_boost,
     )
 
     m = len(rows)
@@ -560,17 +561,7 @@ def _instance_block_boost(blocks, rows):
                     -1.0,
                 )
             elif kind == BLOCK_EVEN_SPREAD:
-                pos = c > 0
-                minc = np.where(pos, c, np.inf).min(axis=1)
-                maxc = np.where(pos, c, -np.inf).max(axis=1)
-                any_pos = maxc > 0
-                safe_min = np.where(any_pos, np.maximum(minc, 1e-9), 1.0)
-                val = np.where(
-                    c_at == minc,
-                    np.where(minc == maxc, -1.0, (maxc - minc) / safe_min),
-                    (minc - c_at) / safe_min,
-                )
-                val = np.where(any_pos, val, 0.0)
+                val = even_boost(c, blocks.held_at_zero[b][None, :])[at]
             else:
                 val = 0.0
             boost[s:s + step] += np.where(v >= 0, val, -1.0)

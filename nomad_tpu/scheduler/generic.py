@@ -201,6 +201,12 @@ class GenericScheduler:
         self._finalize()
 
     _finished = False
+    # an attempt of this eval handed a plan over (solo: submitted; batched:
+    # returned for the pass's merged plan)
+    _planned = False
+    # the overlay read this attempt's placements were scored on
+    # (``AllocMetric.usage_read``); set by whoever took the read
+    _usage_read = 0
     # [padded_n, D] of what this attempt's plan stops, per node row, or
     # None: set where the plan's stops are taken off the tensors
     _plan_freed = None
@@ -227,6 +233,7 @@ class GenericScheduler:
         overlay_ct = ct
         if self.overlay is not None:
             used_override = self.overlay.begin_pass(ct)
+            self._usage_read = self.overlay.read_ordinal()
             freed = self._plan_freed
             if freed is not None:
                 # the plan's stops are freed before its placements are
@@ -390,6 +397,7 @@ class GenericScheduler:
             return None
         for f in self.followup_evals:
             self.planner.create_eval(f)
+        self._planned = True
         return self.plan
 
     def complete_merged_attempt(self, result, new_snapshot=None) -> bool:
@@ -557,6 +565,7 @@ class GenericScheduler:
 
         for f in self.followup_evals:
             self.planner.create_eval(f)
+        self._planned = True
         # link placements awaiting delayed evals
         result, new_snap = self.planner.submit_plan(self.plan)
         if new_snap is not None:
@@ -650,6 +659,7 @@ class GenericScheduler:
                 metric = AllocMetric(
                     nodes_evaluated=ct.num_nodes,
                     nodes_available=dict(nodes_available),
+                    usage_read=self._usage_read,
                 )
                 node_id = ct.node_ids[row]
                 metric.scores[f"{node_id}.score"] = float(score)
@@ -1067,6 +1077,13 @@ class GenericScheduler:
     # -- completion -------------------------------------------------------
     def _finalize(self) -> None:
         ev = self.eval
+        if not self._planned and not self.failed_tg_allocs:
+            # nothing to place, stop or follow up: a no-op, counted once
+            # an eval whichever path finalized it (a member a batched pass
+            # set aside is finalized by its solo pass only)
+            from ..utils.metrics import global_metrics
+
+            global_metrics.incr("nomad.worker.noop_evals")
         if self.failed_tg_allocs:
             # create/update blocked eval to hold unplaced work, for a
             # batch job as for a service job (generic_sched.go:193-212
@@ -1110,6 +1127,9 @@ class GenericScheduler:
         updated = copy.copy(ev)
         updated.status = status
         updated.status_description = desc
+        # the state the eval was processed on (worker.go UpdateEval sets
+        # SnapshotIndex): a batched pass's members share theirs
+        updated.snapshot_index = getattr(self.snapshot, "index", 0)
         updated.failed_tg_allocs = dict(self.failed_tg_allocs)
         updated.queued_allocations = dict(self.queued_allocs)
         self.planner.update_eval(updated)

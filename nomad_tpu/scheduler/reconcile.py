@@ -29,6 +29,7 @@ from ..structs import (
     Node,
     TaskGroup,
 )
+from ..structs.alloc import name_index
 
 # Stop/update description strings (structs.go AllocUpdateReason*)
 REASON_ALLOC_NOT_NEEDED = "alloc not needed due to job update"
@@ -151,6 +152,24 @@ class AllocNameIndex:
 
     def highest(self, n: int) -> set[int]:
         return set(sorted(self.used, reverse=True)[:n])
+
+
+def _unreplaced_by_name(allocs) -> dict:
+    """Name -> the newest allocation of a group stopped as lost (its node
+    down) or migrated (its node draining) that names no replacement: a
+    plan stopped it, and the applier refused the placement beside the
+    stop (its node closed after the plan's snapshot)."""
+    out: dict = {}
+    for a in allocs:
+        if (
+            a.desired_status == ALLOC_DESIRED_STOP and not a.next_allocation
+            and (a.client_status == ALLOC_CLIENT_LOST
+                 or a.desired_transition.migrate)
+        ):
+            seen = out.get(a.name)
+            if seen is None or a.modify_index > seen.modify_index:
+                out[a.name] = a
+    return out
 
 
 def reconcile(
@@ -453,19 +472,26 @@ def reconcile(
             desired,
             [a for a in allocs if not a.terminal_status()],
         )
-        for prev, penalty in replace:
-            r.place.append(
-                PlaceRequest(
-                    name=prev.name,
-                    task_group=tg,
-                    previous_alloc=prev,
-                    reschedule_penalty_node=penalty,
-                )
+        placing = [
+            PlaceRequest(
+                name=prev.name,
+                task_group=tg,
+                previous_alloc=prev,
+                reschedule_penalty_node=penalty,
             )
-            counts["place"] += 1
+            for prev, penalty in replace
+        ]
+        unreplaced = _unreplaced_by_name(allocs) if missing else {}
         for name in name_idx.next(missing):
-            r.place.append(PlaceRequest(name=name, task_group=tg))
-            counts["place"] += 1
+            # a name whose lost or migrated allocation was stopped by a
+            # plan whose placement the applier refused: the retry's
+            # placement is that allocation's replacement
+            placing.append(PlaceRequest(
+                name=name, task_group=tg, previous_alloc=unreplaced.get(name)))
+        # placed in name order, whatever order the allocations came in
+        placing.sort(key=lambda pr: name_index(pr.name))
+        r.place.extend(placing)
+        counts["place"] += len(placing)
 
         r.desired_tg_updates[tg_name] = counts
 

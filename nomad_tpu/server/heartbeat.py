@@ -13,6 +13,7 @@ from typing import Optional
 
 from ..chaos.plane import chaos_site
 from ..structs import NODE_STATUS_DOWN
+from ..utils.metrics import global_metrics
 
 DEFAULT_HEARTBEAT_TTL = 5.0
 
@@ -70,14 +71,28 @@ class NodeHeartbeater:
                     if deadline < now:
                         expired.append(node_id)
                         del self._deadlines[node_id]
-            for node_id in expired:
-                node = self.server.store.node_by_id(node_id)
-                if node is None or node.terminal_status():
-                    continue
-                if chaos_site("heartbeat.expiry") == "drop":
-                    # missed sweep: the expiry is deferred, not lost —
-                    # re-arm the timer so the next sweep fires it
-                    self.heartbeat(node_id)
-                    continue
-                # missed TTL ⇒ node down ⇒ reschedule evals fan out
-                self.server.update_node_status(node_id, NODE_STATUS_DOWN)
+            self.expire(expired)
+
+    def expire(self, node_ids) -> int:
+        """The TTLs of ``node_ids`` ran out in one sweep: each node still
+        up is marked down, in order (heartbeat.go invalidateHeartbeat →
+        Node.UpdateStatus down), and its node evals fan out. A node
+        without a timer is marked down the same way. Returns how many
+        were marked down (``nomad.heartbeat.expired``)."""
+        down = 0
+        for node_id in node_ids:
+            with self._lock:
+                self._deadlines.pop(node_id, None)
+            node = self.server.store.node_by_id(node_id)
+            if node is None or node.terminal_status():
+                continue
+            if chaos_site("heartbeat.expiry") == "drop":
+                # missed sweep: the expiry is deferred, not lost —
+                # re-arm the timer so the next sweep fires it
+                self.heartbeat(node_id)
+                continue
+            # missed TTL ⇒ node down ⇒ reschedule evals fan out
+            self.server.update_node_status(node_id, NODE_STATUS_DOWN)
+            global_metrics.incr("nomad.heartbeat.expired")
+            down += 1
+        return down

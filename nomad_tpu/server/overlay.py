@@ -70,6 +70,17 @@ class SharedOverlay:
         # another's: held by at most one thread, released by its holder
         self._scoring = threading.Lock()
         self._scoring_owner: Optional[int] = None
+        # reads of the usage so far, and the calling thread's last one
+        self._reads = 0
+        self._read = threading.local()
+
+    def read_ordinal(self) -> int:
+        """The ordinal of the calling thread's last ``begin_pass`` here.
+        Reads are taken one at a time (the read-then-write lock): the
+        usage of read n holds the placements of every read before it in
+        the epoch, committed or still in flight, and none of a read after
+        it. Each placement carries it (``AllocMetric.usage_read``)."""
+        return getattr(self._read, "ordinal", 0)
 
     def end_scoring(self) -> None:
         if self._scoring_owner == threading.get_ident():
@@ -114,6 +125,8 @@ class SharedOverlay:
                 global_metrics.incr("nomad.overlay.scoring_wait_timeouts")
         with self._lock:
             self._passes += 1
+            self._reads += 1
+            self._read.ordinal = self._reads
             if self._base is not None and self._layout_gen != ct.layout_gen:
                 # full reflatten reordered rows: the frozen base no
                 # longer aligns — drop it (applier remains the authority)
@@ -279,6 +292,9 @@ class LaneOverlays:
 
     def pass_finished(self) -> None:
         self._overlays[0].pass_finished()
+
+    def read_ordinal(self) -> int:
+        return self._overlays[0].read_ordinal()
 
     def end_scoring(self) -> None:
         self._overlays[0].end_scoring()

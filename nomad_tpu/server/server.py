@@ -24,6 +24,7 @@ from ..broker.eval_broker import EvalBroker
 from ..broker.plan_queue import PlanApplyLoop, PlanQueue
 from ..state import StateStore
 from ..structs import (
+    ALLOC_CLIENT_LOST,
     EVAL_STATUS_BLOCKED,
     EVAL_STATUS_PENDING,
     NODE_SCHED_ELIGIBLE,
@@ -392,11 +393,18 @@ class Server:
         an allocation with desired status stop as gone. Blocked evals get
         their chance then, as they do when a client reports an allocation
         terminal (blocked_evals.go:55). An eviction frees nothing: the
-        preemptor's placement in the same plan takes the room."""
+        preemptor's placement in the same plan takes the room. A stop
+        marked ``lost`` frees what the allocation held until the plan:
+        only the server sets that status, so the client never reported it
+        terminal and the room is still counted."""
         stopped: dict = {}
         for r in results:
             for node_id, allocs in r.node_update.items():
-                gone = [a for a in allocs if not a.client_terminal_status()]
+                gone = [
+                    a for a in allocs
+                    if not a.client_terminal_status()
+                    or a.client_status == ALLOC_CLIENT_LOST
+                ]
                 if gone:
                     stopped.setdefault(node_id, []).extend(gone)
         if not stopped:
@@ -644,14 +652,31 @@ class Server:
 
     def update_node_status(self, node_id: str, status: str) -> list[Evaluation]:
         """Node.UpdateStatus: commit + fan out node-update evals for every
-        job with allocs on the node (nomad/node_endpoint.go createNodeEvals)."""
-        self.raft_apply(
-            self._msg.NODE_STATUS, {"node_id": node_id, "status": status}
-        )
-        self._publish(
-            "Node", "NodeStatusUpdate", node_id, "default", {"status": status}
-        )
-        return self._create_node_evals(node_id)
+        job with allocs on the node (nomad/node_endpoint.go createNodeEvals).
+        The background span ``node_status`` runs from the call's entry to
+        the node evals enqueued."""
+        from ..obs.trace import global_tracer
+
+        t_entry = time.perf_counter()
+        with global_tracer.background("node_status") as sp:
+            live = sum(
+                1 for a in self.store.allocs_by_node(node_id)
+                if not a.terminal_status()
+            )
+            self.raft_apply(
+                self._msg.NODE_STATUS, {"node_id": node_id, "status": status}
+            )
+            self._publish(
+                "Node", "NodeStatusUpdate", node_id, "default",
+                {"status": status},
+            )
+            evals = self._create_node_evals(node_id, entered_at=t_entry)
+            if sp is not None:
+                sp.tags.update(
+                    node_id=node_id, status=status, allocs=live,
+                    node_evals=len(evals),
+                )
+        return evals
 
     def update_node_drain(self, node_id: str, drain) -> list[Evaluation]:
         """Node.UpdateDrain: stamp the force deadline and commit; the

@@ -673,6 +673,9 @@ class Worker:
             # this overlay is the worker's own; peers' in-flight state
             # is irrelevant by construction (disjoint lanes + claims).
             used_override = overlay.begin_pass(ct)
+            read = overlay.read_ordinal()
+            for _ev, _tok, sched, _n in prepared:
+                sched._usage_read = read
             ps.share("overlay.wait")
             try:
                 kernel = prepared[0][2].kernel

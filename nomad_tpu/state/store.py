@@ -716,6 +716,9 @@ class StateStore(StateSnapshot):
             else:
                 a.create_index = index
             a.modify_index = index
+            # the server's own last write of the allocation: a client's
+            # update and the link to a successor leave it (AllocModifyIndex)
+            a.alloc_modify_index = index
             table[a.id] = a
             if existing is not None:
                 gone.append(existing)

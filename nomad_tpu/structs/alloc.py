@@ -62,6 +62,9 @@ class AllocMetric:
     # reference's DimensionExhausted strings; rides blocked evals so
     # `eval status` can say what to drain or resize.
     rejections: dict[str, int] = field(default_factory=dict)
+    # the ordinal of the placement overlay's read the placement was scored
+    # on (``SharedOverlay.read_ordinal``), 0 where no overlay was read
+    usage_read: int = 0
 
     def exhausted_node(self, node_id: str, dimension: str) -> None:
         self.nodes_exhausted += 1
@@ -96,6 +99,18 @@ class DesiredTransition:
     migrate: bool = False
     reschedule: bool = False
     force_reschedule: bool = False
+
+
+def name_index(name: str) -> int:
+    """An allocation name's index: "job.group[3]" → 3 (-1 without one)."""
+    lb = name.rfind("[")
+    rb = name.rfind("]")
+    if lb == -1 or rb == -1:
+        return -1
+    try:
+        return int(name[lb + 1 : rb])
+    except ValueError:
+        return -1
 
 
 @dataclass(slots=True)
@@ -180,14 +195,7 @@ class Allocation:
 
     def index(self) -> int:
         """Alloc name index: "job.group[3]" → 3."""
-        lb = self.name.rfind("[")
-        rb = self.name.rfind("]")
-        if lb == -1 or rb == -1:
-            return -1
-        try:
-            return int(self.name[lb + 1 : rb])
-        except ValueError:
-            return -1
+        return name_index(self.name)
 
     def job_namespaced_id(self) -> tuple[str, str]:
         return (self.namespace, self.job_id)

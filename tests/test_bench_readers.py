@@ -18,7 +18,9 @@ import check_traces  # noqa: E402
 from benchmark.readers import (  # noqa: E402
     drain_evals,
     eval_wait,
+    held_span_quantile,
     latency_untraced,
+    loss_evals,
     pass_stage_sum,
     pass_wall,
     span_sum,
@@ -418,6 +420,93 @@ class TestDrainEvals:
         old = self._ctx([solo_pass(), *batched_pass()])  # no node_id
         assert drain_evals.read(old, 0.5, "busy") is None
         assert drain_evals.read(old, 0.5, "wait") is None
+
+
+class TestLossEvals:
+    """A failure's requests (one a job, due at the failure) over the node
+    evals of their jobs on the failure's nodes, on the tracer's clock as
+    ``TestDrainEvals`` has them."""
+
+    T = 9_000.0
+
+    def _ctx(self, traces, requests):
+        by = global_tracer.unix_at(self.T) - T0
+        for t in traces:
+            for s in t["spans"]:
+                s["start_unix"] += by
+        return {"traces": traces, "requests": requests,
+                "t_open": self.T, "t_close": self.T + 1.0}
+
+    def _requests(self, dones, job_ids, node="n-1"):
+        failure = types.SimpleNamespace(node_ids=[node], due=self.T + 0.197)
+        return [
+            types.SimpleNamespace(failure=failure, job_id=j, ok=True,
+                                  due=self.T + 0.197, done=self.T + d)
+            for j, d in zip(job_ids, dones)
+        ]
+
+    def _traces(self):
+        lead, member = set_aside_pass()
+        lead["tags"]["job_id"], member["tags"]["job_id"] = "j-1", "j-2"
+        return [lead, member]
+
+    def test_a_jobs_evals_split_into_work_and_waits(self):
+        ctx = self._ctx(self._traces(), self._requests([0.217], ["j-1"]))
+        # the leader's: busy [0.200, 0.2015) and [0.2045, 0.2105); covered
+        # [0.198, 0.2105)
+        assert loss_evals.read(ctx, 0.5, "busy") == pytest.approx(
+            7.5, abs=1e-3)
+        assert loss_evals.read(ctx, 0.5, "wait") == pytest.approx(
+            5.0, abs=1e-3)
+
+    def test_evals_of_other_nodes_and_other_jobs_are_left_out(self):
+        traces = self._traces()
+        ctx = self._ctx(traces, self._requests([0.217], ["j-3"]))
+        assert loss_evals.read(ctx, 0.5, "busy") == 0.0
+        ctx = self._ctx(self._traces(),
+                        self._requests([0.217], ["j-1"], node="n-9"))
+        assert loss_evals.read(ctx, 0.5, "busy") == 0.0
+
+    def test_recover_is_due_to_the_last_job_done(self):
+        ctx = self._ctx(self._traces(),
+                        self._requests([0.217, 0.300], ["j-1", "j-2"]))
+        assert loss_evals.read(ctx, 0.5, "recover") == pytest.approx(103.0)
+
+    def test_another_deployments_requests_give_nothing(self):
+        reqs = [types.SimpleNamespace(job_id="j-1", ok=True, due=self.T,
+                                      done=self.T + 0.1)]
+        ctx = self._ctx(self._traces(), reqs)
+        for part in ("busy", "wait", "recover"):
+            assert loss_evals.read(ctx, 0.5, part) is None
+
+
+class TestHeldSpanQuantile:
+    T = 9_000.0
+
+    @pytest.fixture(autouse=True)
+    def _ring(self):
+        flight_recorder.clear()
+        yield
+        flight_recorder.clear()
+
+    def test_reads_the_window_the_ring_holds(self):
+        for k, ms in enumerate((1.0, 3.0, 2.0)):
+            global_tracer.add_background("node_status", ms / 1000.0,
+                                         start=self.T + 0.1 * (k + 1))
+        ctx = {"t_open": self.T, "t_close": self.T + 1.0}
+        assert held_span_quantile.read(ctx, "node_status", 0.5) == (
+            pytest.approx(2.0))
+
+    def test_a_full_ring_that_lost_the_windows_start_reads_nothing(
+            self, monkeypatch):
+        from nomad_tpu.obs import recorder
+
+        monkeypatch.setattr(recorder, "DEFAULT_BACKGROUND_CAPACITY", 3)
+        for k in range(3):
+            global_tracer.add_background("node_status", 0.001,
+                                         start=self.T + 0.1 * (k + 1))
+        ctx = {"t_open": self.T, "t_close": self.T + 1.0}
+        assert held_span_quantile.read(ctx, "node_status", 0.5) is None
 
 
 class TestCheckTraces:

@@ -463,7 +463,16 @@ def test_a_drain_starts_on_the_commit_that_set_it(quiet_server):
     assert held > 0
     scans = global_metrics.snapshot()["counters"].get("nomad.drain.waves", 0)
 
-    t0 = time.perf_counter()
+    from nomad_tpu.obs.recorder import flight_recorder
+
+    def scans_since(t_unix):
+        # this drain's scans, not an earlier test's in the same ring
+        return [
+            s for s in flight_recorder.background()
+            if s["name"] == "drain.scan" and s["start_unix"] >= t_unix
+        ]
+
+    t0, t0_unix = time.perf_counter(), time.time()
     evals = server.update_node_drain(victim.id, DrainStrategy(deadline_s=3600))
     assert evals == []  # a drain that starts makes no node evals
     assert wait_until(
@@ -473,7 +482,17 @@ def test_a_drain_starts_on_the_commit_that_set_it(quiet_server):
         ),
         timeout=2.0, interval=0.002,
     )
-    assert time.perf_counter() - t0 < 0.05
+    # the first marks are the scan the strategy's commit woke, not the 10 s
+    # poll's: the scan that marked them says who woke it
+    assert wait_until(
+        lambda: any(s["tags"].get("marked") for s in scans_since(t0_unix)),
+        timeout=2.0, interval=0.002,
+    )
+    first = min(
+        (s for s in scans_since(t0_unix) if s["tags"]["marked"]),
+        key=lambda s: s["start_unix"],
+    )
+    assert "node_drain" in first["tags"]["woken_by"].split(",")
     # every later wave rides the clients' acknowledgement: the whole drain
     # ends well inside the 10 s of one poll
     deadline = time.time() + 8.0
@@ -489,11 +508,8 @@ def test_a_drain_starts_on_the_commit_that_set_it(quiet_server):
     waves = global_metrics.snapshot()["counters"]["nomad.drain.waves"] - scans
     assert waves == held  # max_parallel 1: a wave an allocation
     # the scans were wakes over the draining set, not walks of the fleet
-    from nomad_tpu.obs.recorder import flight_recorder
-
     woken = [
-        s for s in flight_recorder.background()
-        if s["name"] == "drain.scan" and s["tags"]["woken_by"] != "interval"
+        s for s in scans_since(t0_unix) if s["tags"]["woken_by"] != "interval"
     ]
     assert woken and all(s["tags"]["walked"] <= 1 for s in woken)
     assert {"node_drain", "client_update"} <= {

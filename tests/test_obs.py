@@ -341,6 +341,42 @@ class TestEndToEndTrace:
         assert global_tracer.active_count() == 0
         assert global_tracer.dropped_spans() == 0
 
+    def test_a_node_down_writes_node_status_and_its_evals_register(self):
+        """``update_node_status`` is one background span ``node_status``
+        (entry -> node evals enqueued); each node eval's trace carries
+        ``register`` from that entry, as a drain's do."""
+        server = Server(ServerConfig(num_workers=1))
+        server.establish_leadership()
+        try:
+            nodes = [mock.node() for _ in range(2)]
+            for n in nodes:
+                server.register_node(n)
+            job = mock.job()
+            job.task_groups[0].count = 4
+            server.register_job(job)
+            assert server.wait_for_evals(timeout=15)
+            victim = max(nodes, key=lambda n: len([
+                a for a in server.store.allocs_by_node(n.id)
+                if not a.terminal_status()]))
+            held = len([a for a in server.store.allocs_by_node(victim.id)
+                        if not a.terminal_status()])
+            assert held > 0
+            (ev,) = server.update_node_status(victim.id, "down")
+            assert server.wait_for_evals(timeout=15)
+            tr = _wait_trace(ev.id)
+        finally:
+            server.shutdown()
+        (span,) = [s for s in flight_recorder.background()
+                   if s["name"] == "node_status"]
+        assert span["tags"] == {
+            "node_id": victim.id, "status": "down", "allocs": held,
+            "node_evals": 1,
+        }
+        assert tr is not None and tr["tags"]["node_id"] == victim.id
+        reg, deq = span_by_name(tr, "register"), span_by_name(tr, "dequeue")
+        assert reg["start_unix"] >= span["start_unix"] - 1e-3
+        assert _end(reg) == pytest.approx(deq["start_unix"], abs=1e-4)
+
     def test_http_trace_endpoints(self):
         from nomad_tpu.api.client import APIException, NomadClient
         from nomad_tpu.api.http import HTTPAgent

@@ -263,6 +263,9 @@ class _Epoch:
     def begin_pass(self, ct):
         return None if self.base is None else self.base.copy()
 
+    def read_ordinal(self):
+        return 0
+
     def add_delta(self, ct, rows, ask, writer=None):
         if self.frozen_from is None:
             self.frozen_from = np.asarray(ct.used).copy()
