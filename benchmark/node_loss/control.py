@@ -16,16 +16,17 @@ again by a retry that reads after the next pass and commits before it. So
 every part of the judge's view is in play: placements in flight at a read,
 placements of a later read committed first, an eval judged on its retry.
 
-Seven runs: soundly, and with one of ``FAULTS`` each: a node that went down
+Eight runs: soundly, and with one of ``FAULTS`` each: a node that went down
 left open to placement, the racks' counts still holding the lost
 allocations, scores in bfloat16 (the precision below the program's
 float32), a job's later eval that places once more, a lost allocation left
-running, and every pass of a failure scored on the usage as the failure
-began (a read older than its stamp says) while stamped as the sound run
-is. The cell's own comparison (``judge.judge`` + ``check.verdict``) judges
-all seven: the sound one must come out correct, each control not, by its
-own number. No server, no chip: numpy only; the benchmark's own runs never
-run it.
+running, every pass of a failure scored on the usage as the failure began
+(a read older than its stamp says) while stamped as the sound run is, and
+a service job's node eval failed after its plan attempts while the rack
+went down, on a node that stayed up. The cell's own comparison
+(``judge.judge`` + ``check.verdict``) judges all eight: the sound one must
+come out correct, each control not, by its own number. No server, no
+chip: numpy only; the benchmark's own runs never run it.
 """
 
 from __future__ import annotations
@@ -44,8 +45,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 from benchmark.reference import node_loss as _ref  # noqa: E402
 
 CELL = "rackloss-10k.arrivals-rack-down"
-# the reference's faults, and a pass that scored on an older read
-FAULTS = _ref.FAULTS + ("stale_read",)
+# the reference's faults, a pass that scored on an older read, and a node
+# eval failed where no node of its own went down
+FAULTS = _ref.FAULTS + ("stale_read", "failed_on_a_live_node")
 # the number each fault has to push over its limit
 FAILS = {
     "down_nodes_feasible": "placed_on_down_node",
@@ -54,6 +56,7 @@ FAILS = {
     "later_eval_replaces_again": "job_count_off",
     "lost_left_running": "lost_not_marked",
     "stale_read": "score_mismatch_share",
+    "failed_on_a_live_node": "failed_evals_unexplained",
 }
 # every this many-th eval's plan is refused in part and retried
 RETRY_EVERY = 4
@@ -160,7 +163,7 @@ def reference_run(start: dict, config: dict, traffic: dict, seed: int,
                     for d in DIMS:
                         seen[d][row] += float(spec[d])
         return ref.serve_eval(fleet, seen, specs[job_id], live_rows(job_id),
-                              down, None if fault == "stale_read" else fault)
+                              down, fault if fault in _ref.FAULTS else None)
 
     def apply(job_id, e, moved, placing, index) -> int:
         """One commit at ``index + 1``: ``moved``'s stops and the
@@ -217,8 +220,10 @@ def reference_run(start: dict, config: dict, traffic: dict, seed: int,
         return index
 
     order = rack_order(racks, traffic["failure"], seed)
+    taken_racks = []
     for k in range(n_failures):
         rack = next(order)
+        taken_racks.append(rack)
         rows = list(range(rack, n, racks))
         failure = Failure(k, rack, rows, [f"n{r}" for r in rows],
                           float(len(requests)))
@@ -273,6 +278,18 @@ def reference_run(start: dict, config: dict, traffic: dict, seed: int,
             index += 1
             failure.ready_index[row] = index
             down[row] = False
+    if fault == "failed_on_a_live_node":
+        # a service job's node eval made as the last rack began to go down
+        # and failed after its attempts once the rack was down, on a node
+        # of a rack that stayed up
+        job_id = next(r.job_id for r in failure.requests
+                      if specs[r.job_id]["type"] == "service")
+        born = min(failure.down_index.values())
+        evals.append({
+            "job": job_id, "create": born, "snap": born,
+            "node": next(r for r in range(n) if r % racks not in taken_racks),
+            "modify": max(failure.down_index.values()) + 1, "failed": True,
+        })
     specs_by_job = dict(enumerate(start["specs"]))
     ordinal = {s["id"]: j for j, s in specs_by_job.items()}
     as_i = lambda key: np.asarray(  # noqa: E731
@@ -288,16 +305,18 @@ def reference_run(start: dict, config: dict, traffic: dict, seed: int,
         answers[d] = np.asarray([a["spec"][d] for a in allocs], dtype=np.int64)
     answers["res"] = {d: answers[d] for d in plain.DIMS}
     answers["ids"] = {f"a{i}": i for i in range(len(allocs))}
+    failed = np.asarray([e.get("failed", False) for e in evals], dtype=bool)
     answers["evals"] = {
         "job": np.asarray([ordinal[e["job"]] for e in evals], dtype=np.int64),
         "create": np.asarray([e["create"] for e in evals], dtype=np.int64),
         "node": np.asarray([e["node"] for e in evals], dtype=np.int64),
         "snap": np.asarray([e["snap"] for e in evals], dtype=np.int64),
-        "modify": np.full(len(evals), index, dtype=np.int64),
-        "ok": np.ones(len(evals), dtype=bool),
+        "modify": np.asarray([e.get("modify", index) for e in evals],
+                             dtype=np.int64),
+        "ok": ~failed,
         "blocked": np.zeros(len(evals), dtype=bool),
-        "failed": np.zeros(len(evals), dtype=bool),
-        "max_plans": np.zeros(len(evals), dtype=bool),
+        "failed": failed,
+        "max_plans": failed,
     }
     answers["eval_row"] = {f"e{i}": i for i in range(len(evals))}
     answers["counters"] = {
@@ -357,6 +376,7 @@ def main(argv=None) -> int:
                     "lost_not_marked", "job_count_off",
                     "worst_gap_to_best", "evals_judged_in_flight",
                     "evals_judged_cut", "evals_judged_retried",
+                    "failed_evals_unexplained",
                 )},
             }
             ok = ok and (

@@ -83,7 +83,7 @@ def extract_answers(store, job_ids: dict) -> dict:
         if e.status == "failed" and len(failed_seen) < 5:
             failed_seen.append([e.triggered_by, e.type, e.status_description,
                                 e.create_index, e.snapshot_index,
-                                e.modify_index])
+                                e.modify_index, _node_row(e.node_id or "")])
     cols: dict = {k: [] for k in (
         "node", "job", "create", "stop", "name_idx", "eval", "lost",
         "score", "read", *plain.DIMS,
@@ -145,19 +145,21 @@ def losses_of(requests: list) -> list:
 
 class Down:
     """The intervals in which a node was down: ``(marked down, marked
-    ready again)``, both exclusive."""
+    ready again)``, both exclusive, each with the ordinal of its failure."""
 
     def __init__(self, n: int, failures: list):
         self.n = n
-        rows, since, until = [], [], []
-        for f in failures:
+        rows, since, until, of = [], [], [], []
+        for k, f in enumerate(failures):
             for row, index in f.down_index.items():
                 rows.append(row)
                 since.append(index)
                 until.append(f.ready_index.get(row) or _FOREVER)
+                of.append(k)
         self.rows = np.asarray(rows, dtype=np.int64)
         self.since = np.asarray(since, dtype=np.int64)
         self.until = np.asarray(until, dtype=np.int64)
+        self.failure = np.asarray(of, dtype=np.int64)
 
     def at(self, index: int) -> np.ndarray:
         """Bool per node: down at ``index`` (its down commit included)."""
@@ -264,13 +266,11 @@ def _judge_eval(fleet: dict, a: dict, spec: dict, e: int, down: Down) -> dict:
 
 
 def failed_evals(specs_by_job: dict, ev: dict, down: Down) -> tuple:
-    """``(on a dying rack, other)``: evals that ended ``failed``. One is of
-    the first kind where it is a batch job's that ran out of plan attempts
-    (generic_sched.go gives a batch eval 2, then fails it with a blocked
-    eval behind it) while a node went down, between its creation and the
-    commit that failed it: the applier refused its placements on nodes of
-    the rack going down under it. Each attempt refreshes its snapshot, so
-    the last one's is no witness."""
+    """``(on a dying rack, other)`` by a rule of the job's type alone: a
+    batch job's eval that ran out of plan attempts while any node went
+    down, between its creation and the commit that failed it. The judge
+    holds a run to ``failed_on_a_dying_rack``; tests/test_node_loss.py
+    still holds this one to its rule."""
     dying = other = 0
     for e in np.flatnonzero(ev["failed"]):
         born, mod = int(ev["create"][e]), int(ev["modify"][e])
@@ -281,6 +281,33 @@ def failed_evals(specs_by_job: dict, ev: dict, down: Down) -> tuple:
         else:
             other += 1
     return dying, other
+
+
+def failed_on_a_dying_rack(specs_by_job: dict, ev: dict, down: Down) -> dict:
+    """Evals that ended ``failed``: ``service`` and ``batch`` count the
+    service and batch jobs' evals that ran out of plan attempts on a dying
+    rack, ``unexplained`` every other. One ran out on a dying rack where it
+    is a node-update eval whose own node (``ev["node"]``) went down in a
+    failure of which a node went down between the eval's creation and the
+    commit that failed it: the rack goes down over the 0.1-0.3 s of its
+    node writes, the spread boost keeps sending the job's replacements to
+    the rack's nodes still up, the applier refuses them as each goes down
+    ("node is not allowed to receive allocations"), and generic_sched.go
+    fails the eval after its attempts (a service's 5, a batch's 2) with a
+    blocked eval behind it. Each attempt refreshes its snapshot, so the
+    last one's is no witness."""
+    out = {"service": 0, "batch": 0, "unexplained": 0}
+    for e in np.flatnonzero(ev["failed"]):
+        born, mod = int(ev["create"][e]), int(ev["modify"][e])
+        mine = np.isin(down.failure, down.failure[down.rows == ev["node"][e]])
+        went_down = bool(
+            (mine & (down.since > born) & (down.since <= mod)).any())
+        kind = specs_by_job[int(ev["job"][e])]["type"]
+        if ev["max_plans"][e] and went_down and kind in ("service", "batch"):
+            out[kind] += 1
+        else:
+            out["unexplained"] += 1
+    return out
 
 
 def judge(fleet: dict, specs_by_job: dict, requests: list, answers: dict,
@@ -330,12 +357,12 @@ def judge(fleet: dict, specs_by_job: dict, requests: list, answers: dict,
         (stayed & (a["stop"] > 0) & ~a["lost"]).sum())
     out["alloc_names_duplicated"] = names_duplicated(a)
     out["blocked_evals_left"] = int(a["evals"]["blocked"].sum())
-    dying, other = failed_evals(specs_by_job, a["evals"], down)
-    out["failed_evals_on_a_dying_rack"] = dying
-    out["failed_evals_unexplained"] = other
+    failed = failed_on_a_dying_rack(specs_by_job, a["evals"], down)
+    out["failed_evals_unexplained"] = failed.pop("unexplained")
+    out["failed_evals_on_a_dying_rack"] = failed
     if a.get("failed_seen"):
         # trigger, type, status description, create, snapshot and modify
-        # index of the first failed evals
+        # index and node row of the first failed evals
         out["failed_evals_seen"] = a["failed_seen"]
     counters = a["counters"]
     out["lost_counter_off"] = abs(
