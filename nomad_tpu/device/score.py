@@ -75,6 +75,7 @@ from ..utils.backend import (
     traced_jit,
     transfer_totals,
 )
+from ..utils.metrics import global_metrics as _metrics
 
 # Retrace budgets (nomad_tpu.analysis.retrace): the per-kernel trace
 # count a representative bench batch may reach. Every dynamic dimension
@@ -2033,6 +2034,104 @@ def _rescore_pick(capacity, used, a, placed_on_node, counts, algorithm_spread):
     return row, float(score[row])
 
 
+class _LaneRescore:
+    """``_rescore_pick`` for the placements of one lane's repair walk,
+    kept from one pick to the next. Built with one full pass, it keeps
+    what depends on a row's own state and the ask alone — the fit mask
+    before the value blocks, the numerator and the denominator without
+    their spread term — and ``commit`` recomputes them at the one row a
+    placement changed. A pick recomputes only what the per-value counts
+    decide (the V-wide block tables and their gather over the rows), so
+    it returns the oracle's ``(row, score)`` on the same ``used``, lane
+    placements and ``counts``, bit for bit: the same expressions, in the
+    same order and dtypes. ``used`` and ``counts`` are the walk's own
+    arrays, read at each pick; nothing else may write ``used`` while the
+    walk holds one of these."""
+
+    def __init__(self, capacity, used, a, placed_on_node, counts,
+                 algorithm_spread):
+        self.capacity, self.used, self.a = capacity, used, a
+        self.counts, self.algorithm_spread = counts, algorithm_spread
+        self.pm = np.zeros(capacity.shape[0], dtype=np.float32)
+        for r, m in placed_on_node.items():
+            self.pm[r] = m
+        self.fits, self.num, self.den = self._parts(slice(None))
+        # per block: each row's index into its table, V where the row
+        # has no value (the table's extra last entry: _rescore_pick's
+        # fill for a row without one)
+        blocks = a.blocks
+        self.cols = [] if blocks is None else [
+            np.where(vids >= 0, vids, blocks.num_values).astype(np.intp)
+            for vids in blocks.value_ids
+        ]
+
+    def _parts(self, sl):
+        """The row-local parts of ``_rescore_pick`` over the rows ``sl``."""
+        a, capacity = self.a, self.capacity[sl]
+        pm = self.pm[sl]
+        prop = self.used[sl] + a.ask[None, :]
+        fits = np.all(prop <= capacity, axis=1) & a.eligible[sl]
+        jc = a.job_counts[sl] + pm
+        if a.distinct_hosts:
+            fits &= jc == 0
+        if a.slot_caps is not None:
+            fits &= pm < a.slot_caps[sl]
+        free = np.where(
+            capacity > 0, (capacity - prop) / np.maximum(capacity, 1e-9), 1.0
+        )
+        pow_sum = 10.0 ** free[:, 0] + 10.0 ** free[:, 1]
+        binpack = np.clip(20.0 - pow_sum, 0.0, BINPACK_MAX_SCORE)
+        spread_fit = np.clip(pow_sum - 2.0, 0.0, BINPACK_MAX_SCORE)
+        fit_score = (
+            spread_fit if self.algorithm_spread else binpack
+        ) / BINPACK_MAX_SCORE
+        coll = jc.astype(np.float32)
+        anti = np.where(jc > 0, -(coll + 1.0) / max(a.desired_total, 1.0), 0.0)
+        resched = np.where(a.penalty_nodes[sl], -1.0, 0.0)
+        aff = a.affinity_scores[sl] if a.has_affinities else 0.0
+        num = fit_score + anti + resched + aff
+        den = (
+            1.0
+            + (jc > 0)
+            + a.penalty_nodes[sl]
+            + (1.0 if a.has_affinities else 0.0)
+        )
+        return fits, num, den
+
+    def commit(self, row: int) -> None:
+        """One more placement of the lane at ``row``, after the walk has
+        added it to ``used``."""
+        self.pm[row] += 1
+        sl = slice(row, row + 1)
+        self.fits[sl], self.num[sl], self.den[sl] = self._parts(sl)
+
+    def pick(self):
+        """``_rescore_pick`` on the walk's state: (row, score), row −1
+        when nothing fits."""
+        blocks, fits = self.a.blocks, self.fits
+        boost = np.zeros(fits.shape[0], dtype=np.float32)
+        has_spread_any = False
+        if blocks is not None:
+            tbl_boost, tbl_allow = _host_block_tables(self.counts, blocks)
+            for b, col in enumerate(self.cols):
+                if blocks.kinds[b] == BLOCK_DISTINCT_CAP:
+                    fits = fits & np.append(tbl_allow[b], True).take(col)
+                elif blocks.kinds[b] in (
+                    BLOCK_TARGET_SPREAD, BLOCK_EVEN_SPREAD
+                ):
+                    has_spread_any = True
+                    tbl = np.append(tbl_boost[b], -1.0)
+                    boost += tbl.astype(tbl_boost.dtype).take(col)
+        if not fits.any():
+            return -1, -np.inf
+        spread_on = has_spread_any & (boost != 0.0)
+        num = self.num + np.where(spread_on, boost, 0.0)
+        den = self.den + spread_on
+        score = np.where(fits, num / den, -np.inf)
+        row = int(np.argmax(score))
+        return row, float(score[row])
+
+
 def repair_batch_conflicts(
     cluster,
     asks: list,
@@ -2077,7 +2176,32 @@ def repair_batch_conflicts(
     failure releases the overlay reservations of EVERY processed lane in
     the group and skips its remaining lanes — sibling placements of a
     discarded plan must not stay reserved against later lanes.
+
+    Writes the ``repair`` span. Its tags ``full`` and ``row``, and the
+    counters ``nomad.worker.repair_rescores_full`` and ``_row``, count
+    the exact re-scores: full passes (a walk's first re-score, which
+    builds its ``_LaneRescore``, and each contention probe) and picks
+    that reuse the walk's re-scorer.
     """
+    rescores = {"full": 0, "row": 0}
+    with _tracer.span("repair") as sp:
+        ok_lanes = _repair_walks(
+            cluster, asks, results, algorithm_spread, fail_on_contention,
+            lane_groups, used_override, rescores,
+        )
+        if sp is not None:
+            sp.tags.update(rescores)
+    for kind, n in rescores.items():
+        _metrics.incr(f"nomad.worker.repair_rescores_{kind}", n)
+    return ok_lanes
+
+
+def _repair_walks(
+    cluster, asks, results, algorithm_spread, fail_on_contention,
+    lane_groups, used_override, rescores,
+) -> list[bool]:
+    """The body of ``repair_batch_conflicts``; counts its exact
+    re-scores into ``rescores``."""
     capacity = np.asarray(cluster.capacity)
     used0 = (
         np.asarray(cluster.used)
@@ -2120,6 +2244,10 @@ def repair_batch_conflicts(
             rows, overflow = [-1] * len(rows), []
         of_idx = 0
         dead = False  # lane-intrinsic infeasibility: stop re-scoring
+        # the exact re-score, built at the walk's first and kept up to
+        # date by every commit after it: a lane that re-scores k
+        # placements pays one full pass and k − 1 picks
+        rescorer: Optional[_LaneRescore] = None
 
         def commit(row: int) -> None:
             used[row] += a.ask
@@ -2129,6 +2257,8 @@ def repair_batch_conflicts(
                     v = blocks.value_ids[b, row]
                     if v >= 0:
                         counts[b, v] += 1
+            if rescorer is not None:
+                rescorer.commit(row)
 
         def acceptable(row: int) -> bool:
             if row < 0:
@@ -2153,19 +2283,25 @@ def repair_batch_conflicts(
             """Exact re-place of placement ``i``. Returns 'placed',
             'contention' (fits alone, not under the overlay), or
             'intrinsic'."""
-            pm = np.zeros(capacity.shape[0], dtype=np.float32)
-            for r, m in placed_on_node.items():
-                pm[r] = m
-            row, sc = _rescore_pick(
-                capacity, used, a, pm, counts, algorithm_spread
-            )
+            nonlocal rescorer
+            if rescorer is None:
+                rescorer = _LaneRescore(
+                    capacity, used, a, placed_on_node, counts,
+                    algorithm_spread,
+                )
+                rescores["full"] += 1
+            else:
+                rescores["row"] += 1
+            row, sc = rescorer.pick()
             if row >= 0:
                 res.node_rows[i] = row
                 res.scores[i] = sc
                 commit(row)
                 return "placed"
             # would it fit with only this lane's own placements applied?
+            pm = rescorer.pm
             lane_used = used0 + pm[:, None] * a.ask[None, :]
+            rescores["full"] += 1
             row, _sc = _rescore_pick(
                 capacity, lane_used, a, pm, counts, algorithm_spread
             )

@@ -262,17 +262,16 @@ class GenericScheduler:
                 # as placement failures
                 from ..device.score import repair_batch_conflicts
 
-                with tracer.span("repair"):
-                    repair_batch_conflicts(
-                        ct, asks, results,
-                        algorithm_spread=self.kernel.algorithm_spread,
-                        # single-eval: no fresh state to re-run against,
-                        # so an unplaceable placement fails into the
-                        # blocked-eval accounting instead of aborting the
-                        # lane
-                        fail_on_contention=True,
-                        used_override=used_override,
-                    )
+                repair_batch_conflicts(
+                    ct, asks, results,
+                    algorithm_spread=self.kernel.algorithm_spread,
+                    # single-eval: no fresh state to re-run against,
+                    # so an unplaceable placement fails into the
+                    # blocked-eval accounting instead of aborting the
+                    # lane
+                    fail_on_contention=True,
+                    used_override=used_override,
+                )
                 if self._explain:
                     # repair moves rows in place, so provenance is
                     # stamped from the POST-repair (= committed) rows

@@ -714,19 +714,18 @@ class Worker:
                     )
                     from ..device.score import repair_batch_conflicts
 
-                    with tracer.span("repair"):
-                        lane_ok = repair_batch_conflicts(
-                            ct,
-                            all_asks,
-                            results,
-                            algorithm_spread=kernel.algorithm_spread,
-                            # multi-TG evals span lanes; a failed lane
-                            # discards the WHOLE eval, so repair must
-                            # release (and stop reserving for) every
-                            # sibling lane too
-                            lane_groups=lane_groups,
-                            used_override=used_override,
-                        )
+                    lane_ok = repair_batch_conflicts(
+                        ct,
+                        all_asks,
+                        results,
+                        algorithm_spread=kernel.algorithm_spread,
+                        # multi-TG evals span lanes; a failed lane
+                        # discards the WHOLE eval, so repair must
+                        # release (and stop reserving for) every
+                        # sibling lane too
+                        lane_groups=lane_groups,
+                        used_override=used_override,
+                    )
                     if explain:
                         # post-repair: stamp the committed rows into each
                         # lane's explanation (obs/explain.py)
